@@ -85,6 +85,19 @@ struct ShmRing {
   uint8_t* map = nullptr;
   uint64_t cap = 0;
   bool creator = false;
+  // Producer-side OPEN stall (process-local, not in the shared
+  // header). Both callers of shmring_writev wait on a full ring in
+  // short slices — between them they take their own arrivals off
+  // their inbound rings so opposing full-ring senders cannot deadlock
+  // — and then retry the SAME record (the ring is SPSC and in order:
+  // nothing else can be written first). A -1 return therefore leaves
+  // the stall open and the retry resumes it: one blocked record is
+  // one w_stalls count however many slices it took, and w_stall_ns
+  // and the event record's waited-ns run from the first full-ring
+  // sighting to the write, the caller's time between slices included.
+  bool w_stall_open = false;
+  std::chrono::steady_clock::time_point w_stall_t0;    // first sighting
+  std::chrono::steady_clock::time_point w_stall_mark;  // credited up to
 };
 
 inline RingHdr* hdr(ShmRing* r) {
@@ -113,6 +126,12 @@ inline void max_rlx(uint64_t* p, uint64_t v) {
     __atomic_store_n(p, v, __ATOMIC_RELAXED);
 }
 
+inline uint64_t ns_between(std::chrono::steady_clock::time_point a,
+                           std::chrono::steady_clock::time_point b) {
+  return static_cast<uint64_t>(
+      std::chrono::duration_cast<std::chrono::nanoseconds>(b - a).count());
+}
+
 // one blocked wait = one stall; construct when the fast check fails,
 // settle() once on the way out (every exit path, including errors)
 struct StallTimer {
@@ -130,10 +149,7 @@ struct StallTimer {
   uint64_t settle() {
     if (!armed) return 0;
     armed = false;
-    auto dt = std::chrono::steady_clock::now() - t0;
-    uint64_t w =
-        static_cast<uint64_t>(std::chrono::duration_cast<
-                              std::chrono::nanoseconds>(dt).count());
+    uint64_t w = ns_between(t0, std::chrono::steady_clock::now());
     bump_rlx(ns, w);
     return w;
   }
@@ -322,9 +338,11 @@ int64_t shmring_stat(void* vr, int32_t which) {
 }
 
 // Producer side: append one record whose payload is the concatenation
-// of the scatter-gather parts. 0 on success, -1 timeout (ring full),
-// -2 record can never fit (caller must route via TCP), -3 consumer
-// process is gone.
+// of the scatter-gather parts. 0 on success, -1 timeout (ring full;
+// the stall stays open for the caller's retry of this record, see
+// ShmRing — a caller that gives the record up instead leaves it open
+// until the next write), -2 record can never fit (caller must route
+// via TCP), -3 consumer process is gone.
 int shmring_writev(void* vr, int32_t tag, const uint8_t** parts,
                    const int64_t* lens, int32_t nparts,
                    int timeout_ms) {
@@ -336,23 +354,38 @@ int shmring_writev(void* vr, int32_t tag, const uint8_t** parts,
   uint64_t total = kRecHdr + plen;
   if (total > r->cap) return -2;
   Deadline dl(timeout_ms);
-  StallTimer stall(&h->w_stalls, &h->w_stall_ns);
+  // credit the open stall's time since the last credit to w_stall_ns
+  auto credit = [r, h] {
+    auto now = std::chrono::steady_clock::now();
+    bump_rlx(&h->w_stall_ns, ns_between(r->w_stall_mark, now));
+    r->w_stall_mark = now;
+  };
   uint64_t w = h->widx;  // we are the only writer
   for (;;) {
     uint64_t used = w - load_acq(&h->ridx);
     if (r->cap - used >= total) break;
-    stall.arm();  // ring full: this write is a stall until it drains
+    if (!r->w_stall_open) {  // ring full: this record is one stall
+      r->w_stall_open = true;  // until it is written (see ShmRing)
+      r->w_stall_t0 = r->w_stall_mark = std::chrono::steady_clock::now();
+      bump_rlx(&h->w_stalls, 1);
+    }
     if (pid_dead(h->consumer_pid)) {
-      stall.settle();
+      credit();
+      r->w_stall_open = false;
       return -3;
     }
     if (dl.expired()) {
-      stall.settle();
-      return -1;
+      credit();
+      return -1;  // the stall stays open: the caller retries
     }
     ring_nap();
   }
-  uint64_t waited = stall.settle();
+  uint64_t waited = 0;
+  if (r->w_stall_open) {
+    credit();
+    r->w_stall_open = false;
+    waited = ns_between(r->w_stall_t0, r->w_stall_mark);
+  }
   uint8_t rec[kRecHdr];
   uint32_t l32 = static_cast<uint32_t>(plen);
   std::memcpy(rec, &l32, 4);

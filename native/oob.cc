@@ -228,6 +228,15 @@ int oob_next_len(void* h, int32_t tag, int timeout_ms) {
   }
 }
 
+// Liveness beats toward dst from a native thread (no interpreter, no
+// GIL): one (dst, tag) frame every interval_ms carrying this
+// process's resusage sample. See Endpoint::start_beats.
+void oob_beats_start(void* h, int32_t dst, int32_t tag, int interval_ms) {
+  static_cast<Endpoint*>(h)->start_beats(dst, tag, interval_ms);
+}
+
+void oob_beats_stop(void* h) { static_cast<Endpoint*>(h)->stop_beats(); }
+
 void oob_destroy(void* h) { delete static_cast<Endpoint*>(h); }
 
 }  // extern "C"

@@ -21,6 +21,7 @@
 #include <chrono>
 #include <condition_variable>
 #include <cstdint>
+#include <cstdio>
 #include <cstring>
 #include <deque>
 #include <map>
@@ -183,7 +184,77 @@ struct Endpoint {
   std::vector<std::thread> threads;
   std::thread acceptor;
 
+  // Liveness beats from a native thread. The reference's beat timer
+  // (sensor_heartbeat.c:61) lives in the daemon's event loop, not in
+  // the application; a beat sent by a Python thread needs the GIL for
+  // every line it runs, and a main thread inside back-to-back
+  // GIL-holding calls (bulk bytes/numpy copies, bytes.find over
+  // hundreds of MiB in the native-plan provenance probe) held it off
+  // past the HNP's miss window — a healthy job torn down as
+  // HEARTBEAT_FAILED. This thread never touches the interpreter.
+  std::thread beater;
+  std::mutex beat_mu;       // guards `beating`
+  std::mutex beat_join_mu;  // guards `beater` (taken before beat_mu)
+  std::condition_variable beat_cv;
+  bool beating = false;
+
   ~Endpoint() { stop(); }
+
+  // One beat's payload: the resusage sample the HNP keeps per worker
+  // (sensor_resusage feeding orte-ps), as the JSON its monitor parses.
+  static std::string beat_payload() {
+    unsigned long long vm_pages = 0, rss_pages = 0;
+    if (FILE* f = std::fopen("/proc/self/statm", "r")) {
+      if (std::fscanf(f, "%llu %llu", &vm_pages, &rss_pages) != 2)
+        vm_pages = rss_pages = 0;
+      std::fclose(f);
+    }
+    unsigned long long page =
+        static_cast<unsigned long long>(::sysconf(_SC_PAGESIZE));
+    char buf[128];
+    int n = std::snprintf(buf, sizeof buf,
+                          "{\"vmsize\": %llu, \"rss\": %llu, \"pid\": %d}",
+                          vm_pages * page, rss_pages * page,
+                          static_cast<int>(::getpid()));
+    return std::string(buf, static_cast<size_t>(n));
+  }
+
+  // Send one (dst, tag) beat every interval_ms until stop_beats(), the
+  // endpoint stops, or the link to dst is gone (a run that ended that
+  // way is not restarted: there is nobody left to beat to).
+  void start_beats(int32_t dst, int32_t tag, int interval_ms) {
+    std::lock_guard<std::mutex> j(beat_join_mu);
+    std::lock_guard<std::mutex> l(beat_mu);
+    if (beating || beater.joinable() || stopping) return;
+    beating = true;
+    beater = std::thread([this, dst, tag, interval_ms] {
+      std::unique_lock<std::mutex> bl(beat_mu);
+      while (beating) {
+        beat_cv.wait_for(bl, std::chrono::milliseconds(interval_ms));
+        if (!beating) break;
+        bl.unlock();
+        Frame f;
+        f.src = id;
+        f.dst = dst;
+        f.tag = tag;
+        std::string p = beat_payload();
+        f.payload.assign(p.begin(), p.end());
+        bool sent = send_frame(f);
+        bl.lock();
+        if (!sent) beating = false;  // lifeline gone: teardown follows
+      }
+    });
+  }
+
+  void stop_beats() {
+    std::lock_guard<std::mutex> j(beat_join_mu);
+    {
+      std::lock_guard<std::mutex> l(beat_mu);
+      beating = false;
+    }
+    beat_cv.notify_all();
+    if (beater.joinable()) beater.join();
+  }
 
   void stop() {
     if (stopping.exchange(true)) return;
@@ -199,6 +270,7 @@ struct Endpoint {
       std::lock_guard<std::mutex> l(mu);
       for (int fd : open_fds) ::shutdown(fd, SHUT_RDWR);
     }
+    stop_beats();  // after the shutdown: a beat parked in write() fails
     cv.notify_all();
     if (acceptor.joinable()) acceptor.join();
     for (auto& t : threads)

@@ -72,6 +72,9 @@ _RING_SLOTS_DEFAULT = 4
 _RING_BYTES_DEFAULT = 8 * 1024 * 1024
 _EVENT_SLOTS_DEFAULT = 1 << 16   # 2 MiB of 32-byte records
 _SEND_TIMEOUT_MS = 30_000
+#: first look (and every look while the peer's frames keep arriving)
+#: at our own inbound rings when a send finds the peer's ring full
+_FULL_RING_LOOK_MS = 5
 #: exit-time grace for tx rings holding bytes no consumer mapped yet —
 #: covers a receiver still inside interpreter/jax startup, not a hang
 _DRAIN_TIMEOUT_MS = 10_000
@@ -211,15 +214,18 @@ def _hwm_fold() -> float:
 
 _ring_stalls_pvar = _pvar.PVARS.register(
     "wire_native_ring_stalls", _pvar.PvarClass.COUNTER,
-    "times a native-datapath call sat blocked (full tx ring, empty rx "
-    "ring, empty tcp frame queue) — folded on read from the C-side "
-    "counter blocks, zero Python on the byte path",
+    "native-datapath stalls: records that found their tx ring full "
+    "(one per record, however many wait slices it took), reads that "
+    "found their rx ring or tcp frame queue empty — folded on read "
+    "from the C-side counter blocks, zero Python on the byte path",
     getter=lambda: _stall_fold()[0],
 )
 _stall_seconds_pvar = _pvar.PVARS.register(
     "wire_native_stall_seconds", _pvar.PvarClass.TIMER,
     "cumulative seconds the native datapath spent blocked waiting on "
-    "a peer (the time complement of wire_native_ring_stalls)",
+    "a peer (the time complement of wire_native_ring_stalls; a full "
+    "tx ring counts from first sighting to the write, the sender's "
+    "draining of its own inbound rings in between included)",
     getter=lambda: _stall_fold()[1] / 1e9,
 )
 _hwm_frac_pvar = _pvar.PVARS.register(
@@ -402,12 +408,9 @@ def module_for(cards, my_pidx: int) -> Optional["NativeWireBtl"]:
     datapath cannot run here (portable paths take over wholesale)."""
     if not nativewire_ready():
         return None
-    try:
-        mod = NativeWireBtl()
-        mod.bind(cards, int(my_pidx))
-        return mod
-    except Exception:
-        return None
+    mod = NativeWireBtl()
+    mod.bind(cards, int(my_pidx))
+    return mod
 
 
 def _ring_name(token: str, src_pidx: int, slot: int) -> str:
@@ -576,22 +579,25 @@ class NativeWireBtl(DcnBtl):
                 ent = self._tx[key] = (ring, threading.Lock())
             return ent
 
-    def _rx_ring(self, src_pidx: int, slot: int, deadline: float):
+    def _rx_ring(self, src_pidx: int, slot: int, deadline: float,
+                 wait: bool = True):
         """Consumer-side attach for (src -> me, slot), retried until
-        the producer's lazy create lands; the name is unlinked right
-        after attach (the mapping lives on) so /dev/shm stays clean.
-        A producer that died before creating surfaces as the typed
-        ERR_PROC_FAILED — pid liveness is authoritative on one host."""
+        the producer's lazy create lands (``wait=False``: one try —
+        one failed ``shm_open`` — and None if the ring is not there
+        yet); the name is unlinked right after attach (the mapping
+        lives on) so /dev/shm stays clean. A producer that died before
+        creating surfaces as the typed ERR_PROC_FAILED — pid liveness
+        is authoritative on one host."""
         src_cap = self._cap(src_pidx)
         key = (src_pidx, src_cap[0] if src_cap else "", slot)
         with self._ring_guard:
             ent = self._rx.get(key)
         if ent is not None:
             return ent
-        from ..native import ShmRing
-
-        token = self._cap(self.my_pidx)[0]
-        name = _ring_name(token, src_pidx, slot)
+        name = _ring_name(self._cap(self.my_pidx)[0], src_pidx, slot)
+        ent = self._rx_attach(key, name)
+        if ent is not None or not wait:
+            return ent
         peer_pid = 0
         try:
             peer_pid = int(self.cards[src_pidx].get("pid", 0) or 0)
@@ -606,17 +612,8 @@ class NativeWireBtl(DcnBtl):
                     "peer_pid": int(p)})
         try:
             while True:
-                ring = ShmRing.attach(name, os.getpid())
-                if ring is not None:
-                    ShmRing.unlink(name)
-                    with self._ring_guard:
-                        ent = self._rx.get(key)
-                        if ent is None:
-                            _track_ring(ring, tx=False)
-                            ent = self._rx[key] = (ring,
-                                                   threading.Lock(), {})
-                        else:
-                            ring.close()  # benign double-attach race
+                ent = self._rx_attach(key, name)
+                if ent is not None:
                     return ent
                 if peer_pid:
                     try:
@@ -641,6 +638,24 @@ class NativeWireBtl(DcnBtl):
         finally:
             if tok is not None:
                 _watchdog.disarm(tok)
+
+    def _rx_attach(self, key, name: str):
+        """One attach attempt; the rx entry, or None while the producer
+        has not created the ring."""
+        from ..native import ShmRing
+
+        ring = ShmRing.attach(name, os.getpid())
+        if ring is None:
+            return None
+        ShmRing.unlink(name)
+        with self._ring_guard:
+            ent = self._rx.get(key)
+            if ent is None:
+                _track_ring(ring, tx=False)
+                ent = self._rx[key] = (ring, threading.Lock(), {})
+            else:
+                ring.close()  # benign double-attach race
+        return ent
 
     def plan_endpoints(self, tag: int, send_peers, recv_srcs):
         """Per-peer native handles for a frozen-plan executor
@@ -700,15 +715,51 @@ class NativeWireBtl(DcnBtl):
             ent[0].close()
 
     # -- send side ---------------------------------------------------------
+    def _stash_inbound(self) -> bool:
+        """Move every frame our co-hosted peers have queued for us off
+        their rings into the rings' cross-tag stashes — where the
+        receive loop looks first, so order holds. True when anything
+        moved. A ring one of our own receivers is reading right now is
+        left to it (try-lock: the caller holds a tx lock). Rings not
+        created yet are probed again on every look, never remembered
+        as absent: a peer that starts sending to us while we are
+        parked is exactly the one whose ring must be drained."""
+        moved = False
+        rings = [(p, slot) for p in range(len(self.cards))
+                 if self.peer_capable(p) and self._same_host(p)
+                 for slot in range(self._cap(self.my_pidx)[1])]
+        for src_pidx, slot in rings:
+            ent = self._rx_ring(src_pidx, slot, 0.0, wait=False)
+            if ent is None or not ent[1].acquire(blocking=False):
+                continue
+            ring, rlk, rstash = ent
+            tmp = bytearray(1 << 16)  # one scratch for the whole ring
+            try:
+                while ring.pending() > 0:
+                    popped = self._pop_other_locked(ring, tmp)
+                    if popped is None:
+                        break
+                    _rlen, rtag, raw, tmp = popped
+                    rstash.setdefault(rtag, []).append(raw)
+                    _fallback_copies.add()  # the one restash copy
+                    moved = True
+            finally:
+                rlk.release()
+        return moved
+
     def _ring_put(self, ring, lk, oob_ep, peer_pidx: int, tag: int,
                   parts) -> None:
         deadline = _time.monotonic() + _SEND_TIMEOUT_MS / 1000
+        slice_ms = _FULL_RING_LOOK_MS
         tok = None
         if _watchdog.enabled:
-            # a full-ring wait blocks INSIDE ring.writev (C slices of
-            # <=2s); the zero-arg info resolves at dump time, so the
-            # postmortem names the ring, its consumer, and the LIVE
-            # occupancy at the moment the watchdog fired
+            # a full-ring wait blocks INSIDE ring.writev, in slices;
+            # the ring keeps the stall open across them, so however
+            # many it takes this send is ONE w_stalls count and its
+            # stall time runs to the write (native/btl_shm.cc). The
+            # zero-arg info resolves at dump time, so the postmortem
+            # names the ring, its consumer, and the LIVE occupancy at
+            # the moment the watchdog fired
             tok = _watchdog.arm(
                 "nw_ring_put", peer=peer_pidx,
                 info=lambda r=ring, p=peer_pidx: _ring_wait_info(
@@ -718,7 +769,7 @@ class NativeWireBtl(DcnBtl):
                 while True:
                     left = max(1, int((deadline - _time.monotonic())
                                       * 1000))
-                    rc = ring.writev(tag, parts, min(left, 2000))
+                    rc = ring.writev(tag, parts, min(left, slice_ms))
                     if rc == 0:
                         return
                     if rc == -3:
@@ -740,6 +791,17 @@ class NativeWireBtl(DcnBtl):
                             f"full for {_SEND_TIMEOUT_MS} ms "
                             "(consumer stalled)",
                         )
+                    # ring full. Every process of a schedule round
+                    # posts its sends before it reaps, so past one
+                    # ring of bytes the consumer may itself be parked
+                    # on a full ring — ours, or (around a cycle of
+                    # peers) someone's who waits on us. Take what is
+                    # queued for us off our inbound rings
+                    # (native/planexec.cc keeps the same discipline);
+                    # while frames keep coming look again soon,
+                    # otherwise wait in long slices
+                    slice_ms = (_FULL_RING_LOOK_MS
+                                if self._stash_inbound() else 2000)
         finally:
             if tok is not None:
                 _watchdog.disarm(tok)
@@ -953,7 +1015,7 @@ class NativeWireBtl(DcnBtl):
                             self.staged_chunks_pvar.add()
                         continue
                     if restash is not None:
-                        rlen, rtag, raw2 = restash
+                        _rlen, rtag, raw2, _scratch = restash
                         with rlk:
                             rstash.setdefault(rtag, []).append(raw2)
                         _fallback_copies.add()  # the one restash copy
@@ -1026,22 +1088,25 @@ class NativeWireBtl(DcnBtl):
         return jax.device_put(arr, dst_device)
 
     @staticmethod
-    def _pop_other_locked(ring):
-        """Pop the ring head (known to belong to another tag) while
-        the caller holds the ring lock; returns (len, tag, bytes) or
-        None when the head raced away / cannot be materialized."""
-        size = 1 << 16
+    def _pop_other_locked(ring, tmp=None):
+        """Pop the ring head (a record some other receive wants) while
+        the caller holds the ring lock; returns (len, tag, bytes,
+        scratch) or None when the head raced away / cannot be
+        materialized. ``tmp``: a scratch bytearray to read through
+        (grown x8 when the record does not fit, and handed back so a
+        caller popping many records allocates once)."""
+        if tmp is None:
+            tmp = bytearray(1 << 16)
         while True:
-            tmp = bytearray(size)
             rc, rtag = ring.read_into(tmp, 10)
             if rc == -2:
-                if size >= ring.capacity:
+                if len(tmp) >= ring.capacity:
                     return None
-                size = min(size * 8, ring.capacity)
+                tmp = bytearray(min(len(tmp) * 8, ring.capacity))
                 continue
             if rc < 0:  # -1 raced-empty / -3 dead: main loop handles
                 return None
-            return rc, rtag, bytes(memoryview(tmp)[:rc])
+            return rc, rtag, bytes(memoryview(tmp)[:rc]), tmp
 
 
 class NativeWireComponent(mca_component.Component):

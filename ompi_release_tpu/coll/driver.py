@@ -19,10 +19,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..utils import jaxcompat as _jaxcompat
-
-_jaxcompat.install()  # jax.shard_map on 0.4.x jaxlibs
-
 from .. import obs as _obs
 from ..mca import pvar
 from ..obs import skew as _skew

@@ -36,11 +36,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils import jaxcompat as _jaxcompat
-
-_jaxcompat.install()  # jax.shard_map/typeof on 0.4.x jaxlibs
-
 from ..parallel import tp as tp_mod
+from ..utils import compile_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -179,11 +176,13 @@ def make_batch_sharding(mesh: Mesh) -> NamedSharding:
 
 def make_forward(cfg: MixerConfig, mesh: Mesh):
     cfg.validate(mesh)
+    compile_cache.ensure()
     return jax.jit(_loss_spmd(cfg, mesh))
 
 
 def make_train_step(cfg: MixerConfig, mesh: Mesh, optimizer):
     cfg.validate(mesh)
+    compile_cache.ensure()
     loss_fn = _loss_spmd(cfg, mesh)
 
     @jax.jit

@@ -32,12 +32,9 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils import jaxcompat as _jaxcompat
-
-_jaxcompat.install()  # jax.shard_map/typeof on 0.4.x jaxlibs
-
 from ..parallel import cp, ep as ep_mod, pp as pp_mod, tp as tp_mod
 from ..parallel import tree as tree_mod
+from ..utils import compile_cache
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,9 +53,10 @@ class ModelConfig:
     #                      recompute for activation memory)
     dtype: Any = jnp.bfloat16
     rope_base: float = 10000.0
-    # attention implementation: "auto" = Pallas flash kernel on TPU when
-    # the sequence is unsharded, ring attention otherwise; "ring" /
-    # "flash" force one path (flash runs interpreted off-TPU)
+    # attention implementation: "auto" = Pallas flash kernel when the
+    # mesh is TPU devices and the sequence is unsharded, ring attention
+    # otherwise (resolved from the mesh by the jitted entry points);
+    # "ring" / "flash" force one path (flash runs interpreted off-TPU)
     attn_impl: str = "auto"
 
     def validate(self, mesh: Mesh) -> None:
@@ -199,11 +197,7 @@ def _layer(cfg: ModelConfig, lp: Dict, x: jax.Array) -> jax.Array:
             "attn_impl='flash' is single-shard attention; with sp>1 "
             "use 'ring' (or 'auto', which picks ring for sharded seq)"
         )
-    use_flash = cfg.attn_impl == "flash" or (
-        cfg.attn_impl == "auto" and sp_n == 1
-        and jax.default_backend() == "tpu"
-    )
-    if use_flash:
+    if cfg.attn_impl == "flash":
         from ..ops.pallas_attention import flash_attention
 
         attn_fn = lambda q1, k1, v1: flash_attention(q1, k1, v1, True)
@@ -303,9 +297,14 @@ def _loss_spmd(cfg: ModelConfig, mesh: Mesh):
     # verified against the dense reference both directions in
     # tests/test_pallas.py). Disable the check exactly there, keeping
     # it live for every other configuration.
-    check_vma = not (
-        cfg.attn_impl == "flash" and jax.default_backend() != "tpu"
-    )
+    on_tpu = mesh.devices.flat[0].platform == "tpu"
+    if cfg.attn_impl == "auto":
+        # decided by where the operands live (the mesh), not by the
+        # process's default backend
+        flash = on_tpu and mesh.shape["sp"] == 1
+        cfg = dataclasses.replace(
+            cfg, attn_impl="flash" if flash else "ring")
+    check_vma = not (cfg.attn_impl == "flash" and not on_tpu)
     return jax.shard_map(
         partial(forward_loss, cfg),
         mesh=mesh,
@@ -319,6 +318,7 @@ def make_forward(cfg: ModelConfig, mesh: Mesh):
     """Jitted loss-evaluation forward step (the flagship inference/eval
     path); returns fn(params, tokens, targets) -> scalar loss."""
     cfg.validate(mesh)
+    compile_cache.ensure()
     return jax.jit(_loss_spmd(cfg, mesh))
 
 
@@ -331,6 +331,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, optimizer):
     north-star requirement of SURVEY §6).
     """
     cfg.validate(mesh)
+    compile_cache.ensure()
     loss_fn = _loss_spmd(cfg, mesh)
 
     @jax.jit

@@ -82,6 +82,19 @@ def load_library() -> ctypes.CDLL:
             return _lib
         if not os.path.exists(_SO_PATH) or not _stamp_current():
             _log.verbose(1, "building native control-plane library")
+            import shutil
+
+            missing = [t for t in ("make", os.environ.get("CXX", "g++"))
+                       if shutil.which(t) is None]
+            if missing:
+                raise MPIError(
+                    ErrorCode.ERR_OTHER,
+                    f"native library cannot be built: "
+                    f"{' and '.join(missing)} not found on PATH. "
+                    f"{_SO_PATH} is compiled from native/*.cc on first "
+                    "use, and the OOB control plane and the native "
+                    "wire datapath of every tpurun job need it",
+                )
             r = subprocess.run(
                 ["make", "-s", "all"], cwd=_NATIVE_DIR,
                 capture_output=True, text=True,
@@ -152,6 +165,11 @@ def _declare(lib: ctypes.CDLL) -> None:
     lib.oob_auth_rejected.restype = ctypes.c_int
     lib.oob_next_len.argtypes = [P, ctypes.c_int32, ctypes.c_int]
     lib.oob_next_len.restype = ctypes.c_int
+    lib.oob_beats_start.argtypes = [P, ctypes.c_int32, ctypes.c_int32,
+                                    ctypes.c_int]
+    lib.oob_beats_start.restype = None
+    lib.oob_beats_stop.argtypes = [P]
+    lib.oob_beats_stop.restype = None
     lib.oob_destroy.argtypes = [P]
 
     # nativewire datapath symbols are OPTIONAL: a stale .so built from
@@ -598,6 +616,20 @@ class OobEndpoint:
 
     def pending(self) -> int:
         return self._lib.oob_pending(self._handle())
+
+    def start_beats(self, dst: int, tag: int, interval_s: float) -> None:
+        """One ``(dst, tag)`` liveness frame every ``interval_s`` from
+        a native thread, each carrying this process's resusage sample
+        as JSON (``vmsize``, ``rss``, ``pid``). The thread never takes
+        the GIL, so no amount of Python-side work delays a beat. Ends
+        with :meth:`stop_beats`, :meth:`close`, or the link to ``dst``
+        going away."""
+        self._lib.oob_beats_start(self._handle(), dst, tag,
+                                  max(1, int(interval_s * 1000)))
+
+    def stop_beats(self) -> None:
+        if self._h:
+            self._lib.oob_beats_stop(self._h)
 
     def close(self) -> None:
         if self._h:

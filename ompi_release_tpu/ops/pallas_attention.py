@@ -28,10 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..utils import jaxcompat as _jaxcompat
-
-_jaxcompat.install()  # jax.typeof/ShapeDtypeStruct-vma on 0.4.x jaxlibs
-
 NEG_INF = -1e30
 
 

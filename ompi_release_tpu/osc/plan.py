@@ -252,9 +252,9 @@ class EpochPlan:
         args = []
         for p in todo:
             if p.data is not None:
-                args.append(p.data)
+                args.append(win._origin(p.data))
             if p.compare is not None:
-                args.append(p.compare)
+                args.append(win._origin(p.compare))
         _orch.add(_time.perf_counter() - t0)
         with _dispatch_lock:
             _epoch_dispatches.add()

@@ -391,6 +391,17 @@ class Window:
         self._freed = True
 
     # -- RMA operations ----------------------------------------------------
+    def _origin(self, x):
+        """An origin buffer as an epoch-program argument. A device-
+        resident one arrives COMMITTED to its own device while the
+        program runs where the window lives, and jit refuses the mix —
+        replicate it over the window's devices. Host buffers and
+        arrays already placed there pass through."""
+        if (getattr(x, "committed", False) and
+                x.sharding.device_set != self._data.sharding.device_set):
+            return jax.device_put(x, NamedSharding(self._shard.mesh, P()))
+        return x
+
     def _queue(self, op: _PendingOp) -> Optional[Request]:
         self._require(_EpochKind.FENCE, _EpochKind.LOCK, _EpochKind.PSCW)
         if (self._epoch is _EpochKind.LOCK
@@ -667,8 +678,8 @@ class Window:
         def pay(x):
             if x is None:
                 return zeros
-            return jnp.broadcast_to(jnp.asarray(x).astype(dtype),
-                                    pay_shape)
+            return jnp.broadcast_to(
+                jnp.asarray(self._origin(x)).astype(dtype), pay_shape)
 
         codes_a = jnp.asarray(codes, jnp.int32)
         targets_a = jnp.asarray(

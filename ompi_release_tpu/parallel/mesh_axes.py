@@ -17,10 +17,6 @@ import numpy as np
 import jax
 from jax.sharding import Mesh
 
-from ..utils import jaxcompat as _jaxcompat
-
-_jaxcompat.install()  # jax.shard_map/typeof on 0.4.x jaxlibs
-
 AXIS_DP = "dp"  # data parallel (gradient psum)
 AXIS_PP = "pp"  # pipeline stages (ppermute ring)
 AXIS_SP = "sp"  # sequence/context parallel (alltoall / K-V ring)

@@ -881,7 +881,8 @@ class WorkerAgent:
         self.ep.connect(0, hnp_host, hnp_port)
         self.ep.set_default_route(0)  # everything flows toward the root
         self.cards: List[Dict[str, Any]] = []
-        self._hb_thread: Optional[threading.Thread] = None
+        #: stops the process-management threads (die/ft watchers);
+        #: the beats themselves come from the endpoint's native thread
         self._hb_stop = threading.Event()
         # created HERE, not lazily: two threads' first RPCs racing a
         # lazy check-then-set would mint two locks and defeat the
@@ -1192,15 +1193,13 @@ class WorkerAgent:
         self.ep.send(0, TAG_HEARTBEAT, json.dumps(ru).encode())
 
     def start_heartbeats(self, interval_s: float = 1.0) -> None:
-        def run() -> None:
-            while not self._hb_stop.wait(interval_s):
-                try:
-                    self.heartbeat()
-                except MPIError:
-                    return  # lifeline gone; process teardown follows
-
-        self._hb_thread = threading.Thread(target=run, daemon=True)
-        self._hb_thread.start()
+        """Beat to the HNP every ``interval_s`` from the endpoint's
+        native thread (same frame as :meth:`heartbeat`). Not a Python
+        thread: that one needs the GIL for every line, and a main
+        thread inside back-to-back GIL-holding calls (the native-plan
+        probe's bulk byte copies and searches at 64 MiB) kept it from
+        beating for longer than the HNP's miss window."""
+        self.ep.start_beats(0, TAG_HEARTBEAT, interval_s)
         self._start_die_watcher()
 
     def _start_die_watcher(self) -> None:
@@ -1232,8 +1231,7 @@ class WorkerAgent:
 
     def stop_heartbeats(self) -> None:
         self._hb_stop.set()
-        if self._hb_thread is not None:
-            self._hb_thread.join(timeout=2)
+        self.ep.stop_beats()
 
     # -- teardown ----------------------------------------------------------
     def send_fin(self) -> None:

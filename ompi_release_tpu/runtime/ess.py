@@ -111,6 +111,53 @@ class DistributedEss(mca_component.Component):
         }
 
 
+def host_platform_declared() -> bool:
+    """True when this process was started with an explicit
+    ``JAX_PLATFORMS`` that leaves the TPU out (``cpu``): a CPU run that
+    was asked for by name, as opposed to jax's silent fallback when no
+    chip answers."""
+    import jax
+
+    pinned = str(jax.config.jax_platforms or "")
+    return bool(pinned) and "tpu" not in pinned.split(",")
+
+
+def _bound_platform() -> str:
+    """Platform of this worker's devices, holding the launcher's chip
+    binding to its word: ``tpurun`` gave this process one local slot
+    = one chip (``tools/tpurun.chip_binding_env``). If no chip answers
+    for the slot the rank FAILS here, naming the slot — it never
+    quietly becomes a CPU rank (jax falls back to the CPU without a
+    word when ``JAX_PLATFORMS`` is unset). A host rank is one that was
+    started with an explicit ``JAX_PLATFORMS`` that leaves the TPU
+    out; those behave as they always did."""
+    import jax
+
+    from ..utils.errors import ErrorCode, MPIError
+
+    slot = os.environ.get("OMPITPU_LOCAL_SLOT")
+    where = (f"tpurun rank {int(os.environ['OMPITPU_NODE_ID']) - 1} "
+             f"(local slot {slot} on host "
+             f"{os.environ.get('OMPITPU_HOST', '?')})")
+    try:
+        platform = jax.local_devices()[0].platform
+    except RuntimeError as e:
+        raise MPIError(
+            ErrorCode.ERR_OTHER,
+            f"{where}: no usable device behind the slot — {e}",
+        ) from e
+    if (slot is not None and platform == "cpu"
+            and not host_platform_declared()):
+        raise MPIError(
+            ErrorCode.ERR_OTHER,
+            f"{where} has no TPU chip behind it: jax came up on the "
+            "CPU. A rank never lands on the CPU by itself — map at "
+            "most one rank per chip, or declare a host rank by "
+            "starting it with JAX_PLATFORMS=cpu",
+        )
+    return str(platform)
+
+
 class TpurunEss(mca_component.Component):
     """Bootstrap for processes launched by ``tpurun`` (the ess/env
     analogue: mpirun-launched procs detect the daemon's env vars,
@@ -143,6 +190,7 @@ class TpurunEss(mca_component.Component):
 
         from . import coordinator as coord
 
+        platform = _bound_platform()  # before any wire-up: fail fast
         host, port = os.environ["OMPITPU_HNP"].rsplit(":", 1)
         node_id = int(os.environ["OMPITPU_NODE_ID"])
         num_workers = int(os.environ["OMPITPU_NUM_NODES"])
@@ -170,16 +218,15 @@ class TpurunEss(mca_component.Component):
             "host": os.environ.get("OMPITPU_HOST_ID")
                     or socket.gethostname(),
             "local_device_count": jax.local_device_count(),
-            "platform": jax.local_devices()[0].platform,
+            "platform": platform,
         }
-        try:
-            # nativewire capability advertisement (ring token/geometry):
-            # a probe failure just means the card stays portable-only
-            from ..btl import nativewire as _nativewire
+        # nativewire capability advertisement (ring token/geometry);
+        # empty when the datapath is switched off. The .so itself is
+        # already loaded — the OOB endpoint above cannot exist without
+        # it — so nothing here can quietly withdraw the native path
+        from ..btl import nativewire as _nativewire
 
-            card.update(_nativewire.modex_entry())
-        except Exception:
-            pass
+        card.update(_nativewire.modex_entry())
         cards = agent.run_modex(card)  # launcher mode: workers only
         agent.setup_tree(num_workers + 1, cards)
         # FULL wire-up (superset of the tree edges): connect to every

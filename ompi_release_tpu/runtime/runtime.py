@@ -81,6 +81,12 @@ class Runtime:
                     "(matches MPI_Init-after-MPI_Finalize)",
                 )
 
+            # every process of a job compiles into the one shared
+            # persistent cache, before anything below can jit
+            from ..utils import compile_cache as _compile_cache
+
+            _compile_cache.ensure()
+
             # 1. core vars + CLI
             mesh_mod.register_vars()
             from .wire import register_vars as _wire_register_vars

@@ -302,6 +302,48 @@ def parse_rankfile(path: str, n: int,
     return [by_name[placed[r]] for r in range(n)]
 
 
+#: ``TPU_VISIBLE_CHIPS`` for a slot with no chip behind it: an index no
+#: host has, so libtpu itself refuses ("no device found") and the rank
+#: fails at init by name instead of reaching for a chip that is not its
+_NO_CHIP = "255"
+
+
+def chip_binding_env(slot: int,
+                     visible: Optional[str] = None) -> Dict[str, str]:
+    """Environment that makes the worker in local slot ``slot`` of a
+    host see exactly one chip of that host, as a one-chip topology of
+    its own. A chip belongs to one process at a time, so without this
+    every rank's libtpu reaches for every chip and the second rank to
+    start fails or hangs. Decided here, in the launcher, from the rank
+    map alone — the launcher never asks a jax backend what devices
+    exist (that would take the chips itself).
+
+    Slot k gets chip k, unless the launcher was itself confined to
+    some of the host's chips: ``visible`` is its own inherited
+    ``TPU_VISIBLE_CHIPS`` (a user's or a scheduler's), and slot k then
+    gets the k-th chip of that list — a job given chips 4-7 of a
+    shared host binds 4-7, not 0-3. A slot past the end of the list
+    has no chip.
+
+    The TPU_* names are the ones the installed libtpu reads; a worker
+    started with ``JAX_PLATFORMS=cpu`` never loads libtpu and ignores
+    them. ``OMPITPU_LOCAL_SLOT`` lets the worker name its slot when no
+    chip answers (``runtime/ess.py``). Every ``tpurun`` rank is thus a
+    one-chip controller: there is no ICI between ranks, and a rank's
+    ``local_device_count`` is 1 on a TPU."""
+    chip = str(slot)
+    if visible:
+        chips = [c.strip() for c in visible.split(",") if c.strip()]
+        chip = chips[slot] if slot < len(chips) else _NO_CHIP
+    return {
+        "OMPITPU_LOCAL_SLOT": str(slot),
+        "TPU_VISIBLE_CHIPS": chip,
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+
+
 class Job:
     """One launched job: processes + coordinator + state machines."""
 
@@ -340,6 +382,11 @@ class Job:
                 self.hosts = list(seen.values())
         else:
             self.rank_hosts = map_ranks(self.hosts, num_procs, map_by)
+        # local slot of each rank on its host = the chip it is bound
+        # to (chip_binding_env): ranks on one host get 0, 1, 2, ...
+        self.rank_slots: List[int] = []
+        for h in self.rank_hosts:
+            self.rank_slots.append(self._free_slot(h))
         self.remote = any(not h.is_local for h in self.rank_hosts)
         self.launch_agent = launch_agent
         # errmgr policy: 'abort' = default_hnp teardown; 'restart' =
@@ -405,6 +452,18 @@ class Job:
         os.environ[SECRET_ENV] = self.secret
 
     # -- launch ------------------------------------------------------------
+    def _free_slot(self, host: HostSpec, skip: int = -1) -> int:
+        """Lowest local slot on ``host`` no mapped rank holds (``skip``:
+        a rank index whose current slot does not count — the one being
+        moved)."""
+        taken = {s for i, (h, s) in enumerate(
+                     zip(self.rank_hosts, self.rank_slots))
+                 if h.name == host.name and i != skip}
+        slot = 0
+        while slot in taken:
+            slot += 1
+        return slot
+
     def _env_for(self, node_id: int) -> Dict[str, str]:
         env = dict(os.environ)
         env.update(self._ompitpu_env(node_id))
@@ -425,6 +484,11 @@ class Job:
                 self.heartbeat_s
             ),
         }
+        # the launcher's own confinement speaks for its own host only
+        env.update(chip_binding_env(
+            self.rank_slots[node_id - 1],
+            os.environ.get("TPU_VISIBLE_CHIPS")
+            if self.rank_hosts[node_id - 1].is_local else None))
         if self.on_failure == "restart":
             # workers under the resilient policy tolerate unreachable
             # peers at wire-up (a peer may be mid-restart or finished)
@@ -612,6 +676,8 @@ class Job:
             )
             if candidates:
                 self.rank_hosts[node_id - 1] = candidates[0]
+                self.rank_slots[node_id - 1] = self._free_slot(
+                    candidates[0], skip=node_id - 1)
             elif failed_host.name in self._excluded_hosts:
                 # nowhere to put an evacuated rank: surface rather
                 # than silently respawning on the host being drained

@@ -18,37 +18,36 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cpu_multiprocess_gap() -> str:
-    """Empty when this jax build can run cross-controller collectives
-    on the CPU backend; otherwise the missing capability, named.
+    """Empty when the installed jax can run cross-controller
+    collectives on the CPU backend; otherwise the missing capability,
+    named.
 
     The multi-controller bootstrap itself always works (it is our own
     coordination service), but the compiled collective needs the CPU
     client to be built WITH a cross-process collectives implementation
-    (gloo/mpi) and jax to expose the config knob that selects it —
+    (gloo) and jax to expose the config option that selects it —
     without both, XLA raises "Multiprocess computations aren't
     implemented on the CPU backend" at the first collective."""
-    try:
-        import jax
-    except Exception as e:  # pragma: no cover - jax is a hard dep
-        return f"jax unavailable ({e})"
+    import jax
+    import jaxlib
+
     if not hasattr(jax.config, "jax_cpu_collectives_implementation"):
-        return ("jax %s has no jax_cpu_collectives_implementation "
-                "option: the CPU backend cannot run multiprocess "
-                "computations" % jax.__version__)
+        return ("this jax has no jax_cpu_collectives_implementation "
+                "option")
     try:
-        from jaxlib import xla_extension as _xe
-    except Exception as e:
-        return f"jaxlib xla_extension unavailable ({e})"
-    if not hasattr(_xe, "make_gloo_tcp_collectives"):
-        return ("jaxlib built without gloo CPU collectives: the CPU "
-                "backend cannot run multiprocess computations")
+        from jaxlib import _jax
+    except ImportError as e:
+        return f"jaxlib {jaxlib.__version__} has no _jax module ({e})"
+    if not hasattr(_jax, "make_gloo_tcp_collectives"):
+        return (f"jaxlib {jaxlib.__version__} is built without gloo "
+                "CPU collectives")
     return ""
 
 
 _GAP = _cpu_multiprocess_gap()
 
-#: evaluated at collection time: stock containers ship a jaxlib whose
-#: CPU backend cannot run cross-controller collectives — skip with the
+#: evaluated at collection time: where the installed jaxlib's CPU
+#: backend cannot run cross-controller collectives, skip with the
 #: capability named instead of failing tier-1
 pytestmark = pytest.mark.skipif(
     bool(_GAP), reason=f"ess/distributed needs multiprocess CPU "
@@ -60,11 +59,9 @@ WORKER = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     os.environ["JAX_PLATFORMS"] = "cpu"
     import jax
-    jax.config.update("jax_platforms", "cpu")
-    if hasattr(jax.config, "jax_cpu_collectives_implementation"):
-        # the capability this test is gated on: cross-controller CPU
-        # collectives need an explicit implementation selected
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    # the capability this test is gated on: cross-controller CPU
+    # collectives need an explicit implementation selected
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     import numpy as np
     import ompi_release_tpu as mpi
     from ompi_release_tpu.runtime.runtime import Runtime

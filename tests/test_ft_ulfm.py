@@ -391,6 +391,50 @@ class TestHeartbeatMonitor:
             hnp.shutdown()
             w.close()
 
+    def test_started_beats_do_not_need_the_gil(self):
+        """start_heartbeats beats from the endpoint's native thread:
+        while this thread holds the GIL inside ONE C call for several
+        beat intervals (what the native-plan probe's bulk byte
+        searches did to a 64 MiB collective's first call), beats keep
+        arriving at the HNP — a Python beat thread sent none and the
+        job was torn down as HEARTBEAT_FAILED. Each beat carries the
+        resusage sample tpu-ps shows."""
+        import json
+        import os
+
+        from ompi_release_tpu.runtime.coordinator import TAG_HEARTBEAT
+
+        hnp, (w,) = self._pair(1)
+        try:
+            t0 = time.perf_counter()
+            sum(range(10 ** 6))
+            per = max(time.perf_counter() - t0, 1e-4)
+            w.start_heartbeats(0.05)
+            t0 = time.perf_counter()
+            sum(range(int(10 ** 6 * 0.6 / per)))  # ~0.6 s, GIL held
+            held = time.perf_counter() - t0
+            beats = []
+            try:  # what queued up during the hold, nothing later
+                while True:
+                    beats.append(hnp.ep.recv(tag=TAG_HEARTBEAT,
+                                             timeout_ms=1))
+            except MPIError:
+                pass
+            assert held >= 0.2, held
+            assert len(beats) >= held / 0.05 / 3, (len(beats), held)
+            src, _, raw = beats[-1]
+            ru = json.loads(raw)
+            assert src == 1 and ru["pid"] == os.getpid()
+            assert ru["rss"] > 0 and ru["vmsize"] >= ru["rss"]
+            w.stop_heartbeats()
+            time.sleep(0.15)  # one in flight at most, then silence
+            n = hnp.ep.pending()
+            time.sleep(0.2)
+            assert hnp.ep.pending() == n
+        finally:
+            hnp.shutdown()
+            w.close()
+
     def test_failure_callback_orders_with_errmgr_handle(self):
         """The promotion sequence an errmgr policy observes: epoch
         bump BEFORE the on_failure callback, so a policy that consults

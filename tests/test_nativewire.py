@@ -300,6 +300,44 @@ class TestSocketInterop:
             late.close()
             ShmRing.unlink(name)
 
+    def test_full_ring_stall_is_one_event_across_slices(self):
+        """Senders wait on a full ring in short slices (between them
+        they drain their own inbound rings) and retry the same record.
+        The ring keeps the stall open across the slices: one blocked
+        record is ONE ``w_stalls`` count, and ``w_stall_ns`` runs from
+        the first full-ring sighting to the write, the time between
+        slices included."""
+        from ompi_release_tpu.native import ShmRing
+
+        name = f"/onw-stalltest-{os.getpid()}"
+        ShmRing.unlink(name)
+        tx = ShmRing.create(name, 64 * 1024, os.getpid())
+        rx = ShmRing.attach(name, os.getpid())
+        try:
+            rec = bytes(40 * 1024)
+            assert tx.writev(5, [rec], 100) == 0  # fits: no stall
+            assert tx.stats()["w_stalls"] == 0
+            t0 = time.monotonic()
+            for _ in range(6):  # full: 6 slices of one stall
+                assert tx.writev(5, [rec], 5) == -1
+                time.sleep(0.01)  # the caller's work between slices
+            st = tx.stats()
+            assert st["w_stalls"] == 1, st
+            buf = bytearray(len(rec))
+            assert rx.read_into(buf, 100)[0] == len(rec)
+            assert tx.writev(5, [rec], 100) == 0  # the retry lands
+            total_ns = (time.monotonic() - t0) * 1e9
+            st = tx.stats()
+            assert st["w_stalls"] == 1, st
+            assert 0.06e9 <= st["w_stall_ns"] <= total_ns, (st, total_ns)
+            # the stall is closed: the next blocked record is a new one
+            assert tx.writev(5, [rec], 5) == -1
+            assert tx.stats()["w_stalls"] == 2
+        finally:
+            rx.close()
+            tx.close()
+            ShmRing.unlink(name)
+
 
 class TestSelectionAndFallback:
     """Graceful degradation is structural: MCA withdrawal + per-peer
@@ -529,6 +567,34 @@ class TestNativeJobs:
         for me in range(3):
             assert f"NW_PARITY_OK {me}" in out, out
         assert job.job_state.visited(JobState.TERMINATED)
+
+    def test_opposing_full_rings_do_not_deadlock(self, tmp_path, capfd):
+        """Every process of a schedule round posts its sends before it
+        reaps, so a collective past one ring of bytes parks all the
+        senders on full rings at once — pairwise and, with 3
+        processes, around a cycle. A sender that finds the ring full
+        takes its own arrivals off its inbound rings, so the round
+        completes (the ring is shrunk so 2 MiB is 8 rings of bytes)."""
+        body = """
+    world = mpi.init()
+    rt = Runtime.current()
+    off, n = rt.local_rank_offset, world.size
+    x = np.stack([np.full(1 << 19, off + i + 1, np.int32)
+                  for i in range(2)])
+    got = np.asarray(world.allreduce(x))
+    assert (got == n * (n + 1) // 2).all(), got[:, :4]
+    ag = np.asarray(world.allgather(x)).reshape(2, n, -1)
+    np.testing.assert_array_equal(ag[0, :, 0], np.arange(1, n + 1))
+    print(f"NW_FULL_OK {rt.bootstrap['process_index']}", flush=True)
+    mpi.finalize()
+"""
+        rc, out, _ = _run_job(
+            tmp_path, capfd, body, timeout=120,
+            mca=[("btl_nativewire_ring_bytes", 256 * 1024),
+                 ("wire_pipeline_segsize", 64 * 1024)])
+        assert rc == 0, out
+        for me in range(3):
+            assert f"NW_FULL_OK {me}" in out, out
 
     def test_tcp_vectored_collectives_parity_3proc(self, tmp_path,
                                                    capfd):

@@ -3,6 +3,7 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import ompi_release_tpu as mpi
@@ -39,6 +40,22 @@ class TestFenceEpochs:
             np.asarray(win.read())[3], np.full(4, 7.0)
         )
         win.fence_end()
+
+    def test_device_resident_origin(self, world, win):
+        """An origin buffer that already lives on one device (committed
+        there) must reach a window spread over all of them — through
+        the interpreted epoch program and its frozen-plan replay."""
+        dev = world.submesh.devices.flat[2]
+        for rep in range(3):  # capture, freeze, replay
+            val = jax.device_put(np.full(4, 5.0 + rep, np.float32), dev)
+            win.fence()
+            win.put(val, target=1)
+            win.accumulate(val, target=6)
+            win.fence_end()
+            np.testing.assert_array_equal(
+                np.asarray(win.read())[1], np.full(4, 5.0 + rep))
+        np.testing.assert_array_equal(
+            np.asarray(win.read())[6], np.full(4, 18.0))
 
     def test_rma_outside_epoch_raises(self, win):
         with pytest.raises(MPIError):
