@@ -1,0 +1,12 @@
+from perfbench import trace
+
+
+def read(facts, pattern, scale):
+    """Seconds of the host spans whose name matches ``pattern`` in the
+    traced slice, over the calls in it. The library writes no spans yet;
+    a metric over one it gains is this reader and a pattern."""
+    sl = facts["slice"]
+    if not sl or not sl["calls"]:
+        return None
+    seconds = trace.span_seconds(sl["spans"], pattern)
+    return seconds / sl["calls"] * scale if seconds else None
