@@ -49,6 +49,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 
 from .. import obs as _obs
+from ..obs import spans as _spans
 from ..obs import watchdog as _watchdog
 from ..mca import component as mca_component
 from ..mca import pvar as _pvar
@@ -733,16 +734,21 @@ class NativeWireBtl(DcnBtl):
             if ent is None or not ent[1].acquire(blocking=False):
                 continue
             ring, rlk, rstash = ent
-            tmp = bytearray(1 << 16)  # one scratch for the whole ring
             try:
-                while ring.pending() > 0:
-                    popped = self._pop_other_locked(ring, tmp)
-                    if popped is None:
-                        break
-                    _rlen, rtag, raw, tmp = popped
-                    rstash.setdefault(rtag, []).append(raw)
-                    _fallback_copies.add()  # the one restash copy
-                    moved = True
+                queued = ring.pending()
+                if queued <= 0:
+                    continue
+                tmp = bytearray(1 << 16)  # one scratch for the whole ring
+                # one span per ring drained, around its frame loop
+                with _obs.span(_spans.WIRE_STASH, bytes=queued):
+                    while ring.pending() > 0:
+                        popped = self._pop_other_locked(ring, tmp)
+                        if popped is None:
+                            break
+                        _rlen, rtag, raw, tmp = popped
+                        rstash.setdefault(rtag, []).append(raw)
+                        _fallback_copies.add()  # the one restash copy
+                        moved = True
             finally:
                 rlk.release()
         return moved

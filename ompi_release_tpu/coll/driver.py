@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 from .. import obs as _obs
 from ..mca import pvar
 from ..obs import skew as _skew
+from ..obs import spans as _spans
 
 _invoke_count = pvar.counter(
     "coll_invocations", "host-driver collective invocations"
@@ -117,6 +118,16 @@ def _arr_nbytes(x) -> int:
         return 0
 
 
+def _launch_span(missed: bool, key: Tuple):
+    """The span around a program call, from where ``_orch`` closes to
+    the call's return: ``ompi.coll.launch``, or ``ompi.coll.compile``
+    when the program-cache lookup missed (that call traces and compiles
+    before it launches — it must never fire inside a timed window)."""
+    if missed:
+        return _obs.span(_spans.COLL_COMPILE, op=_op_name(key))
+    return _obs.span(_spans.COLL_LAUNCH)
+
+
 def _program_cache(comm) -> Dict[Tuple, Callable]:
     cache = getattr(comm, "_coll_programs", None)
     if cache is None:
@@ -147,8 +158,9 @@ def run_sharded2d(comm, key: Tuple, body: Callable, x, *,
         )
     cache = _program_cache(comm)
     prog = cache.get(key)
-    _plan_cache.observe(0.0 if prog is None else 1.0)
-    if prog is None:
+    missed = prog is None
+    _plan_cache.observe(0.0 if missed else 1.0)
+    if missed:
         _compile_count.add()
         devs = _np.asarray(
             list(comm.submesh.devices.reshape(-1)), dtype=object
@@ -167,11 +179,12 @@ def run_sharded2d(comm, key: Tuple, body: Callable, x, *,
         )
         cache[key] = prog
     _orch.add(_time.perf_counter() - t_in)
-    if tok is None:
-        return prog(jnp.asarray(x))
-    _skew.body(tok)
-    out = prog(jnp.asarray(x))
-    _skew.end(tok, _arr_nbytes(x))
+    if tok is not None:
+        _skew.body(tok)
+    with _launch_span(missed, key):
+        out = prog(jnp.asarray(x))
+    if tok is not None:
+        _skew.end(tok, _arr_nbytes(x))
     return out
 
 
@@ -211,8 +224,9 @@ def run_sharded_spmd(comm, key: Tuple, body: Callable, local_x) -> Any:
     )
     cache = _program_cache(comm)
     prog = cache.get(key)
-    _plan_cache.observe(0.0 if prog is None else 1.0)
-    if prog is None:
+    missed = prog is None
+    _plan_cache.observe(0.0 if missed else 1.0)
+    if missed:
         _compile_count.add()
 
         def wrapper(xb):
@@ -227,7 +241,8 @@ def run_sharded_spmd(comm, key: Tuple, body: Callable, local_x) -> Any:
     _orch.add(_time.perf_counter() - t_in)
     if tok is not None:
         _skew.body(tok)
-    out = prog(garr)
+    with _launch_span(missed, key):
+        out = prog(garr)
     if tok is not None:
         _skew.end(tok, _arr_nbytes(local_x))
 
@@ -323,8 +338,9 @@ def run_sharded(comm, key: Tuple, body: Callable, x, *,
         _check_no_narrowing(arr)
     cache = _program_cache(comm)
     prog = cache.get(key)
-    _plan_cache.observe(0.0 if prog is None else 1.0)
-    if prog is None:
+    missed = prog is None
+    _plan_cache.observe(0.0 if missed else 1.0)
+    if missed:
         _compile_count.add()
         mesh = comm.submesh
         n_extra = len(extra_arrays)
@@ -351,14 +367,14 @@ def run_sharded(comm, key: Tuple, body: Callable, x, *,
         cap.append({"prog": prog, "x": x, "extra": bool(extra_arrays),
                     "out": None})
     _orch.add(_time.perf_counter() - t_in)
-    if tok is None:
-        out = prog(jnp.asarray(x),
-                   *[jnp.asarray(e) for e in extra_arrays])
-    else:
+    if tok is not None:
         # skew emit point: wait = arrival -> program launch (cache
         # lookup / compile / validation), body = the dispatch itself
         _skew.body(tok)
-        out = prog(jnp.asarray(x), *[jnp.asarray(e) for e in extra_arrays])
+    with _launch_span(missed, key):
+        out = prog(jnp.asarray(x),
+                   *[jnp.asarray(e) for e in extra_arrays])
+    if tok is not None:
         _skew.end(tok, _arr_nbytes(x))
     if cap is not None:
         cap[-1]["out"] = out

@@ -57,6 +57,7 @@ from __future__ import annotations
 import time as _time
 from typing import Callable, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -64,9 +65,11 @@ from .. import obs as _obs
 from ..mca import component as mca_component
 from ..mca import pvar
 from ..mca import var as mca_var
+from ..obs import spans as _spans
 from ..obs import watchdog as _watchdog
 from ..ops.op import Op
 from ..utils import output
+from ..runtime import progress as _progress
 from ..utils.errors import ErrorCode, MPIError
 from . import hier_schedules as _hs
 from . import topo_schedules as _topo
@@ -114,6 +117,27 @@ def _hier_rounds_snapshot() -> Dict[str, Dict]:
 _watchdog.add_contributor("hier_rounds", _hier_rounds_snapshot)
 
 
+def _d2h(x) -> np.ndarray:
+    """``np.asarray(x)``; when ``x`` is a device buffer the fetch is an
+    ``ompi.hier.d2h`` span. A value already on the host passes through
+    unmarked, so every conversion in this module can come here."""
+    if not isinstance(x, jax.Array):
+        return np.asarray(x)
+    with _obs.span(_spans.HIER_D2H, bytes=_spans.nbytes(x)):
+        return np.asarray(x)
+
+
+def _h2d(v):
+    """``jnp.asarray(v)``; when ``v`` is a host value the placement is
+    an ``ompi.hier.h2d`` span (until ``jnp.asarray`` returns: the
+    transfer itself may still be in flight). A device array passes
+    through unmarked."""
+    if isinstance(v, jax.Array):
+        return jnp.asarray(v)
+    with _obs.span(_spans.HIER_H2D, bytes=_spans.nbytes(v)):
+        return jnp.asarray(v)
+
+
 class _XchgAdapter:
     """The round transport :mod:`coll.hier_schedules` drives: one call
     posts ALL of a schedule round's sends (striped/pipelined by
@@ -137,19 +161,23 @@ class _XchgAdapter:
                  for p, arrs in sends.items() if arrs}
         recvs = {p: int(c) for p, c in recvs.items() if c > 0}
         got: Dict[int, list] = {p: [] for p in recvs}
-        if m._overlap():
-            if sends:
-                m._send_all(sends)
-            if recvs:
-                m._reap(dict(recvs),
-                        lambda src, arr: got[src].append(arr))
-            return got
-        for p in sorted(sends):
-            for a in sends[p]:
-                m._send(p, a)
-        for p in sorted(recvs):
-            for _ in range(recvs[p]):
-                got[p].append(m._recv(p))
+        with _obs.span(_spans.PLAN_XCHG, cid=m.comm.cid,
+                       seq=_progress.executing_seq(),
+                       bytes=sum(a.nbytes for arrs in sends.values()
+                                 for a in arrs)):
+            if m._overlap():
+                if sends:
+                    m._send_all(sends)
+                if recvs:
+                    m._reap(dict(recvs),
+                            lambda src, arr: got[src].append(arr))
+            else:
+                for p in sorted(sends):
+                    for a in sends[p]:
+                        m._send(p, a)
+                for p in sorted(recvs):
+                    for _ in range(recvs[p]):
+                        got[p].append(m._recv(p))
         return got
 
 
@@ -317,7 +345,8 @@ class _HierModule:
                                 comm_id=self.comm.cid, peer=peer,
                                 info=self._awaiting_info({peer: 1}))
         try:
-            out = np.asarray(self.router.coll_recv(self.comm, peer))
+            # the native wire hands arrivals over as device arrays
+            out = _d2h(self.router.coll_recv(self.comm, peer))
         finally:
             if tok is not None:
                 _watchdog.disarm(tok)
@@ -418,7 +447,11 @@ class _HierModule:
                 _inter_msgs_recvd.add()
                 pending[src] -= 1
                 left -= 1
-                arr = np.asarray(arr)
+                # the native wire hands arrivals over as device arrays
+                # (btl/nativewire.recv_staged ends in a device_put):
+                # this is their way back, per message, inside the
+                # exchange's span
+                arr = _d2h(arr)
                 if rec and _obs.enabled:
                     _obs.record("hier_recv", "hier", t0,
                                 _time.perf_counter() - t0,
@@ -509,9 +542,8 @@ class _HierModule:
 
     def _pack_partial(self, partial, op: Op) -> np.ndarray:
         if op.is_pair_op:
-            return self._pack_pair(np.asarray(partial[0]),
-                                   np.asarray(partial[1]))
-        return np.asarray(partial)
+            return self._pack_pair(_d2h(partial[0]), _d2h(partial[1]))
+        return _d2h(partial)
 
     def _unpack_partial(self, buf, like, op: Op):
         # `like` is read for shape/dtype/nbytes only — attributes jax
@@ -519,8 +551,8 @@ class _HierModule:
         # force a device fetch of the unchanged partial per peer)
         if op.is_pair_op:
             v, i = self._unpack_pair(buf, like[0], like[1])
-            return (jnp.asarray(v), jnp.asarray(i))
-        return jnp.asarray(np.asarray(buf).reshape(like.shape))
+            return (_h2d(v), _h2d(i))
+        return _h2d(np.asarray(buf).reshape(like.shape))
 
     @staticmethod
     def _fold(parts: list, op: Op):
@@ -610,8 +642,8 @@ class _HierModule:
         self._note_alg(alg)
         me = self.my_pidx
         if alg in _hs.ORDER_WAIVING:
-            arr = np.asarray(partial)
-            npop = lambda a, b: np.asarray(op(a, b))  # noqa: E731
+            arr = _d2h(partial)
+            npop = lambda a, b: _d2h(op(a, b))  # noqa: E731
             ident = op.identity_for(arr.dtype)
             if alg == "multiring":
                 out = _topo.allreduce_multiring(
@@ -625,7 +657,7 @@ class _HierModule:
                 fn = (_hs.allreduce_ring if alg == "ring"
                       else _hs.allreduce_rabenseifner)
                 out = fn(self._xchg, procs, me, arr, npop, ident)
-            return jnp.asarray(np.asarray(out).reshape(arr.shape))
+            return _h2d(np.asarray(out).reshape(arr.shape))
         if alg == "recursive_doubling":
             flats = _hs.allgather_bruck(
                 self._xchg, procs, me, packed,
@@ -667,7 +699,7 @@ class _HierModule:
         return total
 
     def _bcast_local_axis(self, value):
-        value = jnp.asarray(value)
+        value = _h2d(value)
         return jnp.broadcast_to(
             value[None], (self.local_n,) + value.shape
         )
@@ -676,7 +708,7 @@ class _HierModule:
     def _cat(parts: list) -> np.ndarray:
         """Concatenate per-rank slices the way all_gather+reshape does
         (0-d slices stack into a vector)."""
-        parts = [np.asarray(p) for p in parts]
+        parts = [_d2h(p) for p in parts]
         if parts[0].ndim == 0:
             return np.stack(parts)
         return np.concatenate(parts, axis=0)
@@ -771,11 +803,11 @@ class _HierModule:
             total = None  # recv buffer undefined off-root (zeros)
 
         def place(t):
-            out = np.zeros((self.local_n,) + np.asarray(t).shape,
-                           np.asarray(t).dtype)
+            t = _d2h(t)
+            out = np.zeros((self.local_n,) + t.shape, t.dtype)
             if total is not None and root in self.local_ranks:
-                out[self.local_ranks.index(root)] = np.asarray(t)
-            return jnp.asarray(out)
+                out[self.local_ranks.index(root)] = t
+            return _h2d(out)
 
         if op.is_pair_op:
             like = partial if total is None else total
@@ -799,9 +831,8 @@ class _HierModule:
         total = self._combine_partials(self._local_partial(x, op), op)
         if op.is_pair_op:
             tv, ti = total
-            return (jnp.asarray(chunked(np.asarray(tv))),
-                    jnp.asarray(chunked(np.asarray(ti))))
-        return jnp.asarray(chunked(np.asarray(total)))
+            return (_h2d(chunked(_d2h(tv))), _h2d(chunked(_d2h(ti))))
+        return _h2d(chunked(_d2h(total)))
 
     # -- data movement -----------------------------------------------------
     def bcast(self, comm, x, root: int):
@@ -809,13 +840,13 @@ class _HierModule:
         me = self.my_pidx
         if owner == me:
             self._check_local_axis(x, "bcast")
-            val = np.asarray(x[self.local_ranks.index(root)])
+            val = _d2h(x[self.local_ranks.index(root)])
         else:
             val = None
         # every rank passes an x of the same per-slice shape (the
         # driver-mode SPMD convention), so the decision byte count is
         # derivable symmetrically off-root too
-        xa = np.asarray(x)
+        xa = _d2h(x)
         slice_bytes = int(xa.nbytes // xa.shape[0]) if xa.ndim else 0
         alg = _hs.pick("bcast", len(self.procs), slice_bytes,
                        topo=self.torus_dims)
@@ -903,7 +934,7 @@ class _HierModule:
 
     def allgather(self, comm, x):
         self._check_local_axis(x, "allgather")
-        block = np.asarray(x)  # (local_n, chunk...)
+        block = _d2h(x)  # (local_n, chunk...)
         rows = self._gather_block_rows(block)
         full = self._cat([rows[r] for r in range(comm.size)])
         return self._bcast_local_axis(full)
@@ -913,7 +944,7 @@ class _HierModule:
         owner = self.owner[root]
         me = self.my_pidx
         P = len(self.procs)
-        block = np.asarray(x)
+        block = _d2h(x)
         full_shape = (comm.size * block.shape[1],) + block.shape[2:] \
             if block.ndim > 1 else (comm.size,)
         chunk_shape = block.shape[1:]
@@ -952,7 +983,7 @@ class _HierModule:
         full = self._cat([rows[r] for r in range(comm.size)])
         out = np.zeros((self.local_n,) + full.shape, full.dtype)
         out[self.local_ranks.index(root)] = full
-        return jnp.asarray(out)
+        return _h2d(out)
 
     def scatter(self, comm, x, root: int):
         n = comm.size
@@ -968,7 +999,7 @@ class _HierModule:
         chunks = None
         if owner == me:
             self._check_local_axis(x, "scatter")
-            full = np.asarray(x[self.local_ranks.index(root)])
+            full = _d2h(x[self.local_ranks.index(root)])
             if full.shape[0] % n:
                 raise MPIError(
                     ErrorCode.ERR_COUNT,
@@ -1001,12 +1032,12 @@ class _HierModule:
         else:
             # (local_n, chunk...)
             mine = self._xchg.exchange({}, {owner: 1})[owner][0]
-        return jnp.asarray(mine)
+        return _h2d(mine)
 
     def alltoall(self, comm, x):
         self._check_local_axis(x, "alltoall")
         n = comm.size
-        block = np.asarray(x)
+        block = _d2h(x)
         if block.shape[1] % n:
             raise MPIError(
                 ErrorCode.ERR_COUNT,
@@ -1063,7 +1094,7 @@ class _HierModule:
             for a, i in enumerate(self.members_of[p]):
                 for b in range(self.local_n):
                     out[b, i] = r[a, b]
-        return jnp.asarray(out.reshape(block.shape))
+        return _h2d(out.reshape(block.shape))
 
     # -- v-variant collectives (ragged; lists indexed by LOCAL member) -----
     # Spanning-comm analogue of coll/vcoll.py's driver-mode convention:
@@ -1080,7 +1111,7 @@ class _HierModule:
                 f"{what} on spanning {self.comm.name}: pass one buffer "
                 f"per LOCAL member ({self.local_n}), got {len(bufs)}",
             )
-        out = [np.asarray(b).reshape(-1) for b in bufs]
+        out = [_d2h(b).reshape(-1) for b in bufs]
         dtypes = {a.dtype for a in out}
         if len(dtypes) != 1:
             raise MPIError(
@@ -1158,8 +1189,8 @@ class _HierModule:
                 if self.owner[i] == self.my_pidx else from_peer[(i, j)]
                 for i in range(n)
             ]
-            recv.append(jnp.asarray(np.concatenate(parts) if parts
-                                    else np.zeros((0,), dtype)))
+            recv.append(_h2d(np.concatenate(parts) if parts
+                             else np.zeros((0,), dtype)))
         return recv
 
     def _gather_rows(self, bufs: List[np.ndarray]) -> Dict[int, np.ndarray]:
@@ -1185,7 +1216,7 @@ class _HierModule:
         every rank, returned once (the vcoll convention)."""
         bufs = self._ragged_local(sendbufs, "allgatherv")
         rows = self._gather_rows(bufs)
-        return jnp.asarray(
+        return _h2d(
             np.concatenate([rows[r] for r in range(comm.size)])
         )
 
@@ -1213,7 +1244,7 @@ class _HierModule:
         for p in self.peers:
             for r, arr in zip(self.members_of[p], got[p]):
                 rows[r] = np.asarray(arr)
-        return jnp.asarray(np.concatenate([rows[r] for r in range(n)]))
+        return _h2d(np.concatenate([rows[r] for r in range(n)]))
 
     def scatterv(self, comm, sendbuf, counts, root: int):
         """Root's owner splits ``sendbuf`` by ``counts`` and ships each
@@ -1231,8 +1262,8 @@ class _HierModule:
         owner = self.owner[root]
         if owner != self.my_pidx:
             got = self._xchg.exchange({}, {owner: self.local_n})
-            return [jnp.asarray(a) for a in got[owner]]
-        buf = np.asarray(sendbuf).reshape(-1)
+            return [_h2d(a) for a in got[owner]]
+        buf = _d2h(sendbuf).reshape(-1)
         from .driver import _check_no_narrowing
 
         _check_no_narrowing(buf)
@@ -1246,7 +1277,7 @@ class _HierModule:
         chunks = [buf[offs[j]:offs[j] + counts[j]] for j in range(n)]
         self._xchg.exchange({p: [chunks[j] for j in self.members_of[p]]
                              for p in self.peers}, {})
-        return [jnp.asarray(chunks[j]) for j in self.local_ranks]
+        return [_h2d(chunks[j]) for j in self.local_ranks]
 
     def reduce_scatter(self, comm, x, recvcounts, op: Op):
         """General MPI_Reduce_scatter: combine (local partial, then
@@ -1264,7 +1295,7 @@ class _HierModule:
         if op.is_pair_op:
             vals, idxs = x
             self._check_local_axis(vals, "reduce_scatter")
-            vals = np.asarray(vals)
+            vals = _d2h(vals)
             if vals.reshape(self.local_n, -1).shape[1] != total:
                 raise MPIError(
                     ErrorCode.ERR_COUNT,
@@ -1274,14 +1305,14 @@ class _HierModule:
             tv, ti = self._combine_partials(
                 self._local_partial((vals, idxs), op), op
             )
-            tv, ti = np.asarray(tv).reshape(-1), np.asarray(ti).reshape(-1)
+            tv, ti = _d2h(tv).reshape(-1), _d2h(ti).reshape(-1)
             offs = np.concatenate([[0], np.cumsum(recvcounts)])
             return [
-                (jnp.asarray(tv[offs[r]:offs[r] + recvcounts[r]]),
-                 jnp.asarray(ti[offs[r]:offs[r] + recvcounts[r]]))
+                (_h2d(tv[offs[r]:offs[r] + recvcounts[r]]),
+                 _h2d(ti[offs[r]:offs[r] + recvcounts[r]]))
                 for r in self.local_ranks
             ]
-        x = np.asarray(x)
+        x = _d2h(x)
         from .driver import _check_no_narrowing
 
         _check_no_narrowing(x)  # BEFORE the jnp conversion below
@@ -1293,17 +1324,17 @@ class _HierModule:
                 f"{total}), got {x.shape}",
             )
         x = x.reshape(self.local_n, total)
-        red = np.asarray(self._combine_partials(
-            self._local_partial(jnp.asarray(x), op), op
+        red = _d2h(self._combine_partials(
+            self._local_partial(_h2d(x), op), op
         ))
         offs = np.concatenate([[0], np.cumsum(recvcounts)])
-        return [jnp.asarray(red[offs[r]:offs[r] + recvcounts[r]])
+        return [_h2d(red[offs[r]:offs[r] + recvcounts[r]])
                 for r in self.local_ranks]
 
     # -- prefix scans ------------------------------------------------------
     def _full_rows(self, x) -> Dict[int, np.ndarray]:
         """Every rank's slice, via the selected allgather schedule."""
-        return self._gather_block_rows(np.asarray(x))
+        return self._gather_block_rows(_d2h(x))
 
     def _scan_impl(self, comm, x, op: Op, exclusive: bool):
         if op.is_pair_op:
@@ -1321,14 +1352,12 @@ class _HierModule:
                     outv.append(np.zeros_like(vrows[0]))
                     outi.append(np.zeros_like(irows[0]))
                     continue
-                acc = (jnp.asarray(vrows[0]), jnp.asarray(irows[0]))
+                acc = (_h2d(vrows[0]), _h2d(irows[0]))
                 for j in range(1, end):
-                    acc = op(acc, (jnp.asarray(vrows[j]),
-                                   jnp.asarray(irows[j])))
-                outv.append(np.asarray(acc[0]))
-                outi.append(np.asarray(acc[1]))
-            return (jnp.asarray(np.stack(outv)),
-                    jnp.asarray(np.stack(outi)))
+                    acc = op(acc, (_h2d(vrows[j]), _h2d(irows[j])))
+                outv.append(_d2h(acc[0]))
+                outi.append(_d2h(acc[1]))
+            return (_h2d(np.stack(outv)), _h2d(np.stack(outi)))
         self._check_local_axis(x, "scan")
         rows = self._full_rows(x)
         out = []
@@ -1337,15 +1366,15 @@ class _HierModule:
                 if r == 0:
                     out.append(np.zeros_like(rows[0]))
                     continue
-                acc = jnp.asarray(rows[0])
+                acc = _h2d(rows[0])
                 for j in range(1, r):
-                    acc = op(acc, jnp.asarray(rows[j]))
+                    acc = op(acc, _h2d(rows[j]))
             else:
-                acc = jnp.asarray(rows[0])
+                acc = _h2d(rows[0])
                 for j in range(1, r + 1):
-                    acc = op(acc, jnp.asarray(rows[j]))
-            out.append(np.asarray(acc))
-        return jnp.asarray(np.stack(out))
+                    acc = op(acc, _h2d(rows[j]))
+            out.append(_d2h(acc))
+        return _h2d(np.stack(out))
 
     def scan(self, comm, x, op: Op):
         return self._scan_impl(comm, x, op, exclusive=False)

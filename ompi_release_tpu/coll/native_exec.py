@@ -54,6 +54,7 @@ import numpy as np
 from .. import obs as _obs
 from ..mca import pvar
 from ..mca import var as mca_var
+from ..obs import spans as _spans
 from ..utils.errors import ErrorCode, MPIError
 
 #: bytes held by the per-plan native reassembly pools (the
@@ -675,15 +676,18 @@ class NativeXchg:
     veto (stashed frames, lock contention) delegates the entire fire
     to a fresh :class:`~.plan.PlannedXchg` — same plan, same bytes."""
 
-    __slots__ = ("m", "plan", "np", "i", "ts", "args", "_delegate",
-                 "_pool", "_c_wait")
+    __slots__ = ("m", "plan", "np", "i", "ts", "args", "seq",
+                 "_delegate", "_pool", "_c_wait")
 
     def __init__(self, module, plan, npl: NativePlan,
-                 args: Tuple) -> None:
+                 args: Tuple, seq: int = 0) -> None:
         self.m = module
         self.plan = plan
         self.np = npl
         self.i = 0
+        #: the schedule's posting seq: joins this fire's span to its
+        #: ``ompi.nbc.wait``
+        self.seq = seq
         self.ts: Optional[List[float]] = None
         self.args = args
         self._delegate = None
@@ -739,7 +743,7 @@ class NativeXchg:
         if self.i == 0 and not self._fire(sends_f):
             _native_fallbacks.add()
             from .plan import PlannedXchg
-            dg = PlannedXchg(self.m, plan)
+            dg = PlannedXchg(self.m, plan, self.seq)
             dg.ts = self.ts
             self._delegate = dg
             return dg.exchange(sends, recvs)
@@ -805,7 +809,6 @@ class NativeXchg:
             return False
         held.append(chan)
         fired = False
-        t0 = _time.perf_counter()
         try:
             for _p, _kind, lk in npl.fire_locks:
                 if not lk.acquire(blocking=False):
@@ -827,8 +830,14 @@ class NativeXchg:
             if px.fire_begin(inputs, base, npl.timeout_ms) != 0:
                 return False
             fired = True
-            self._run(px, npl, epoch0)
-            self._harvest(px, npl, t0)
+            # every veto above withdrew before a byte moved: the span
+            # is the C walk itself, and closes before a delegate's
+            # ``ompi.plan.xchg`` could open
+            with _obs.span(_spans.PLAN_NATIVE_FIRE,
+                           journal=("plan_native_fire", "plan"),
+                           cid=npl.cid, seq=self.seq):
+                self._run(px, npl, epoch0)
+                self._harvest(px, npl)
             return True
         finally:
             if fired:
@@ -926,7 +935,7 @@ class NativeXchg:
                     stash.setdefault((router._nid(pidx), tag),
                                      []).append(raw)
 
-    def _harvest(self, px, npl: NativePlan, t0: float) -> None:
+    def _harvest(self, px, npl: NativePlan) -> None:
         self._pool = px.pool_view()
         if self.ts is not None:
             self.ts[:] = px.round_ts()
@@ -951,9 +960,6 @@ class NativeXchg:
             btl.staged_bytes_pvar.add(npl.send_bytes + npl.recv_bytes)
         _pool_hits.add(npl.pool_count)
         _native_fires.add()
-        if _obs.enabled:
-            _obs.record("plan_native_fire", "plan", t0,
-                        _time.perf_counter() - t0, comm_id=npl.cid)
 
     def _materialize(self, r: int) -> Dict[int, list]:
         npl = self.np

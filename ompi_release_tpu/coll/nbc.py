@@ -53,6 +53,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 from .. import obs as _obs
 from ..mca import pvar
 from ..obs import sentinel as _sentinel
+from ..obs import spans as _spans
 from ..request.request import Request
 from ..runtime import progress as _progress
 from ..utils.errors import ErrorCode, MPIError
@@ -133,7 +134,8 @@ def _op_request(op: _progress.ScheduledOp) -> Request:
             raise _op.error
 
     def block(_op=op, _eng=eng) -> None:
-        _eng.wait(_op)  # raises the schedule's error
+        with _obs.span(_spans.NBC_WAIT, cid=_op.cid, seq=_op.seq):
+            _eng.wait(_op)  # raises the schedule's error
 
     req = Request(progress_fn=prog, block_fn=block)
     # expose the schedule handle: per-pass consumers (parallel/tree's
@@ -278,7 +280,8 @@ def run_blocking(comm, name: str, fn: Callable, args: Tuple,
     op = _make_op(comm, name, run, args, kw)
     _post(comm, op)
     _orch.add(_time.perf_counter() - t0)
-    return eng.wait(op)
+    with _obs.span(_spans.NBC_WAIT, cid=comm.cid, seq=op.seq):
+        return eng.wait(op)
 
 
 def submit(comm, name: str, fn: Callable, args: Tuple,

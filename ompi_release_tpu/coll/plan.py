@@ -83,7 +83,9 @@ from .. import obs as _obs
 from ..mca import pvar
 from ..mca import var as mca_var
 from ..obs import ledger as _ledger
+from ..obs import spans as _spans
 from ..obs import watchdog as _watchdog
+from ..runtime import progress as _progress
 from ..utils.errors import ErrorCode, MPIError
 
 #: plan-cache outcome per plannable collective fire: 1 = a frozen plan
@@ -392,9 +394,10 @@ def dispatch(comm, name: str, fn: Callable, args: Tuple,
             # exactly where run_sharded closes it on the interpreted
             # leg — the two legs time the identical span
             d._orch.add(_time.perf_counter() - t0)
+            with _obs.span(_spans.COLL_LAUNCH):
+                out = prog(_jnp.asarray(args[0]))
             if not obs_on:
-                return prog(_jnp.asarray(args[0]))
-            out = prog(_jnp.asarray(args[0]))
+                return out
             lid = e.get("lid")
             if lid is None:
                 lid = e["lid"] = _ledger.register_device_plan(
@@ -599,12 +602,15 @@ class PlannedXchg:
     in arrival order. Divergence is a loud typed error — frames from
     a wrong header would corrupt the peer's reassembly."""
 
-    __slots__ = ("m", "plan", "i", "ts")
+    __slots__ = ("m", "plan", "i", "ts", "seq")
 
-    def __init__(self, module, plan: WirePlan) -> None:
+    def __init__(self, module, plan: WirePlan, seq: int = 0) -> None:
         self.m = module
         self.plan = plan
         self.i = 0
+        #: the schedule's posting seq: joins this fire's
+        #: ``ompi.plan.xchg`` spans to its ``ompi.nbc.wait``
+        self.seq = seq
         #: round-end clock reads for the flight recorder (one
         #: perf_counter per planned round); None = unobserved fire,
         #: zero clock reads
@@ -655,18 +661,21 @@ class PlannedXchg:
                 f"sends/recvs {meta}/{recvs_l} != frozen "
                 f"{rnd.sends_meta}/{rnd.recvs}")
         m = self.m
-        if sends_f:
-            m._send_all_planned(rnd, sends_f)
         got: Dict[int, list] = {p: [] for p in rnd.recvs}
-        if rnd.recvs:
-            # record=False: the flight recorder owns this fire's
-            # span/flow story (expanded from the plan structure at
-            # doctor time) — per-arrival journal spans here would
-            # duplicate the synthetic ones and advance the hier
-            # flow-k counters the expansion re-derives from zero
-            m._reap(dict(rnd.recvs),
-                    lambda src, arr: got[src].append(arr),
-                    plan.timeout_ms, record=False)
+        with _obs.span(_spans.PLAN_XCHG, cid=plan.cid, seq=self.seq,
+                       bytes=sum(a.nbytes for arrs in sends_f.values()
+                                 for a in arrs)):
+            if sends_f:
+                m._send_all_planned(rnd, sends_f)
+            if rnd.recvs:
+                # record=False: the flight recorder owns this fire's
+                # span/flow story (expanded from the plan structure at
+                # doctor time) — per-arrival journal spans here would
+                # duplicate the synthetic ones and advance the hier
+                # flow-k counters the expansion re-derives from zero
+                m._reap(dict(rnd.recvs),
+                        lambda src, arr: got[src].append(arr),
+                        plan.timeout_ms, record=False)
         ts = self.ts
         if ts is not None:
             ts.append(_time.perf_counter())
@@ -763,12 +772,13 @@ class SpanningPlanState:
                 # runs fully interpreted (complete span/flow record);
                 # the frozen plan survives for the next fire
                 return fn(*args, **kw)
+        seq = _progress.executing_seq()
         nx = self.native
         if nx is not None and nx.gen == plan.gen:
             from . import native_exec as _native
-            px = _native.NativeXchg(m, plan, nx, args)
+            px = _native.NativeXchg(m, plan, nx, args, seq)
         else:
-            px = PlannedXchg(m, plan)
+            px = PlannedXchg(m, plan, seq)
         t0 = 0.0
         if rec:
             if plan.ledger_id is None:

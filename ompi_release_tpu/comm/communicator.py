@@ -29,8 +29,10 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs as _obs
 from ..mca import pvar
 from ..obs import sentinel as _sentinel
+from ..obs import spans as _spans
 from ..utils import output
 from ..utils.errors import Errhandler, ErrorCode, MPIError, ERRORS_ARE_FATAL
 from .group import Group, UNDEFINED
@@ -653,33 +655,47 @@ class Communicator:
                 # the comm's signature chain too (chain determinism,
                 # the post-hoc journal record); spanning comms note
                 # inside nbc.run_blocking where the args are bound
-                def noted(comm_, *a, **k):
+                def run(comm_, *a, **k):
                     _sentinel.note(self, op_name, a, k)
                     return _plan.dispatch(comm_, op_name, fn, a, k)
+            else:
+                def run(comm_, *a, **k):
+                    return _plan.dispatch(comm_, op_name, fn, a, k)
+        else:
+            # fast ULFM fail: a collective involves every member, so a
+            # known-failed member process fails the op NOW with the
+            # typed error instead of posting a schedule doomed to park
+            from ..ft import ulfm as _ulfm
 
-                return noted
-            return lambda comm_, *a, **k: _plan.dispatch(
-                comm_, op_name, fn, a, k)
-        # fast ULFM fail: a collective involves every member, so a
-        # known-failed member process fails the op NOW with the typed
-        # error instead of posting a schedule doomed to park
-        from ..ft import ulfm as _ulfm
+            _ulfm.state().check_wait(
+                self.cid, self._member_procs(),
+                f"collective {op_name} on {self.name} with member process",
+                epoch0=self._ft_epoch0)
+            # spanning comms: EVERY collective — blocking or not — goes
+            # through the async progress engine as "post schedule +
+            # wait", so blocking and nonblocking calls execute in
+            # posting order on every process (their wire exchanges
+            # share one per-cid channel, and two concurrently-running
+            # collectives would interleave frames on it) and there is
+            # ONE round-advancing code path (coll/nbc + runtime/progress)
+            from ..coll import nbc as _nbc
 
-        _ulfm.state().check_wait(
-            self.cid, self._member_procs(),
-            f"collective {op_name} on {self.name} with member process",
-            epoch0=self._ft_epoch0)
-        # spanning comms: EVERY collective — blocking or not — goes
-        # through the async progress engine as "post schedule + wait",
-        # so blocking and nonblocking calls execute in posting order on
-        # every process (their wire exchanges share one per-cid
-        # channel, and two concurrently-running collectives would
-        # interleave frames on it) and there is ONE round-advancing
-        # code path (coll/nbc + runtime/progress)
-        from ..coll import nbc as _nbc
+            def run(comm_, *a, **k):
+                return _nbc.run_blocking(self, op_name, fn,
+                                         (comm_,) + a, k)
+        if self.cid < 0:
+            # runtime-internal comms (the hier shadow) write no call
+            # span: only USER-visible collectives do, the rule
+            # coll_compiled_cache_hits already keeps
+            return run
 
-        return lambda comm_, *a, **k: _nbc.run_blocking(
-            self, op_name, fn, (comm_,) + a, k)
+        def call(comm_, *a, **k):
+            with _obs.span(_spans.COLL_CALL, op=op_name,
+                           cid=self.cid,
+                           bytes=_spans.nbytes(a[0]) if a else 0):
+                return run(comm_, *a, **k)
+
+        return call
 
     def _run_serialized(self, fn, *args, **kw):
         """Run ``fn`` in the comm's collective posting order, blocking

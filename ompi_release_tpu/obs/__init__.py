@@ -25,11 +25,14 @@ exercises every pvar class and exporter round-trip, device-free.
 from __future__ import annotations
 
 import os
+import time as _time
 
 from ..mca import pvar as _pvar
 from ..mca import var as _var
 from . import journal as journal_mod
 from .journal import Journal, Span, flow_id  # noqa: F401  (public API)
+from . import spans  # noqa: F401  (the span names)
+from .spans import span  # noqa: F401  (THE emit helper)
 
 #: THE hot-path gate: emit points check ``obs.enabled`` and do nothing
 #: else when False. One module attribute, mutated only by
@@ -75,6 +78,31 @@ _pvar.PVARS.register(
 _clock_state: dict = {"offset_s": None, "rtt_s": None, "source": None}
 
 
+#: ``(time.perf_counter(), time.time_ns())`` read together by
+#: :func:`enable`: the journal's timebase is ``perf_counter``, a
+#: profiler trace's is the wall clock (its ``profile_start_time`` plus
+#: each event's offset), and this pair lays one over the other. The
+#: dumps carry it (``obs/export.py``).
+_clock_anchor = None
+
+
+def clock_anchor():
+    """``{"perf_counter_s", "time_ns"}`` of the last :func:`enable`,
+    or None if the plane was never on."""
+    if _clock_anchor is None:
+        return None
+    return {"perf_counter_s": _clock_anchor[0],
+            "time_ns": _clock_anchor[1]}
+
+
+def wall_ns(t: float):
+    """A journal timestamp (``perf_counter`` seconds) as wall-clock
+    nanoseconds through the anchor; None without one."""
+    if _clock_anchor is None:
+        return None
+    return _clock_anchor[1] + int((t - _clock_anchor[0]) * 1e9)
+
+
 def rank_identity() -> dict:
     """Best-effort process identity (pid, pidx, world-rank span) — THE
     shared derivation behind both the postmortem's ``rank`` block and
@@ -111,11 +139,12 @@ def clock_offset():
 def enable(size: int = None) -> None:
     """Turn the plane on; the journal takes ``obs_journal_size`` (or
     the explicit ``size``) without losing already-buffered spans."""
-    global enabled
+    global enabled, _clock_anchor
     if size is None:
         size = int(_var.get("obs_journal_size", journal_mod.DEFAULT_SIZE))
     if int(size) != journal.size:
         journal.resize(int(size))
+    _clock_anchor = (_time.perf_counter(), _time.time_ns())
     enabled = True
     from . import sentinel as _sentinel
     from . import watchdog as _wd

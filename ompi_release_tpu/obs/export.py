@@ -75,7 +75,12 @@ def chrome_trace(spans: Optional[Sequence[Span]] = None) -> Dict[str, Any]:
          "args": {"name": layer}}
         for layer, tid in sorted(tids.items(), key=lambda kv: kv[1])
     ]
-    return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+    from .. import obs as _obs
+
+    # ``ts`` is perf_counter microseconds; the anchor maps it onto the
+    # wall clock a profiler trace (XPlane) is stamped with
+    return {"traceEvents": meta + events, "displayTimeUnit": "ms",
+            "otherData": {"clock_anchor": _obs.clock_anchor()}}
 
 
 def dump_chrome_trace(path: str,
@@ -86,11 +91,19 @@ def dump_chrome_trace(path: str,
 
 
 def dump_jsonl(path: str, spans: Optional[Sequence[Span]] = None) -> str:
+    from .. import obs as _obs
+
     if spans is None:
         spans = _JOURNAL.snapshot()
     with open(path, "w") as f:
         for s in spans:
-            f.write(json.dumps(s.asdict()) + "\n")
+            d = s.asdict()
+            # the anchored start, so a line can be laid over an XPlane
+            # without the dump's header (this format has none)
+            wall = _obs.wall_ns(s.t_start)
+            if wall is not None:
+                d["wall_ns"] = wall
+            f.write(json.dumps(d) + "\n")
     return path
 
 
@@ -119,6 +132,9 @@ def rank_dump(clock_sync: bool = True) -> Dict[str, Any]:
             pass  # offset stays at its last/None value
     meta["clock_offset_s"] = _obs._clock_state["offset_s"]
     meta["clock_rtt_s"] = _obs._clock_state["rtt_s"]
+    # perf_counter -> wall clock: lays this dump (and the ledger dump
+    # written beside it, same timebase) over a profiler trace
+    meta["clock_anchor"] = _obs.clock_anchor()
     from . import sentinel as _sentinel
 
     if _sentinel.enabled:

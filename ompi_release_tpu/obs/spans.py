@@ -1,0 +1,129 @@
+"""Library spans on the profiler's clock — the one emit helper.
+
+:func:`span` opens a ``jax.profiler.TraceAnnotation`` (a ``TraceMe``)
+under one of the names below, so the library's layer boundaries land in
+the profiler's own trace (``/host:CPU`` of the XPlane), on the clock
+the device planes share. ``TraceMe`` records only while a profiler
+session is open: THAT is the gate — no cvar, no environment variable.
+With no session a site costs one ``TraceAnnotation`` construction.
+
+When ``obs.enabled`` is true and the site names a ``journal`` pair, the
+same interval is also journaled on exit under that existing
+``(op, layer)`` name, so such a site needs no hand-written
+``rec = _obs.enabled; t0 = ...; _obs.record(...)`` triple.
+
+Names are few and exact (metrics match them with ``^...$`` patterns);
+what varies per call — ``op``, ``cid``, ``seq``, ``bytes`` — rides as
+keyword stats of the event, never folded into the name:
+
+``ompi.coll.call``         a user-visible communicator's collective,
+                           entry to return (``op``, ``cid``, ``bytes``:
+                           the buffer handed in by this process)
+``ompi.coll.launch``       the compiled program's call, host side: from
+                           where ``coll_orchestration_seconds`` closes
+                           to the call's return (nested in ``call``)
+``ompi.coll.compile``      the same call when the program-cache lookup
+                           missed: trace + compile + first launch (``op``)
+``ompi.nbc.wait``          the caller parked on (or running inline) a
+                           posted spanning schedule (``cid``, ``seq``)
+``ompi.plan.native_fire``  one frozen wire plan walked by the C executor:
+                           the exchange and the wait for the peer
+                           (``cid``, ``seq``)
+``ompi.plan.xchg``         one exchange of a schedule round in Python:
+                           planned replay or the interpreted adapter
+                           (``cid``, ``seq``, ``bytes`` sent)
+``ompi.hier.d2h``          a device buffer fetched to the host (``bytes``)
+``ompi.hier.h2d``          a host result placed on the device (``bytes``)
+``ompi.wire.stash``        a sender draining one of its own inbound rings
+                           because the peer's ring is full (``bytes``
+                           queued in that ring)
+
+``seq`` is the posted schedule's ``ScheduledOp.seq`` (process-local): a
+schedule may run on another thread than its ``ompi.coll.call``
+(progress thread, kick drainer), and ``(cid, seq)`` then joins
+``ompi.nbc.wait`` to the exchanges it waited for. On one thread nesting
+is the link.
+"""
+
+from __future__ import annotations
+
+import math
+import time as _time
+from typing import Optional, Tuple
+
+COLL_CALL = "ompi.coll.call"
+COLL_LAUNCH = "ompi.coll.launch"
+COLL_COMPILE = "ompi.coll.compile"
+NBC_WAIT = "ompi.nbc.wait"
+PLAN_NATIVE_FIRE = "ompi.plan.native_fire"
+PLAN_XCHG = "ompi.plan.xchg"
+HIER_D2H = "ompi.hier.d2h"
+HIER_H2D = "ompi.hier.h2d"
+WIRE_STASH = "ompi.wire.stash"
+
+NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
+         PLAN_NATIVE_FIRE, PLAN_XCHG, HIER_D2H, HIER_H2D, WIRE_STASH)
+
+#: ``jax.profiler.TraceAnnotation`` and the ``obs`` package, bound on
+#: the first span: importing ``obs`` must not import jax (``obs
+#: --selftest`` is device-free), and ``obs`` imports this module
+_annotation = None
+_obs = None
+
+
+def _bind():
+    global _annotation, _obs
+    from jax.profiler import TraceAnnotation
+
+    from .. import obs
+
+    _annotation, _obs = TraceAnnotation, obs
+    return TraceAnnotation
+
+
+def nbytes(x) -> int:
+    """Bytes of an array from its shape and dtype, 0 for anything else
+    (a pair-op tuple, no buffer): the ``bytes`` stat of a hot site,
+    where jax's own ``nbytes`` property would cost more than the span."""
+    try:
+        return math.prod(x.shape) * x.dtype.itemsize
+    except AttributeError:
+        return 0
+
+
+class _Journaled:
+    """A span that also journals its interval on exit (obs enabled)."""
+
+    __slots__ = ("_ta", "_journal", "_stats", "_t0")
+
+    def __init__(self, ta, journal: Tuple[str, str], stats: dict) -> None:
+        self._ta = ta
+        self._journal = journal
+        self._stats = stats
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_Journaled":
+        self._ta.__enter__()
+        self._t0 = _time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = _time.perf_counter() - self._t0
+        self._ta.__exit__(*exc)
+        if _obs.enabled and exc[0] is None:
+            op, layer = self._journal
+            st = self._stats
+            _obs.record(op, layer, self._t0, dt,
+                        nbytes=int(st.get("bytes", 0)),
+                        comm_id=int(st.get("cid", -1)))
+
+
+def span(name: str, journal: Optional[Tuple[str, str]] = None, **stats):
+    """Context manager for one library span (see the module docstring).
+    ``name`` is one of this module's constants, ``stats`` the event's
+    keyword metadata; ``journal`` is the site's existing journal
+    ``(op, layer)`` name, written too while ``obs.enabled``."""
+    ta = (_annotation or _bind())(name, **stats)
+    if journal is not None and _obs.enabled:
+        return _Journaled(ta, journal, stats)
+    return ta

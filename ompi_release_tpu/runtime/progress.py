@@ -670,6 +670,14 @@ def engine() -> ProgressEngine:
     return ENGINE
 
 
+def executing_seq() -> int:
+    """Posting seq of the schedule the CURRENT thread is executing (0:
+    none) — what the exchange spans carry so a trace joins them to the
+    ``ompi.nbc.wait`` of the same ``(cid, seq)``."""
+    op = ENGINE.executing()
+    return op.seq if op is not None else 0
+
+
 pvar.PVARS.register(
     "nbc_schedules_inflight", pvar.PvarClass.LEVEL,
     "nonblocking collective schedules posted but not yet complete",

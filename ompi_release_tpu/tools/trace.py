@@ -6,8 +6,9 @@ relinking via weak PMPI symbols (``ompi/mpi/c/init.c:32``) and ships
 communicator in :func:`wrap` and every collective/p2p call is recorded
 (name, wall time, payload bytes) to an event list, optional JSONL
 sink, and per-operation timing pvars — without touching the wrapped
-object or the call sites. ``profiler_trace`` bridges to the JAX
-profiler (XPlane) for device-side timelines.
+object or the call sites. ``profiler_trace`` opens a JAX profiler
+session (XPlane): the device's timeline with the library's own spans
+(``obs/spans.py``) on the same clock.
 """
 
 from __future__ import annotations
@@ -133,11 +134,42 @@ def wrap(comm, sink_path: Optional[str] = None) -> TracingComm:
 
 @contextlib.contextmanager
 def profiler_trace(logdir: str):
-    """Device-side profiling via the JAX profiler (XPlane/TensorBoard),
-    the VampirTrace analogue for the compiled data plane."""
+    """A JAX profiler session around the ``with`` body (the VampirTrace
+    analogue), started with the options the benchmark's traced runs use
+    (``python_tracer_level = 0``: no per-Python-call events, so tracing
+    does not slow the host path it observes). It writes
+    ``<logdir>/plugins/profile/<time>/<host>.xplane.pb``; read it with
+    ``jax.profiler.ProfileData.from_file`` or TensorBoard's profile
+    plugin. Only the process that holds the chip can trace it.
+
+    While the session is open the library writes these host spans into
+    the trace (plane ``/host:CPU``), on the clock of the device planes;
+    what varies per call rides as event stats, never in the name:
+
+    - ``ompi.coll.call`` — a user-visible communicator's collective,
+      entry to return (``op``, ``cid``, ``bytes``)
+    - ``ompi.coll.launch`` — the compiled program's call, host side
+    - ``ompi.coll.compile`` — the same call when the program was not
+      cached: it traces and compiles first (``op``)
+    - ``ompi.nbc.wait`` — the caller parked on, or running, a posted
+      spanning schedule (``cid``, ``seq``)
+    - ``ompi.plan.native_fire`` — a frozen wire plan walked by the C
+      executor: the exchange and the wait for the peer (``cid``, ``seq``)
+    - ``ompi.plan.xchg`` — one exchange of a schedule round in Python,
+      planned or interpreted (``cid``, ``seq``, ``bytes``)
+    - ``ompi.hier.d2h`` / ``ompi.hier.h2d`` — a device buffer fetched
+      to the host / a host result placed on the device (``bytes``)
+    - ``ompi.wire.stash`` — a sender draining its own inbound ring
+      because the peer's is full (``bytes``)
+
+    ``(cid, seq)`` joins an exchange to its ``ompi.nbc.wait`` when the
+    schedule ran on another thread; on one thread nesting is the link.
+    """
     import jax
 
-    jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
     try:
         yield
     finally:

@@ -27,6 +27,8 @@ Gating shapes recognized:
 import ast
 import os
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CHECKED = ("ompi_release_tpu/coll/pipeline.py",
            "ompi_release_tpu/coll/fusion.py",
@@ -263,6 +265,92 @@ def test_hot_path_emit_sites_are_gated():
             f"_obs.record emit site")
         checked_any_gate += 1
     assert checked_any_gate == len(CHECKED)
+
+
+# -- library spans (obs.span): few exact names, one helper --------------------
+
+SPAN_FILES = CHECKED + ("ompi_release_tpu/comm/communicator.py",
+                        "ompi_release_tpu/coll/driver.py")
+SPANS_MODULE = "ompi_release_tpu/obs/spans.py"
+
+
+def _span_name_violations(tree, rel):
+    """``span(...)`` calls whose name is not a string literal or a
+    constant (``NAME`` / ``module.NAME``, all capitals): an f-string or
+    a computed name would multiply the names a metric's exact pattern
+    has to match."""
+    out = []
+    for n in ast.walk(tree):
+        if not (isinstance(n, ast.Call) and (
+                (isinstance(n.func, ast.Attribute) and n.func.attr == "span")
+                or (isinstance(n.func, ast.Name) and n.func.id == "span"))):
+            continue
+        arg = n.args[0] if n.args else None
+        const = (isinstance(arg, ast.Constant) and isinstance(arg.value, str))
+        ident = (arg.attr if isinstance(arg, ast.Attribute)
+                 and isinstance(arg.value, ast.Name)
+                 else arg.id if isinstance(arg, ast.Name) else "")
+        if not (const or (ident and ident.isupper())):
+            out.append(f"{rel}:{n.lineno}: span() name is not a constant")
+    return out
+
+
+@pytest.mark.parametrize("rel", SPAN_FILES)
+def test_span_names_are_constants(rel):
+    tree = ast.parse(open(os.path.join(REPO, rel)).read(), filename=rel)
+    assert not _span_name_violations(tree, rel)
+
+
+def test_span_name_checker_catches_violations():
+    bad = ("def f(op):\n"
+           "    with _obs.span(f'ompi.coll.{op}'):\n"       # VIOLATION
+           "        pass\n"
+           "    with _obs.span('ompi.' + op, bytes=1):\n"   # VIOLATION
+           "        pass\n"
+           "    with span(name):\n"                         # VIOLATION
+           "        pass\n"
+           "    with _obs.span(_spans.COLL_CALL, op=op):\n"
+           "        pass\n"
+           "    with _obs.span('ompi.coll.call'):\n"
+           "        pass\n"
+           "    with span(HIER_D2H):\n"
+           "        pass\n")
+    assert len(_span_name_violations(ast.parse(bad), "bad.py")) == 3
+
+
+def test_span_sites_exist_and_only_the_helper_annotates():
+    """Every layer boundary of the table has its site, never inside an
+    ``if _obs.enabled`` block (they fire with the journal off: the
+    profiler session is the gate), and ``TraceAnnotation`` appears in
+    the helper alone."""
+    pkg = os.path.join(REPO, "ompi_release_tpu")
+    holders = []
+    for root, _dirs, files in os.walk(pkg):
+        for fn in files:
+            if fn.endswith(".py"):
+                path = os.path.join(root, fn)
+                if "TraceAnnotation" in open(path).read():
+                    holders.append(os.path.relpath(path, REPO))
+    assert holders == [SPANS_MODULE]
+    wanted = {"COLL_CALL": "comm/communicator.py",
+              "COLL_LAUNCH": "coll/plan.py", "COLL_COMPILE": "coll/driver.py",
+              "NBC_WAIT": "coll/nbc.py",
+              "PLAN_NATIVE_FIRE": "coll/native_exec.py",
+              "PLAN_XCHG": "coll/plan.py", "HIER_D2H": "coll/hier.py",
+              "HIER_H2D": "coll/hier.py", "WIRE_STASH": "btl/nativewire.py"}
+    for const, rel in wanted.items():
+        path = os.path.join(pkg, rel)
+        tree = ast.parse(open(path).read())
+        sites = [n for n in ast.walk(tree)
+                 if isinstance(n, ast.Call) and n.args
+                 and isinstance(n.args[0], ast.Attribute)
+                 and n.args[0].attr == const]
+        assert sites, f"{rel}: no span site for {const}"
+        gated = {id(c) for node in ast.walk(tree)
+                 if isinstance(node, ast.If) and _mentions_enabled(node.test)
+                 for stmt in node.body for c in ast.walk(stmt)}
+        assert not any(id(s) in gated for s in sites), (
+            f"{rel}: a {const} span sits under an enabled gate")
 
 
 def test_watchdog_arm_sites_are_gated_and_present():
