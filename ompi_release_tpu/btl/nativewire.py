@@ -802,10 +802,15 @@ class NativeWireBtl(DcnBtl):
                     # ring of bytes the consumer may itself be parked
                     # on a full ring — ours, or (around a cycle of
                     # peers) someone's who waits on us. Take what is
-                    # queued for us off our inbound rings
-                    # (native/planexec.cc keeps the same discipline);
-                    # while frames keep coming look again soon,
-                    # otherwise wait in long slices
+                    # queued for us off our inbound rings; while
+                    # frames keep coming look again soon, otherwise
+                    # wait in long slices. (native/planexec.cc guards
+                    # against the same deadlock but differs on
+                    # purpose: it never waits inside the ring write,
+                    # drains until a sweep finds nothing and naps
+                    # 50 us, where this leg waits in slices of
+                    # _FULL_RING_LOOK_MS and copies what it drains
+                    # into a stash.)
                     slice_ms = (_FULL_RING_LOOK_MS
                                 if self._stash_inbound() else 2000)
         finally:

@@ -75,6 +75,13 @@ _native_fires = pvar.counter(
     "frozen wire plans fired end-to-end by the C executor (one "
     "ctypes slice loop instead of per-round Python orchestration)",
 )
+_native_ring_yields = pvar.counter(
+    "plan_native_ring_yields",
+    "fragments for which the C executor met a full tx ring: it "
+    "drained its own arrivals and retried instead of waiting in the "
+    "write (0 = the rings never filled; wire_native_ring_stalls "
+    "counts the same records, together with the Python leg's)",
+)
 _native_fallbacks = pvar.counter(
     "plan_native_fallbacks",
     "native-eligible fires that fell back to the interpreted "
@@ -959,6 +966,7 @@ class NativeXchg:
                 + (npl.recv_frames - npl.recv_msgs))
             btl.staged_bytes_pvar.add(npl.send_bytes + npl.recv_bytes)
         _pool_hits.add(npl.pool_count)
+        _native_ring_yields.add(px.ring_yields())
         _native_fires.add()
 
     def _materialize(self, r: int) -> Dict[int, list]:

@@ -264,6 +264,12 @@ def _declare(lib: ctypes.CDLL) -> None:
         lib.planexec_stash_data.argtypes = [P, ctypes.c_int64]
         lib.planexec_stash_data.restype = P
         lib.planexec_stash_clear.argtypes = [P]
+    if hasattr(lib, "planexec_ring_yields"):
+        lib.planexec_ring_yields.argtypes = [P]
+        lib.planexec_ring_yields.restype = ctypes.c_int64
+        lib.planexec_crc32.argtypes = [ctypes.c_uint32, u8p,
+                                       ctypes.c_int64, ctypes.c_int32]
+        lib.planexec_crc32.restype = ctypes.c_uint32
 
 
 def wire_symbols_available() -> bool:
@@ -975,6 +981,13 @@ class PlanExec:
         n = self.round_count
         p = self._lib.planexec_ts_ptr(self._handle())
         return [float(p[i]) for i in range(n)]
+
+    def ring_yields(self) -> int:
+        """Fragments of the last fire that met a full tx ring and
+        yielded to a drain of the executor's own arrivals (0: the
+        rings never filled; 0 too on a .so older than the getter)."""
+        f = getattr(self._lib, "planexec_ring_yields", None)
+        return int(f(self._handle())) if f is not None else 0
 
     def err_peer(self) -> int:
         return int(self._lib.planexec_err_peer(self._handle()))

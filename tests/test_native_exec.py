@@ -90,6 +90,217 @@ class TestBlob:
 
 
 # ---------------------------------------------------------------------------
+# 1a. the executor's CRC and its full-ring turn (device-free, one process)
+# ---------------------------------------------------------------------------
+
+_CRC_LENGTHS = [0, 1, 7, 8, 9, 63, 64, 65, 4095, 4096, 4097,
+                (1 << 20) + 3]
+
+
+@needs_native
+class TestExecutorCrc:
+    """The header CRC is the wire contract of a mixed fleet: a C
+    sender's header is checked by ``zlib.crc32`` in a Python receiver
+    and the reverse. Both of the executor's implementations (the
+    CPU's carry-less multiply where it has one, the slicing tables)
+    must be that function, from any start offset, chained or not."""
+
+    @pytest.mark.parametrize("tables_only", [0, 1],
+                             ids=["cpu", "tables"])
+    @pytest.mark.parametrize("n", _CRC_LENGTHS)
+    def test_equals_zlib(self, n, tables_only):
+        import ctypes
+        import zlib
+
+        from ompi_release_tpu.native import bindings as nb
+
+        lib = nb.load_library()
+        rng = np.random.default_rng(n + 1)
+        buf = rng.integers(0, 256, n + 16, dtype=np.uint8)
+        raw = buf.tobytes()
+
+        def crc(lo, hi, prior=0):  # of buf[lo:hi], in place
+            ptr = ctypes.cast(buf.ctypes.data + lo,
+                              ctypes.POINTER(ctypes.c_uint8))
+            return int(lib.planexec_crc32(prior, ptr, hi - lo,
+                                          tables_only))
+
+        for off in (0, 1, 3, 8, 13):  # unaligned starts
+            assert crc(off, off + n) == zlib.crc32(raw[off:off + n]), \
+                (n, off)
+        # chained over 2..5 segments, as scatter-gather payloads are
+        for parts in (2, 3, 4, 5):
+            cuts = sorted(rng.integers(0, n + 1, parts - 1).tolist())
+            got = 0
+            for lo, hi in zip([0] + cuts, cuts + [n]):
+                got = crc(lo, hi, got)
+            assert got == zlib.crc32(raw[:n]), (n, cuts)
+
+
+class _ExecPair:
+    """Two plan executors in one process, wired as two co-hosted
+    ranks are: an OOB endpoint each (headers) and one shm ring per
+    direction (fragments), each side's plan one round that sends
+    ``nbytes`` to the other and/or receives as much."""
+
+    TAG = 77
+    PRE, MID = b"SGH2-pre", b"-mid-"
+
+    def __init__(self, tmp_name, ring_bytes, nbytes, chunk,
+                 a_sends=True, b_sends=True):
+        from ompi_release_tpu.native.bindings import (
+            OobEndpoint, PlanExec, ShmRing)
+
+        self._unlink = ShmRing.unlink
+        self.nbytes, self.chunk = nbytes, chunk
+        self.nchunks = -(-nbytes // chunk)
+        self.eps = [OobEndpoint(1, secret=b""), OobEndpoint(2, secret=b"")]
+        self.eps[0].connect(2, "127.0.0.1", self.eps[1].port)
+        self.eps[1].connect(1, "127.0.0.1", self.eps[0].port)
+        pid = os.getpid()
+        self.names = [f"/ompitpu-t-{pid}-{tmp_name}-{d}" for d in "ab"]
+        # ring 0 carries a -> b, ring 1 carries b -> a
+        self.tx = [ShmRing.create(nm, ring_bytes, pid)
+                   for nm in self.names]
+        self.rx = [ShmRing.attach(nm, pid) for nm in self.names]
+        assert all(self.tx) and all(self.rx)
+        self.px = []
+        for me, sends, recvs in ((0, a_sends, b_sends),
+                                 (1, b_sends, a_sends)):
+            send_msg = (self.PRE, self.MID, nbytes, self.nchunks, chunk,
+                        ((0, 0, 0, nbytes),))
+            recv_msg = (0, nbytes, self.nchunks, chunk, self.PRE,
+                        self.MID)
+            rounds = [{"depth": 2,
+                       "streams": [(0, [send_msg] if sends else [])],
+                       "rsrcs": [(0, [recv_msg] if recvs else [])]}]
+            px = PlanExec(nx.build_blob(
+                self.TAG, [nbytes], [nbytes] if recvs else [],
+                [1 - me], rounds))
+            px.bind(self.eps[me]._h, me + 1, [2 - me],
+                    [self.tx[me]._h], [self.rx[1 - me]._h])
+            self.px.append(px)
+
+    def header(self, xfer, crc):
+        def rec(v):  # DSS int64 single-value record
+            return b"\x01\x01\x00\x00\x00" + int(v).to_bytes(8, "little")
+        return self.PRE + rec(xfer) + self.MID + rec(crc)
+
+    def fire(self, me, data, xfer_base, out, timeout_ms=20_000):
+        px = self.px[me]
+        rc = px.fire_begin([data], xfer_base, timeout_ms)
+        if rc == 0:
+            rc = px.RC_AGAIN
+            while rc == px.RC_AGAIN:
+                rc = px.fire_step(100)
+        out[me] = rc
+
+    def hand_send(self, xfer, crc, wire):
+        """Rank a's message as a foreign sender would put it on the
+        wire: header on the endpoint, SGC2 fragments on the ring."""
+        self.eps[0].send(2, self.TAG, self.header(xfer, crc))
+        for ci in range(self.nchunks):
+            frag = wire[ci * self.chunk:(ci + 1) * self.chunk]
+            assert self.tx[0].writev(
+                self.TAG, [b"SGC2" + xfer.to_bytes(8, "big"),
+                           ci.to_bytes(8, "big"), frag], 1000) == 0
+
+    def close(self):
+        for px in self.px:
+            px.close()
+        for r in self.tx + self.rx:
+            r.close()
+        for nm in self.names:
+            self._unlink(nm)
+        for ep in self.eps:
+            ep.close()
+
+
+@needs_native
+class TestExecutorFullRing:
+    """Messages of many rings' worth through 64 KiB rings: the send
+    phase must turn to its own arrivals when its ring is full, and the
+    ring's stall count must stay one per blocked record. Counters
+    only, never wall time."""
+
+    RING, CHUNK = 64 << 10, 16 << 10
+
+    @pytest.mark.parametrize("way", ["both", "one"])
+    def test_opposing_and_one_way_messages(self, way):
+        import threading
+
+        nbytes = 16 * self.RING + 1000  # 16 rings and a ragged tail
+        pair = _ExecPair(f"fr-{way}", self.RING, nbytes, self.CHUNK,
+                         a_sends=True, b_sends=(way == "both"))
+        try:
+            rng = np.random.default_rng(5)
+            data = [rng.integers(0, 256, nbytes, dtype=np.uint8)
+                    for _ in range(2)]
+            rcs = [None, None]
+            ths = [threading.Thread(target=pair.fire,
+                                    args=(me, data[me], 100 * (me + 1),
+                                          rcs))
+                   for me in range(2)]
+            for t in ths:
+                t.start()
+            for t in ths:
+                t.join(60)
+            assert rcs == [0, 0], rcs
+            senders = [0, 1] if way == "both" else [0]
+            for me in senders:
+                got = pair.px[1 - me].pool_view()[:nbytes]
+                np.testing.assert_array_equal(got, data[me])
+                st = pair.tx[me].stats()
+                assert st["w_frames"] == pair.nchunks
+                # one stall per record that found the ring full,
+                # however many zero-wait retries it took
+                assert st["w_stalls"] <= st["w_frames"], st
+                assert pair.px[me].ring_yields() == st["w_stalls"], st
+            if way == "both":
+                # 16 rings each way cannot pass without a full ring
+                assert sum(px.ring_yields() for px in pair.px) > 0
+            else:
+                assert pair.px[1].ring_yields() == 0  # sent nothing
+        finally:
+            pair.close()
+
+    @pytest.mark.parametrize("fault", ["none", "payload_bit",
+                                       "header_crc"])
+    def test_corruption_is_truncated(self, fault):
+        """The check covers every byte on the receiving side: one
+        flipped bit in any fragment, or a header that promises another
+        CRC, ends the fire with RC_TRUNCATED, never with data. The
+        same hand-made frames with nothing altered pass (``none``), so
+        the verdict is the CRC's and the CRC is zlib's."""
+        import zlib
+
+        nbytes = 5 * self.CHUNK + 17
+        pair = _ExecPair(f"tr-{fault}", 1 << 20, nbytes, self.CHUNK,
+                         a_sends=True, b_sends=False)
+        try:
+            data = np.random.default_rng(9).integers(
+                0, 256, nbytes, dtype=np.uint8)
+            crc = zlib.crc32(data.tobytes())
+            wire = data.copy()
+            if fault == "payload_bit":
+                wire[3 * self.CHUNK + 5] ^= 0x10
+            elif fault == "header_crc":
+                crc ^= 1
+            pair.hand_send(4242, crc, wire)
+            rcs = [None, None]
+            pair.fire(1, data, 1, rcs, timeout_ms=10_000)
+            px = pair.px[1]
+            if fault == "none":
+                assert rcs[1] == px.RC_DONE, rcs
+                np.testing.assert_array_equal(
+                    px.pool_view()[:nbytes], data)
+            else:
+                assert rcs[1] == px.RC_TRUNCATED, rcs
+        finally:
+            pair.close()
+
+
+# ---------------------------------------------------------------------------
 # 1b. byte-provenance matcher (device-free)
 # ---------------------------------------------------------------------------
 
@@ -411,10 +622,11 @@ APP_PRELUDE = textwrap.dedent("""
 """ % REPO)
 
 
-def _run_job(tmp_path, capfd, body, n=3, timeout=240, job_kw=None):
+def _run_job(tmp_path, capfd, body, n=3, timeout=240, job_kw=None,
+             mca=()):
     app = tmp_path / "app.py"
     app.write_text(APP_PRELUDE + textwrap.dedent(body))
-    job = Job(n, [sys.executable, str(app)], [],
+    job = Job(n, [sys.executable, str(app)], list(mca),
               heartbeat_s=0.5, miss_limit=8, **(job_kw or {}))
     rc = job.run(timeout_s=timeout)
     out = capfd.readouterr()
@@ -483,6 +695,59 @@ class TestNativeJobs:
         assert rc == 0, out
         for me in range(3):
             assert f"MIXED-OK {me} " in out
+
+    @pytest.mark.parametrize("op", ["allgather", "bcast"])
+    def test_many_rings_through_a_small_ring(self, tmp_path, capfd, op):
+        """Two ranks, 64 KiB rings, 1 MiB per process and fire: 16
+        rings each way at once (allgather) or one way (bcast). The
+        native fires return what the interpreted first call returned,
+        bit for bit; with opposing senders some fragment must have
+        met a full ring and yielded; a tx ring counts at most one
+        stall per fragment it carried. Counters, never wall time."""
+        rc, out, _ = _run_job(tmp_path, capfd, """
+            from ompi_release_tpu.btl import nativewire as nw
+            OP = %r
+            per = (1 << 20) // 8  # int32 elems: 512 KiB per local rank
+            x = np.stack([(np.arange(per, dtype=np.int32) * 7
+                           + 1000 * (off + i)) for i in range(2)])
+            call = ((lambda: world.allgather(x)) if OP == "allgather"
+                    else (lambda: world.bcast(x, root=0)))
+            first = np.asarray(call())  # interpreted: records the plan
+            if OP == "allgather":
+                want = np.concatenate(
+                    [np.arange(per, dtype=np.int32) * 7 + 1000 * r
+                     for r in range(n)])
+                np.testing.assert_array_equal(first[0], want)
+            else:
+                np.testing.assert_array_equal(
+                    first[1], np.arange(per, dtype=np.int32) * 7)
+            for it in range(4):
+                np.testing.assert_array_equal(np.asarray(call()),
+                                              first)  # BITWISE
+            fires = _pv("plan_native_fires")
+            assert fires >= 2, fires
+            assert _pv("plan_native_fallbacks") == 0
+            yields = _pv("plan_native_ring_yields")
+            if OP == "allgather":
+                assert yields > 0, yields
+            elif me != 0:
+                assert yields == 0, yields  # sends nothing
+            frames = stalls = 0
+            for ring in list(nw._live_tx):
+                st = ring.stats()
+                assert st["w_stalls"] <= st["w_frames"], st
+                frames += st["w_frames"]
+                stalls += st["w_stalls"]
+            assert yields <= stalls, (yields, stalls)
+            world.barrier()
+            print(f"RINGS-OK {me} fires={fires} yields={yields} "
+                  f"frames={frames} stalls={stalls}", flush=True)
+            mpi.finalize()
+        """ % op, n=2, mca=[("btl_nativewire_ring_bytes", "65536"),
+                            ("wire_pipeline_segsize", "16384")])
+        assert rc == 0, out
+        for me in range(2):
+            assert f"RINGS-OK {me} " in out
 
     def test_sigkill_mid_plan_fire_is_typed_and_fast(
             self, tmp_path, capfd):
