@@ -3,8 +3,9 @@ from perfbench import trace
 
 def read(facts, pattern, scale):
     """Seconds of the host spans whose name matches ``pattern`` in the
-    traced slice, over the calls in it. The library writes no spans yet;
-    a metric over one it gains is this reader and a pattern."""
+    traced slice, over the calls in it: the library's ``ompi.*`` spans
+    (``obs/spans.py``) from every thread of the traced process. A metric
+    over a span the library gains is this reader and a pattern."""
     sl = facts["slice"]
     if not sl or not sl["calls"]:
         return None

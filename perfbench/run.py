@@ -32,6 +32,10 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 WORKER = os.path.join(HERE, "worker.py")
 LIMIT_S = 340.0  # a run ends within 360 s; a first run that compiles may take longer
+# tpurun's failure detector ends the job when a rank's beats stay away for
+# four intervals: 2 s at its default of 0.5 s, which a host that stands still
+# for "some seconds" passes (PERF.md section 2). Its hangs are LIMIT_S's.
+HEARTBEAT_S = 10.0
 
 
 def parse(argv):
@@ -97,14 +101,35 @@ def judge(reports, reference):
     return reference.verdict(numbers)
 
 
-def merge(ranks, trace, reference):
-    """The ranks' reports as the one result. Rank 0 holds the chip: the
-    metrics and the device are its; every rank's comparison counts."""
+def job_device(ranks, rehearsal=False):
+    """The device of the JOB, not of rank 0: the chips of every rank that
+    is not a declared host rank, together, and the fullest of them. The
+    platform and the kind are rank 0's, and so are ``window_s`` and
+    ``busy_s`` of a traced run: only the process that holds a chip can
+    trace it, and rank 0's is the one traced. Outside a rehearsal every
+    such rank has to be on a TPU, not rank 0 alone."""
+    chip = [r for r in ranks if not r["host_rank"]]
+    off = [r["rank"] for r in chip if r["device"]["platform"] != "tpu"]
+    if off and not rehearsal:
+        raise SystemExit(f"perfbench: FAILED: rank(s) {off} are not on a TPU "
+                         "and are not declared host ranks")
+    device = dict(ranks[0]["device"])
+    device["count"] = sum(r["device"]["count"] for r in chip)
+    device["memory_peak_bytes"] = max(r["device"]["memory_peak_bytes"]
+                                      for r in chip)
+    return device
+
+
+def merge(ranks, trace, reference, rehearsal=False):
+    """The ranks' reports as the one result. Rank 0 times and traces: the
+    metrics are its; the device is the job's; every rank's comparison
+    counts."""
     first = ranks[0]
     compared, ok = judge([r["numbers"] for r in ranks], reference)
     failed = sum(r["failed"] for r in ranks)
     result = {"correct": ok, "attempted": first["attempted"], "failed": failed,
-              "metrics": first["metrics"], "device": first["device"]}
+              "metrics": first["metrics"],
+              "device": job_device(ranks, rehearsal)}
     if trace and "breakdown" in first:
         result["breakdown"] = first["breakdown"]
     return result, compared
@@ -155,7 +180,8 @@ def main(argv=None, worker=WORKER):
         args += ["--extra-seeds", a.extra_seeds]
     if cfg["launcher"] == "tpurun":
         args = [sys.executable, "-m", "ompi_release_tpu.tools.tpurun",
-                "-n", str(cfg["ranks"]), "--timeout", str(LIMIT_S)] + args
+                "-n", str(cfg["ranks"]), "--timeout", str(LIMIT_S),
+                "--heartbeat", str(HEARTBEAT_S)] + args
     elif cfg["launcher"] != "driver":
         raise SystemExit(f"perfbench: launcher {cfg['launcher']!r} is not known")
     rc, out = launch(args, child_env(cfg, a.rehearse_cpu), LIMIT_S + 20)
@@ -169,11 +195,7 @@ def main(argv=None, worker=WORKER):
         print(f"perfbench: FAILED: exit code {rc}, {len(ranks)} rank(s) "
               "reported", file=sys.stderr)
         return 1
-    first = ranks[0]
-    if not a.rehearse_cpu and first["device"]["platform"] != "tpu":
-        print("perfbench: FAILED: rank 0 is not on a TPU", file=sys.stderr)
-        return 1
-    result, compared = merge(ranks, a.trace, reference)
+    result, compared = merge(ranks, a.trace, reference, a.rehearse_cpu)
     if a.rehearse_cpu:
         result["rehearsal"] = "cpu: NOT a chip result"
     result.update(controls(ranks, reference))
@@ -181,7 +203,8 @@ def main(argv=None, worker=WORKER):
         "workload": a.workload, "seed": a.seed, "trace": a.trace,
         "ranks": [{k: r.get(k) for k in (
             "rank", "host_rank", "rounds", "window_s", "round_s_warm",
-            "compile_s", "cache_hits", "compare_s", "kept_bytes", "trace",
+            "compile_s", "setup_reached_s", "cache_hits", "compare_s",
+            "kept_bytes", "trace",
             "round_s", "by_call_ms")} for r in ranks]}
     result["compared"] = compared  # last in the line
     for r in ranks:

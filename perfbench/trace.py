@@ -23,6 +23,7 @@ import re
 
 SLICE = "perfbench.slice"       # the benchmark's span around the traced rounds
 CALL = "perfbench.call:"        # ... and around each call in it
+LIBRARY = "ompi."               # the library's own spans (obs/spans.py)
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 
@@ -88,16 +89,52 @@ def union(intervals, lo, hi):
     return merged
 
 
-def _share_gap(gaps, calls, starts, a, b):
-    """Credit the idle stretch [a, b) to the benchmark's call spans it
-    overlaps (``calls`` are sorted and do not overlap: one caller), and
-    what lies outside every call to "between calls"."""
+def _owner(open_spans):
+    """The name an idle moment under ``open_spans`` goes by: the
+    benchmark's call span, then the library span that opened last (of two
+    that opened together, the one that closes first)."""
+    call = next((m[2] for m in open_spans if m[2].startswith(CALL)), None)
+    inner = [m for m in open_spans if m[2].startswith(LIBRARY)]
+    if not inner:
+        return call
+    last = max(inner, key=lambda m: (m[0], -m[1]))[2]
+    return f"{call}:{last}" if call else last
+
+
+def owners(spans):
+    """Who an idle moment is credited to, as sorted pieces that do not
+    overlap, (start_ns, end_ns, name): inside the library's ``ompi.*``
+    spans the innermost one that covers the moment, named after the
+    benchmark's call span around it; elsewhere inside a call, the call's
+    own span. Moments outside both belong to no piece."""
+    marks = [iv for iv in spans
+             if iv[2].startswith((CALL, LIBRARY)) and iv[1] > iv[0]]
+    # at one instant ends sort before starts: no span is open at its own end
+    edges = sorted([(s, 1, i) for i, (s, _, _) in enumerate(marks)]
+                   + [(e, 0, i) for i, (_, e, _) in enumerate(marks)])
+    pieces, open_now, since = [], set(), None
+    for t, opens, i in edges:
+        if open_now and t > since:
+            name = _owner([marks[j] for j in open_now])
+            if pieces and pieces[-1][1:] == (since, name):
+                pieces[-1] = (pieces[-1][0], t, name)
+            else:
+                pieces.append((since, t, name))
+        (open_now.add if opens else open_now.discard)(i)
+        since = t
+    return pieces
+
+
+def _share_gap(gaps, pieces, starts, a, b):
+    """Credit the idle stretch [a, b) to the pieces of ``owners`` it
+    overlaps (sorted, not overlapping), and what lies outside every one
+    to "between calls"."""
     left = b - a
     i = max(0, bisect.bisect_right(starts, a) - 1)
-    while i < len(calls) and calls[i][0] < b:
-        part = min(b, calls[i][1]) - max(a, calls[i][0])
+    while i < len(pieces) and pieces[i][0] < b:
+        part = min(b, pieces[i][1]) - max(a, pieces[i][0])
         if part > 0:
-            gaps[calls[i][2]] = gaps.get(calls[i][2], 0) + part
+            gaps[pieces[i][2]] = gaps.get(pieces[i][2], 0) + part
             left -= part
         i += 1
     if left > 0:
@@ -135,12 +172,13 @@ def reduce(devices, spans, window_s=None):
         s, e = max(s, lo), min(e, hi)
         if e > s:
             by_op[short(name)] = by_op.get(short(name), 0) + (e - s)
-    calls = sorted(iv for iv in spans if iv[2].startswith(CALL))
-    starts = [s for s, _, _ in calls]
+    calls = [iv for iv in spans if iv[2].startswith(CALL)]
+    pieces = owners(spans)
+    starts = [s for s, _, _ in pieces]
     gaps, edge = {}, lo
     for s, e in busy[top] + [[hi, hi]]:
         if s > edge:
-            _share_gap(gaps, calls, starts, edge, s)
+            _share_gap(gaps, pieces, starts, edge, s)
         edge = max(edge, e)
 
     def top10(d):

@@ -334,18 +334,26 @@ def delta(after, before):
 
 def main(argv=None):
     a = parse(argv)
+    reached = {}  # seconds since run.py started, at the end of each stage
+
+    def stage(name):
+        reached[name] = time.time() - a.t0
+
     b = Bench(a)
     jax = b.jax
+    stage("init")  # python and jax imported, the chip reached, mpi.init
     import numpy as np
 
     from perfbench import least, manifest, traffic
 
     b.make_data(a.seed)
+    stage("data")
     # warm up exactly this cell's (operation, size) pairs, twice each: the
     # first call compiles and freezes the plan, the second replays it
-    for _ in range(2):
+    for k in (1, 2):
         for op, size in b.round:
             b.call(op, size)
+        stage(f"warm_up_{k}")
     # how long a round takes, to fix the rounds where the ranks must agree
     # and to size the traced slice: one timed round, and where rounds are
     # short as many more as fit in a second (rank 0 says how many)
@@ -387,6 +395,7 @@ def main(argv=None):
               "attempted": facts["calls"] + b.raised, "rounds": facts["rounds"],
               "window_s": facts["seconds"], "round_s_warm": t_round,
               "compile_s": b.durations[COMPILE_EVENT],
+              "setup_reached_s": dict(reached, window=setup_s),
               "cache_hits": b.events[CACHE_HIT_EVENT],
               # where a run reads far off, these say which rounds and calls
               "round_s": spread(facts["round_seconds"]),

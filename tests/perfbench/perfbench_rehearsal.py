@@ -7,6 +7,7 @@ import json
 import os
 
 from perfbench import manifest, run
+from perfbench.trace import CALL
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 FAULTY = os.path.join(HERE, "faulty_worker.py")
@@ -43,6 +44,11 @@ def check_line(line, cell, trace, err):
         assert 0 < dev["busy_s"] <= dev["window_s"]
         assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
         assert all(len(v) <= 10 for v in line["breakdown"].values())
+        # idle gaps go by the call, and inside the library by its span
+        gaps = [name for name, _ in line["breakdown"]["idle_gaps"]]
+        assert all(g == "between calls" or g.startswith(CALL)
+                   for g in gaps)
+        assert any(":ompi." in g for g in gaps), gaps
     else:
         assert "busy_s" not in dev and "breakdown" not in line
     assert line["correct"] is True and line["failed"] == 0
@@ -54,12 +60,13 @@ def check_line(line, cell, trace, err):
         assert f"compared {name} = " in ln and "limit" in ln
 
 
-def check_fault(capfd, monkeypatch, cell, fault, number):
+def check_fault(capfd, monkeypatch, cell, fault, number, worker=FAULTY):
     monkeypatch.setenv("PERFBENCH_FAULT", fault)
-    line, _ = rehearse(capfd, cell, 0, worker=FAULTY)
+    line, _ = rehearse(capfd, cell, 0, worker=worker)
     assert line["correct"] is False and line["failed"] >= 1
     c = line["compared"][number]
     assert c["value"] > c["limit"]
+    return line
 
 
 def check_control(capfd, cell):
