@@ -264,6 +264,15 @@ def _ep_stash(oob_ep):
         return stash, oob_ep._dcn_stash_lock
 
 
+def stashed_pending(oob_ep, tag: int) -> bool:
+    """Whether the endpoint's stash holds a frame of ``tag`` (from any
+    source): what ``stashed_recv(oob_ep, None, tag, ...)`` would serve
+    before it looks at the endpoint."""
+    stash, lock = _ep_stash(oob_ep)
+    with lock:
+        return any(t == tag and q for (_, t), q in stash.items())
+
+
 def stashed_recv(oob_ep, want_src, tag: int, deadline: float):
     """Next (src, payload) for ``tag``, matched by source: frames from
     other senders interleaved on the same tag are stashed on the
@@ -580,9 +589,11 @@ class DcnBtl(base.BtlModule):
 
     def recv_staged(self, oob_ep, tag: int, *, src=None,
                     dst_device=None, timeout_ms: int = 30_000,
-                    first=None):
+                    first=None, put=None):
         """Reassemble one staged transfer; places the result on
-        ``dst_device`` (default: this process's first device). All
+        ``dst_device`` (default: this process's first device) with
+        ``put(array, device)`` (default: ``jax.device_put``; the p2p
+        route passes its own, which is the same under a span). All
         chunk frames are matched to the header's source, so transfers
         from different peers on one tag cannot interleave. The
         receiver accepts BOTH framings regardless of its local cvar:
@@ -690,7 +701,7 @@ class DcnBtl(base.BtlModule):
                         peer=(src - 1) if src is not None else -1)
         if dst_device is None:
             dst_device = jax.local_devices()[0]
-        return jax.device_put(arr, dst_device)
+        return (put or jax.device_put)(arr, dst_device)
 
 
 class ShmBtl(base.BtlModule):
@@ -937,9 +948,10 @@ class ShmBtl(base.BtlModule):
         return name
 
     def recv_shm(self, oob_ep, tag: int, *, src=None, dst_device=None,
-                 timeout_ms: int = 30_000, first=None):
+                 timeout_ms: int = 30_000, first=None, put=None):
         """Map the announced segment, device_put out of it (the single
-        copy), unlink. ``src`` filters control frames by sender node id
+        copy; ``put`` as in ``recv_staged``), unlink. ``src`` filters
+        control frames by sender node id
         (frames from other senders on the same tag are stashed for
         their own consumer — same discipline as the staged path).
         ``first`` is an already-popped ``(src_nid, frame)`` pair to
@@ -999,7 +1011,7 @@ class ShmBtl(base.BtlModule):
             # still no per-chunk socket streaming
             staged = np.array(view)
             del view
-            out = jax.device_put(staged, dst_device)
+            out = (put or jax.device_put)(staged, dst_device)
         finally:
             seg.close()
             seg.unlink()

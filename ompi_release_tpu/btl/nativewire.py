@@ -905,7 +905,7 @@ class NativeWireBtl(DcnBtl):
 
     def recv_staged(self, oob_ep, tag: int, *, src=None,
                     dst_device=None, timeout_ms: int = 30_000,
-                    first=None):
+                    first=None, put=None):
         """Native reassembly: the header is popped/parsed exactly like
         the portable path (shared stash, shared resync discipline);
         SGH2 fragments from a capable co-hosted sender then come out
@@ -948,7 +948,7 @@ class NativeWireBtl(DcnBtl):
         if magic != _HDR2_MAGIC or not self.peer_capable(src_pidx):
             return DcnBtl.recv_staged(
                 self, oob_ep, tag, src=src, dst_device=dst_device,
-                timeout_ms=left_ms, first=(src, hraw))
+                timeout_ms=left_ms, first=(src, hraw), put=put)
         _track_ep(oob_ep)  # tcp-leg counters fold from its C struct
         nbytes = int(np.prod(shape, dtype=np.int64)) * dtype.itemsize
         if nbytes < 0 or any(d < 0 for d in shape):
@@ -1096,7 +1096,7 @@ class NativeWireBtl(DcnBtl):
                         nbytes=int(arr.nbytes), peer=src_pidx)
         if dst_device is None:
             dst_device = jax.local_devices()[0]
-        return jax.device_put(arr, dst_device)
+        return (put or jax.device_put)(arr, dst_device)
 
     @staticmethod
     def _pop_other_locked(ring, tmp=None):

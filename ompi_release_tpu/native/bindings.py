@@ -573,6 +573,12 @@ class OobEndpoint:
                                f"oob recv timeout (tag {tag})")
             return src.value, tg.value, ctypes.string_at(arr, got)
 
+    def queued(self, tag: int = -1, timeout_ms: int = 0) -> bool:
+        """Whether a frame of ``tag`` (-1: any tag) is queued, waiting
+        up to ``timeout_ms`` for one to arrive; consumes nothing."""
+        return self._lib.oob_next_len(self._handle(), tag,
+                                      timeout_ms) >= 0
+
     # -- nativewire datapath (optional capability) ------------------------
 
     def sendv(self, dst: int, tag: int, parts) -> None:

@@ -37,6 +37,24 @@ keyword stats of the event, never folded into the name:
 ``ompi.wire.stash``        a sender draining one of its own inbound rings
                            because the peer's ring is full (``bytes``
                            queued in that ring)
+``ompi.pml.send``          a cross-process ``isend`` (so ``send`` too),
+                           entry to return: the whole transfer, since
+                           the wire leg sends inside the call
+                           (``bytes``, ``peer``, ``tag``)
+``ompi.pml.d2h``           the fetch of its device payload to the host
+                           (``bytes``; nested in ``send``)
+``ompi.wire.p2p_send``     ``WireRouter.send_p2p``: lane lock, envelope,
+                           payload (``bytes``, ``seq``; nested in
+                           ``send``)
+``ompi.pml.recv_wait``     the blocking wait of a cross-process receive
+                           (``recv``, ``wait``, ``wait_all``) until its
+                           request completes (``source``, ``tag``)
+``ompi.wire.p2p_pump``     one p2p message off its lane, from the popped
+                           envelope to the payload complete (``seq``:
+                           the SENDER's, as in its ``p2p_send``;
+                           ``bytes``)
+``ompi.pml.h2d``           the ``device_put`` of a p2p arrival until it
+                           returns (``bytes``; nested in ``p2p_pump``)
 
 ``seq`` is the posted schedule's ``ScheduledOp.seq`` (process-local): a
 schedule may run on another thread than its ``ompi.coll.call``
@@ -60,9 +78,17 @@ PLAN_XCHG = "ompi.plan.xchg"
 HIER_D2H = "ompi.hier.d2h"
 HIER_H2D = "ompi.hier.h2d"
 WIRE_STASH = "ompi.wire.stash"
+PML_SEND = "ompi.pml.send"
+PML_D2H = "ompi.pml.d2h"
+WIRE_P2P_SEND = "ompi.wire.p2p_send"
+PML_RECV_WAIT = "ompi.pml.recv_wait"
+WIRE_P2P_PUMP = "ompi.wire.p2p_pump"
+PML_H2D = "ompi.pml.h2d"
 
 NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
-         PLAN_NATIVE_FIRE, PLAN_XCHG, HIER_D2H, HIER_H2D, WIRE_STASH)
+         PLAN_NATIVE_FIRE, PLAN_XCHG, HIER_D2H, HIER_H2D, WIRE_STASH,
+         PML_SEND, PML_D2H, WIRE_P2P_SEND, PML_RECV_WAIT, WIRE_P2P_PUMP,
+         PML_H2D)
 
 #: ``jax.profiler.TraceAnnotation`` and the ``obs`` package, bound on
 #: the first span: importing ``obs`` must not import jax (``obs

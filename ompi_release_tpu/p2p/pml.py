@@ -27,6 +27,7 @@ from typing import Any, Deque, Dict, List, Optional, Tuple
 
 from .. import obs as _obs
 from ..mca import component as mca_component
+from ..obs import spans as _spans
 from ..obs import watchdog as _watchdog
 from ..mca import pvar
 from ..mca import var as mca_var
@@ -46,6 +47,18 @@ _eager_count = pvar.counter("pml_eager_sends", "eager-protocol sends")
 _rndv_count = pvar.counter("pml_rndv_sends", "rendezvous-protocol sends")
 _pipeline_count = pvar.counter(
     "pml_pipelined_sends", "segmented (pipelined) large sends"
+)
+# the cross-process leg has no eager/rendezvous choice (the whole
+# transfer happens inside isend), so the three above never tick there
+_wire_sends = pvar.counter(
+    "pml_wire_sends", "sends that crossed a process boundary on the wire"
+)
+_wire_bytes = pvar.counter(
+    "pml_wire_bytes", "payload bytes of those sends"
+)
+_wire_recvs = pvar.counter(
+    "pml_wire_recvs",
+    "messages delivered from the wire into the matching queues",
 )
 
 PML_FRAMEWORK = mca_component.framework(
@@ -482,9 +495,17 @@ class WirePmlEngine(PmlEngine):
         if dst in self._local_set:
             return super().isend(data, dst, tag, src=src, sync=sync,
                                  ready=ready)
-        # cross-process: rsend legally degrades to a standard send (an
-        # implementation MAY treat ready mode as standard; verifying
-        # the remote posted-recv would cost a round trip)
+        with _obs.span(_spans.PML_SEND, bytes=_spans.nbytes(data),
+                       peer=dst, tag=tag):
+            return self._isend_wire(data, dst, tag, src, sync)
+
+    def _isend_wire(self, data, dst: int, tag: int, src: int,
+                    sync: bool) -> Request:
+        """The cross-process leg: the whole transfer happens here,
+        before the request is returned. rsend legally degrades to a
+        standard send (an implementation MAY treat ready mode as
+        standard; verifying the remote posted-recv would cost a round
+        trip)."""
         data = _as_device_payload(data)
         from . import peruse
 
@@ -495,8 +516,12 @@ class WirePmlEngine(PmlEngine):
                 self._logger.record(src, dst, tag, data, sync)
         import numpy as _np
 
-        seq = self._router.send_p2p(self.comm, src, dst, tag,
-                                    _np.asarray(data), sync)
+        nbytes = self._nbytes(data)
+        with _obs.span(_spans.PML_D2H, bytes=nbytes):
+            host = _np.asarray(data)
+        seq = self._router.send_p2p(self.comm, src, dst, tag, host, sync)
+        _wire_sends.add()
+        _wire_bytes.add(nbytes)
         if not sync:
             req = Request()
             req.complete(status=Status(source=src, tag=tag))
@@ -568,6 +593,11 @@ class WirePmlEngine(PmlEngine):
                 engine._drain(dst)
 
             def block() -> None:
+                with _obs.span(_spans.PML_RECV_WAIT, source=source,
+                               tag=tag):
+                    wait_matched()
+
+            def wait_matched() -> None:
                 import time as _time
 
                 tok = None
@@ -642,6 +672,7 @@ class WirePmlEngine(PmlEngine):
             req.on_complete(on_matched)
         entry = _SendEntry(src_rank, dst_rank, user_tag, data, req, False)
         entry.transferred = True
+        _wire_recvs.add()
         with self._lock:
             if self._logger is not None:
                 # a wire arrival IS a send landing in this process's

@@ -21,7 +21,7 @@ import threading
 from typing import Any, Dict, List, Optional
 
 from ..mca import var as mca_var
-from ..utils import output
+from ..utils import malloc_tune, output
 from ..utils.errors import ErrorCode, MPIError
 from . import ess as ess_mod
 from . import mesh as mesh_mod
@@ -323,6 +323,9 @@ class Runtime:
         self.local_size = counts[my_pidx]
         self.proc_spans = [(offsets[p], counts[p])
                            for p in range(len(counts))]
+        # messages are staged through host memory from here on: keep the
+        # allocator from unmapping every staging buffer it is handed back
+        malloc_tune.ensure()
         self.wire = WireRouter(self)
         _log.verbose(
             1,

@@ -63,6 +63,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from .. import obs as _obs
+from ..obs import spans as _spans
 from ..obs import watchdog as _watchdog
 from ..mca import pvar
 from ..mca import var as mca_var
@@ -113,6 +114,16 @@ _coll_pumped = pvar.counter(
 _FT_SLICE_S = 0.1
 
 _ft_singleton = None
+
+
+def _p2p_put(arr, device):
+    """How the p2p route places an arrival on the receiver's device:
+    ``jax.device_put`` under its span (until it returns; the transfer
+    may still be in flight)."""
+    import jax
+
+    with _obs.span(_spans.PML_H2D, bytes=int(arr.nbytes)):
+        return jax.device_put(arr, device)
 
 
 def _ft():
@@ -451,13 +462,13 @@ class WireRouter:
             )
 
     def _recv_payload(self, tag: int, src_pidx: int,
-                      timeout_ms: int = 30_000):
+                      timeout_ms: int = 30_000, put=None):
         btl = self._btl_for(src_pidx)
         if btl is self._shm:
             return btl.recv_shm(self.ep, tag, src=self._nid(src_pidx),
-                                timeout_ms=timeout_ms)
+                                timeout_ms=timeout_ms, put=put)
         return btl.recv_staged(self.ep, tag, src=self._nid(src_pidx),
-                               timeout_ms=timeout_ms)
+                               timeout_ms=timeout_ms, put=put)
 
     # -- p2p (the PML's cross-process route) -------------------------------
     def _next_order(self, dst_world: int) -> int:
@@ -484,45 +495,47 @@ class WireRouter:
         arr = np.asarray(data)
         rec = _obs.enabled  # capture once: flag may flip mid-send
         t0 = time.perf_counter() if rec else 0.0
-        lock = self._chan_lock("send", (dst_world, lane))
-        if not lock.acquire(blocking=False):
-            # contended: another transfer owns this lane — time the
-            # head-of-line wait (the uncontended path never reads a
-            # clock, keeping the off-cost at one try-acquire)
-            w0 = time.perf_counter()
-            lock.acquire()
-            _hol_wait.add(time.perf_counter() - w0)
-        try:
-            # order allocation and the envelope send are one atomic
-            # step per destination: if the envelope never reaches the
-            # wire, the slot is rolled back under the same lock, so a
-            # failed send can never leave a permanent gap that strands
-            # every later message in the receiver's reorder hold.
-            # Envelopes are single small frames — cross-lane payloads
-            # (the actual bytes) still stream concurrently below.
-            with self._chan_lock("order", dst_world):
-                order = self._next_order(dst_world)
-                env = DssBuffer()
-                env.pack_string(_ENV_MAGIC)
-                env.pack_int64([comm.cid, src_rank, dst_rank,
-                                int(user_tag), 1 if sync else 0, seq,
-                                order])
-                try:
-                    self._retry(
-                        lambda: self.ep.send(self._nid(peer), tag,
-                                             env.tobytes()),
-                        f"p2p envelope to process {peer}",
-                    )
-                except MPIError:
-                    with self._order_lock:
-                        # safe: no other thread can have allocated a
-                        # later slot while we hold the order chan lock
-                        self._order[dst_world] = order - 1
-                    raise
-            self._send_payload(peer, tag, arr,
-                               epoch0=getattr(comm, "_ft_epoch0", 0))
-        finally:
-            lock.release()
+        with _obs.span(_spans.WIRE_P2P_SEND, bytes=int(arr.nbytes),
+                       seq=seq):
+            lock = self._chan_lock("send", (dst_world, lane))
+            if not lock.acquire(blocking=False):
+                # contended: another transfer owns this lane — time the
+                # head-of-line wait (the uncontended path never reads a
+                # clock, keeping the off-cost at one try-acquire)
+                w0 = time.perf_counter()
+                lock.acquire()
+                _hol_wait.add(time.perf_counter() - w0)
+            try:
+                # order allocation and the envelope send are one atomic
+                # step per destination: if the envelope never reaches the
+                # wire, the slot is rolled back under the same lock, so a
+                # failed send can never leave a permanent gap that strands
+                # every later message in the receiver's reorder hold.
+                # Envelopes are single small frames — cross-lane payloads
+                # (the actual bytes) still stream concurrently below.
+                with self._chan_lock("order", dst_world):
+                    order = self._next_order(dst_world)
+                    env = DssBuffer()
+                    env.pack_string(_ENV_MAGIC)
+                    env.pack_int64([comm.cid, src_rank, dst_rank,
+                                    int(user_tag), 1 if sync else 0, seq,
+                                    order])
+                    try:
+                        self._retry(
+                            lambda: self.ep.send(self._nid(peer), tag,
+                                                 env.tobytes()),
+                            f"p2p envelope to process {peer}",
+                        )
+                    except MPIError:
+                        with self._order_lock:
+                            # safe: no other thread can have allocated a
+                            # later slot while we hold the order chan lock
+                            self._order[dst_world] = order - 1
+                        raise
+                self._send_payload(peer, tag, arr,
+                                   epoch0=getattr(comm, "_ft_epoch0", 0))
+            finally:
+                lock.release()
         if rec and _obs.enabled:
             # flow id from (sender process, wire seq) — both already
             # ride the envelope, so the receiver derives the SAME id
@@ -545,72 +558,67 @@ class WireRouter:
         popped, its payload is consumed to completion — the sender
         wrote it immediately behind the envelope on the same lane FIFO,
         so the stall is bounded by the in-flight transfer, not by user
-        behavior (head-of-line now scoped to ONE lane: other tags'
-        lanes stay drainable, by this thread on its next sweep or by a
+        behavior (head-of-line scoped to ONE lane: other tags' lanes
+        stay drainable, by this thread on its next sweep or by a
         concurrent thread — busy lanes are skipped, never waited on).
         A sender dying between envelope and payload surfaces as a loud
         ERR_TRUNCATE here, never a silently dropped message.
+
+        A sweep LOOKS at every lane (a probe that consumes nothing,
+        microseconds) and pumps the ones that hold a frame; with none,
+        the caller parks until the endpoint queues a frame on ANY tag.
+        It never waits on one lane while another holds the message:
+        that wait (10 ms a lane, from a rotating first lane) made a
+        blocking receive cost 0-30 ms by where the rotation stood
+        (20 and 50 ms ping-pongs on the chip, PERF.md section 6, PR
+        28). All ``_MAX_LANES`` lanes are looked at, so a sender
+        configured with MORE lanes than the local cvar never has its
+        messages stranded.
         """
         if self._deliver_ready(dst_world_rank):
             return True
         # cheap empty-channel fast path for nonblocking progress
-        # (imprecise: pending() counts frames on every tag, so other
-        # traffic forces the short recv below — never misses a frame)
+        # (imprecise: pending() counts frames on every tag — never
+        # misses a frame)
         if timeout_ms <= 1 and self.ep.pending() == 0:
             return False
+        from ..btl.components import stashed_pending
+
         deadline = time.monotonic() + timeout_ms / 1000
-        nlanes = self.tuning().lanes
-        # lanes beyond the local cvar get ONE cheap probe per blocking
-        # drain call: a sender configured with MORE lanes
-        # (heterogeneous MCA env, or the cvar flipped mid-flight) must
-        # never have its messages stranded on a tag we refuse to poll —
-        # but the mismatch path must not tax every sweep either
-        probe_extras = timeout_ms > 1 and nlanes < _MAX_LANES
-        start = self._drain_rr.get(dst_world_rank, 0) % max(nlanes, 1)
+        # rotate the first lane: a caller that returns at its first
+        # delivery must not always serve the same lane first
+        start = self._drain_rr.get(dst_world_rank, 0) % _MAX_LANES
         self._drain_rr[dst_world_rank] = start + 1
-        first_sweep = True
+        woken = False  # the last pass parked and a frame woke it
         while True:
-            pumped_any = False
+            pumped = contended = False
             for i in range(_MAX_LANES):
-                # rotate only the first sweep's order; later sweeps
-                # are inside a blocking wait and cover every lane
-                lane = (start + i) % nlanes if (first_sweep
-                                                and i < nlanes) else i
-                local = lane < nlanes
-                if not local and not probe_extras:
-                    continue
-                if pumped_any and time.monotonic() >= deadline:
-                    break  # bound nonblocking polls at ~one lane pump
+                lane = (start + i) % _MAX_LANES
+                tag = self._p2p_tag(dst_world_rank, lane)
+                if not (stashed_pending(self.ep, tag)
+                        or self.ep.queued(tag)):
+                    continue  # nothing to pump: in the stash or queued
                 lk = self._chan_lock("drain", (dst_world_rank, lane))
                 if not lk.acquire(blocking=False):
-                    continue  # another thread is pumping this lane
+                    contended = True  # another thread pumps this lane
+                    continue
                 try:
-                    pumped_any = True
-                    left = deadline - time.monotonic()
-                    # short per-lane envelope wait so one silent lane
-                    # cannot eat the whole budget when others have
-                    # frames queued; a single lane gets the full wait;
-                    # extra (mismatch-tolerance) lanes get the minimum
-                    if not local:
-                        per = 0.001
-                    elif nlanes == 1:
-                        per = left
-                    else:
-                        per = min(left, 0.01)
+                    pumped = True
                     self._pump_lane(dst_world_rank, lane,
-                                    time.monotonic() + max(per, 0.001))
+                                    time.monotonic() + 0.001)
                 finally:
                     lk.release()
                 if self._deliver_ready(dst_world_rank):
                     return True
-            probe_extras = False  # once per call is tolerance enough
-            first_sweep = False
-            if time.monotonic() >= deadline:
-                return False
-            if not pumped_any:
-                # every lane is owned by another thread: yield instead
-                # of spinning on try-acquires until the deadline
+            if contended or (woken and not pumped):
+                # frames are queued that this sweep could not take (a
+                # lane another thread owns, another consumer's tag):
+                # the any-tag wait would return at once, so nap first
                 time.sleep(0.001)
+            left = deadline - time.monotonic()
+            if left <= 0:
+                return False
+            woken = self.ep.queued(-1, max(1, int(left * 1000)))
 
     def _pump_lane(self, dst_world: int, lane: int,
                    deadline: float) -> bool:
@@ -636,7 +644,10 @@ class WireRouter:
         rec = _obs.enabled  # capture once: flag may flip mid-recv
         t0 = time.perf_counter() if rec else 0.0
         try:
-            data = self._recv_payload(tag, src_pidx)
+            with _obs.span(_spans.WIRE_P2P_PUMP, seq=int(seq)) as sp:
+                data = self._recv_payload(tag, src_pidx, put=_p2p_put)
+                # known only now: the size rides the payload's header
+                sp.set_metadata(bytes=_spans.nbytes(data))
         except MPIError as e:
             if e.code == ErrorCode.ERR_PROC_FAILED:
                 # the transport already issued the typed ULFM verdict

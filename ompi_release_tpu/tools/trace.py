@@ -161,6 +161,15 @@ def profiler_trace(logdir: str):
       to the host / a host result placed on the device (``bytes``)
     - ``ompi.wire.stash`` — a sender draining its own inbound ring
       because the peer's is full (``bytes``)
+    - ``ompi.pml.send`` — a cross-process ``isend``/``send``, entry to
+      return (``bytes``, ``peer``, ``tag``), with ``ompi.pml.d2h`` (the
+      payload's fetch, ``bytes``) and ``ompi.wire.p2p_send`` (lane lock,
+      envelope, payload; ``bytes``, ``seq``) inside it
+    - ``ompi.pml.recv_wait`` — the blocking wait of a cross-process
+      receive until its request completes (``source``, ``tag``)
+    - ``ompi.wire.p2p_pump`` — one p2p message off its lane, envelope
+      to payload complete (the sender's ``seq``, ``bytes``), with
+      ``ompi.pml.h2d`` (the arrival's ``device_put``, ``bytes``) inside
 
     ``(cid, seq)`` joins an exchange to its ``ompi.nbc.wait`` when the
     schedule ran on another thread; on one thread nesting is the link.

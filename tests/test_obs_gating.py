@@ -337,7 +337,12 @@ def test_span_sites_exist_and_only_the_helper_annotates():
               "NBC_WAIT": "coll/nbc.py",
               "PLAN_NATIVE_FIRE": "coll/native_exec.py",
               "PLAN_XCHG": "coll/plan.py", "HIER_D2H": "coll/hier.py",
-              "HIER_H2D": "coll/hier.py", "WIRE_STASH": "btl/nativewire.py"}
+              "HIER_H2D": "coll/hier.py", "WIRE_STASH": "btl/nativewire.py",
+              "PML_SEND": "p2p/pml.py", "PML_D2H": "p2p/pml.py",
+              "PML_RECV_WAIT": "p2p/pml.py",
+              "WIRE_P2P_SEND": "runtime/wire.py",
+              "WIRE_P2P_PUMP": "runtime/wire.py",
+              "PML_H2D": "runtime/wire.py"}
     for const, rel in wanted.items():
         path = os.path.join(pkg, rel)
         tree = ast.parse(open(path).read())
