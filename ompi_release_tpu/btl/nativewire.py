@@ -1035,8 +1035,19 @@ class NativeWireBtl(DcnBtl):
                         got += 1
                         self.staged_chunks_pvar.add()
                         continue
-                    if rc in (-1, -4, -5):
-                        continue  # slice timeout / stale / raced
+                    if rc == -1:
+                        # nothing came in a whole slice. Its sender may
+                        # be parked on a full ring to a process that is
+                        # itself parked in a read like this one — with
+                        # four processes around a cycle (0 writes to 3,
+                        # which reads from 2, which writes to 1, which
+                        # reads from 0): a reader has to take what is
+                        # queued for it on its OTHER inbound rings, as
+                        # a sender on a full ring does (_ring_put)
+                        self._stash_inbound()
+                        continue
+                    if rc in (-4, -5):
+                        continue  # stale / raced
                     if rc == -3:
                         raise MPIError(
                             ErrorCode.ERR_PROC_FAILED,

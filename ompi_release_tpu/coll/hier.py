@@ -54,8 +54,10 @@ their member ordering.
 
 from __future__ import annotations
 
+import functools
+import math
 import time as _time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +105,15 @@ _leader_combines = pvar.counter(
     "host-leader-tier combines performed by spanning collectives",
 )
 
+_assembled = pvar.counter(
+    "hier_assembled_results",
+    "results of bcast/allgather/gather/alltoall on a spanning comm "
+    "built in one pass from the rank's own buffer and the arrivals "
+    "(_HierModule._assemble)",
+)
+_assembled_bytes = pvar.counter(
+    "hier_assembled_bytes", "bytes of those results")
+
 #: current spanning-collective round per comm cid, maintained only
 #: while obs is enabled: {"op", "round", "awaiting_procs",
 #: "awaiting_ranks"}. THE answer to "the job is stuck — who is waiting
@@ -136,6 +147,44 @@ def _h2d(v):
         return jnp.asarray(v)
     with _obs.span(_spans.HIER_H2D, bytes=_spans.nbytes(v)):
         return jnp.asarray(v)
+
+
+def _aligned_empty(shape: Tuple[int, ...], dtype) -> np.ndarray:
+    """``np.empty`` on a 64-byte boundary: the CPU backend keeps such
+    a buffer as the array's own instead of copying it."""
+    dtype = np.dtype(dtype)
+    nbytes = math.prod(shape) * dtype.itemsize
+    raw = np.empty(nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    return raw[start:start + nbytes].view(dtype).reshape(shape)
+
+
+@functools.lru_cache(maxsize=512)
+def _assemble_program(pieces: Tuple, local_n: int,
+                      shape: Tuple[int, ...]) -> Callable:
+    """The join of :meth:`_HierModule._assemble` as ONE device program
+    over the blocks (jit keeps one executable per block shapes and
+    dtype under it). Everything is cut and joined flat: a slice of a
+    buffer with leading axes of 1 compiles, for the chip, to several
+    megabytes of code in as many seconds."""
+    width = math.prod(shape) // local_n
+
+    def program(*blocks):
+        flat = [b.reshape(-1) for b in blocks]
+
+        def row(member):
+            cuts = [flat[i][start:start + count]
+                    for to, i, start, count in pieces if to == member]
+            return jnp.concatenate(cuts) if cuts \
+                else jnp.zeros((width,), flat[0].dtype)
+
+        if pieces[0][0] is None:
+            out = jnp.broadcast_to(row(None)[None], (local_n, width))
+        else:
+            out = jnp.stack([row(member) for member in range(local_n)])
+        return out.reshape(shape)
+
+    return jax.jit(program)
 
 
 class _XchgAdapter:
@@ -254,6 +303,19 @@ class _HierModule:
         _tuning_db.set_active(
             _tuning_db.fingerprint_for(self.host_of, len(self.procs)),
             force=False)
+        # where a result is joined (_assemble): a host rank's "device"
+        # is the CPU backend, whose arrays live in host memory
+        devs = getattr(comm.submesh, "devices", None)
+        self._host_join = (devs.flat[0].platform if devs is not None
+                           else jax.default_backend()) == "cpu"
+        #: comm rank r's row of a gathered result: (its owner's block
+        #: as _assemble numbers them — 0 this process's, then the
+        #: peers' — and its row in that block)
+        self._rows = tuple(
+            (0 if self.owner[r] == self.my_pidx
+             else 1 + self.peers.index(self.owner[r]),
+             self.members_of[self.owner[r]].index(r))
+            for r in range(comm.size))
         self._xchg = _XchgAdapter(self)
         # handle for coll/plan's frozen-schedule record/replay: the
         # plan layer swaps _xchg for the duration of ONE schedule run
@@ -704,14 +766,80 @@ class _HierModule:
             value[None], (self.local_n,) + value.shape
         )
 
-    @staticmethod
-    def _cat(parts: list) -> np.ndarray:
-        """Concatenate per-rank slices the way all_gather+reshape does
-        (0-d slices stack into a vector)."""
-        parts = [_d2h(p) for p in parts]
-        if parts[0].ndim == 0:
-            return np.stack(parts)
-        return np.concatenate(parts, axis=0)
+    def _assemble(self, x, fetched: np.ndarray, arrivals: Sequence,
+                  pieces: Tuple, shape: Tuple[int, ...]):
+        """The caller's array of a data-movement collective, built in
+        ONE pass from blocks: block 0 is the rank's own buffer — ``x``
+        as the caller passed it where the join runs on the device,
+        ``fetched`` (its host copy, which costs a host rank nothing)
+        where it runs on the host — and the others are the
+        ``arrivals``, which a native fire hands over as read-only views
+        of the executor's slab (``native_exec.VIEW_OPS``), a Python
+        replay as arrays of its own: both are read here and never kept.
+        ``pieces`` are ``(member, block, start, count)``: ``count``
+        elements of the flattened block from ``start`` on, which come
+        next in local member ``member``'s row of the result — or in
+        every member's (``None``, then in all pieces). A member that
+        gets no piece gets zeros.
+
+        Nothing returned may alias the slab, whose plan's next fire
+        overwrites it. On a chip the arrivals are placed on the device
+        (``ompi.hier.h2d``: until the call returns, the transfer may
+        still read them) and joined there with the own buffer by one
+        cached program; the wait below ends only after the transfers
+        have. On a host rank the pieces are written once into an
+        aligned buffer of this call's own, which the CPU backend then
+        keeps without another copy."""
+        if getattr(self._xchg, "dry_run", False):
+            # the native-plan probe reads the schedule's sends alone
+            from .base import NO_RESULT
+
+            return NO_RESULT
+        blocks = [fetched if self._host_join else x, *arrivals]
+        dtype = blocks[0].dtype
+        nbytes = math.prod(shape) * dtype.itemsize
+        with _obs.span(_spans.HIER_ASSEMBLE, bytes=nbytes):
+            if self._host_join:
+                out = _aligned_empty(shape, dtype)
+                rows = out.reshape(self.local_n, -1)
+                flat = [b.reshape(-1) for b in blocks]
+                at = [0] * self.local_n
+                for member, i, start, count in pieces:
+                    row = member or 0
+                    rows[row, at[row]:at[row] + count] = \
+                        flat[i][start:start + count]
+                    at[row] += count
+                if pieces[0][0] is None:
+                    rows[1:] = rows[0]
+                else:
+                    for row, filled in enumerate(at):
+                        rows[row, filled:] = 0
+                out = _h2d(out)
+            else:
+                placed = [_h2d(b) for b in blocks]
+                out = _assemble_program(pieces, self.local_n,
+                                        shape)(*placed)
+                if not all(isinstance(b, jax.Array) for b in blocks):
+                    out.block_until_ready()
+        _assembled.add()
+        _assembled_bytes.add(nbytes)
+        return out
+
+    def _assemble_rows(self, x, block: np.ndarray,
+                       blocks: Dict[int, np.ndarray],
+                       member: Optional[int] = None):
+        """Rank-order concatenation of every comm rank's row (0-d rows
+        stack into a vector, as all_gather + reshape does) for every
+        local member, or for ``member`` alone. ``blocks`` holds every
+        peer's block; this process's is ``x``, fetched as ``block``."""
+        row = block.shape[1:]
+        count = math.prod(row)
+        shape = (self.local_n, self.comm.size * row[0]) + row[1:] \
+            if row else (self.local_n, self.comm.size)
+        return self._assemble(
+            x, block, [blocks[p] for p in self.peers],
+            tuple((member, i, pos * count, count)
+                  for i, pos in self._rows), shape)
 
     # -- operation table ---------------------------------------------------
     def _wrap(self, name: str, fn: Callable) -> Callable:
@@ -840,7 +968,8 @@ class _HierModule:
         me = self.my_pidx
         if owner == me:
             self._check_local_axis(x, "bcast")
-            val = _d2h(x[self.local_ranks.index(root)])
+            idx = self.local_ranks.index(root)
+            val = _d2h(x[idx])
         else:
             val = None
         # every rank passes an x of the same per-slice shape (the
@@ -866,7 +995,16 @@ class _HierModule:
             self._xchg.exchange({p: [val] for p in self.peers}, {})
         else:
             val = self._xchg.exchange({}, {owner: 1})[owner][0]
-        return self._bcast_local_axis(val)
+        if owner == me:
+            # the root's slice is where the result needs it: in x
+            count = int(xa.size // self.local_n)
+            return self._assemble(x, xa, (),
+                                  ((None, 0, idx * count, count),),
+                                  xa.shape)
+        val = np.asarray(val)
+        return self._assemble(val, val, (),
+                              ((None, 0, 0, int(val.size)),),
+                              (self.local_n,) + val.shape)
 
     def _bcast_leader(self, owner: int, val):
         """Leader-tier bcast: binomial over {owner + other hosts'
@@ -888,11 +1026,11 @@ class _HierModule:
         return np.asarray(
             _hs.round_exchange(self._xchg, {}, {src: 1})[src][0])
 
-    def _gather_block_rows(self,
-                           block: np.ndarray) -> Dict[int, np.ndarray]:
-        """Every rank's slice via the selected allgather schedule over
-        per-process blocks (one (local_n, chunk...) block each);
-        returns {comm rank: row}."""
+    def _gather_blocks(self,
+                       block: np.ndarray) -> Dict[int, np.ndarray]:
+        """Every process's block via the selected allgather schedule
+        (one (local_n, chunk...) block each); returns {process: block},
+        this process's own entry being ``block`` itself."""
         me = self.my_pidx
         P = len(self.procs)
         chunk_shape = block.shape[1:]
@@ -925,19 +1063,13 @@ class _HierModule:
             parts = _hs.allgather_ring(self._xchg, self.procs, me, block)
             for i, p in enumerate(self.procs):
                 blocks[p] = np.asarray(parts[i])
-        rows: Dict[int, np.ndarray] = {}
-        for p in self.procs:
-            pblock = blocks[p]
-            for pos, r in enumerate(self.members_of[p]):
-                rows[r] = pblock[pos]
-        return rows
+        return blocks
 
     def allgather(self, comm, x):
         self._check_local_axis(x, "allgather")
-        block = _d2h(x)  # (local_n, chunk...)
-        rows = self._gather_block_rows(block)
-        full = self._cat([rows[r] for r in range(comm.size)])
-        return self._bcast_local_axis(full)
+        block = _d2h(x)  # (local_n, chunk...): what the peers are sent
+        blocks = self._gather_blocks(block)
+        return self._assemble_rows(x, block, blocks)
 
     def gather(self, comm, x, root: int):
         self._check_local_axis(x, "gather")
@@ -953,7 +1085,6 @@ class _HierModule:
         slice_bytes = int(chunk_elems * block.itemsize)
         alg = _hs.pick("gather", P, slice_bytes) if P > 1 else "linear"
         self._note_alg(alg)
-        rows: Dict[int, np.ndarray] = {}
         if alg == "binomial" and P > 1:
             counts = [len(self.members_of[p]) * chunk_elems
                       for p in self.procs]
@@ -963,27 +1094,18 @@ class _HierModule:
             if flats is None:
                 return jnp.zeros((self.local_n,) + full_shape,
                                  block.dtype)
-            for i, p in enumerate(self.procs):
-                pblock = np.asarray(flats[i]).reshape(
-                    (len(self.members_of[p]),) + chunk_shape)
-                for pos, r in enumerate(self.members_of[p]):
-                    rows[r] = pblock[pos]
+            blocks = {p: np.asarray(flats[i]).reshape(
+                (len(self.members_of[p]),) + chunk_shape)
+                for i, p in enumerate(self.procs)}
         else:
             if owner != me:
                 self._xchg.exchange({owner: [block]}, {})
                 return jnp.zeros((self.local_n,) + full_shape,
                                  block.dtype)
-            for pos, r in enumerate(self.members_of[me]):
-                rows[r] = block[pos]
             got = self._xchg.exchange({}, {p: 1 for p in self.peers})
-            for p in self.peers:
-                pblock = np.asarray(got[p][0])
-                for pos, r in enumerate(self.members_of[p]):
-                    rows[r] = pblock[pos]
-        full = self._cat([rows[r] for r in range(comm.size)])
-        out = np.zeros((self.local_n,) + full.shape, full.dtype)
-        out[self.local_ranks.index(root)] = full
-        return _h2d(out)
+            blocks = {p: np.asarray(got[p][0]) for p in self.peers}
+        return self._assemble_rows(x, block, blocks,
+                                   member=self.local_ranks.index(root))
 
     def scatter(self, comm, x, root: int):
         n = comm.size
@@ -1084,17 +1206,21 @@ class _HierModule:
             got = self._exchange({p: [chunks[:, self.members_of[p]]]
                                   for p in self.peers})
             recv_block = {p: np.asarray(got[p][0]) for p in self.peers}
-        out = np.empty_like(chunks)
-        # local block: out[b, i] = in[a, j] for local members i->j
-        for a, i in enumerate(self.local_ranks):
-            for b, j in enumerate(self.local_ranks):
-                out[b, i] = chunks[a, j]
-        for p in self.peers:
-            r = recv_block[p]  # [a, b]: p's member a -> my member b
-            for a, i in enumerate(self.members_of[p]):
-                for b in range(self.local_n):
-                    out[b, i] = r[a, b]
-        return _h2d(out.reshape(block.shape))
+        # out[b, i] for my member b and comm rank i: from a local
+        # member a it is in[a, mine[b]], from peer p's member a it is
+        # recv_block[p][a, b]; each is cf elements of its flat block
+        mine = self.local_ranks
+        cf = c * trail
+        pieces = tuple(
+            (b, 0, (mine.index(i) * n + mine[b]) * cf, cf)
+            if self.owner[i] == me else
+            (b, 1 + self.peers.index(self.owner[i]),
+             (self.members_of[self.owner[i]].index(i) * self.local_n
+              + b) * cf, cf)
+            for b in range(self.local_n) for i in range(n))
+        return self._assemble(x, block,
+                              [recv_block[p] for p in self.peers],
+                              pieces, block.shape)
 
     # -- v-variant collectives (ragged; lists indexed by LOCAL member) -----
     # Spanning-comm analogue of coll/vcoll.py's driver-mode convention:
@@ -1334,7 +1460,9 @@ class _HierModule:
     # -- prefix scans ------------------------------------------------------
     def _full_rows(self, x) -> Dict[int, np.ndarray]:
         """Every rank's slice, via the selected allgather schedule."""
-        return self._gather_block_rows(_d2h(x))
+        blocks = self._gather_blocks(_d2h(x))
+        return {r: blocks[p][pos] for p in self.procs
+                for pos, r in enumerate(self.members_of[p])}
 
     def _scan_impl(self, comm, x, op: Op, exclusive: bool):
         if op.is_pair_op:

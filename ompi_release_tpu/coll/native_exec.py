@@ -89,6 +89,22 @@ _native_fallbacks = pvar.counter(
     "ring-lock contention)",
 )
 
+_pool_copy_bytes = pvar.counter(
+    "plan_pool_copy_bytes",
+    "bytes copied out of a native plan's reassembly slab into fresh "
+    "arrays for a schedule that folds its arrivals (0 for the "
+    "collectives of VIEW_OPS, whose results are assembled straight "
+    "from the slab: hier_assembled_bytes)",
+)
+
+#: collectives whose schedules only MOVE what arrives: a native fire
+#: hands them read-only views of the slab and ``coll/hier`` assembles
+#: the caller's array from those in one pass (``_HierModule._assemble``
+#: has read every view before the schedule returns, so before the
+#: plan's next fire overwrites the slab). Every other plannable
+#: collective folds its arrivals in later jax calls and gets copies.
+VIEW_OPS = frozenset({"allgather", "alltoall", "bcast", "gather"})
+
 _BLOB_MAGIC = 0x314345584C504F  # "OPLXEC1" little-endian
 _BLOB_VERSION = 1
 _WIN = 16        # provenance-window bytes: unique-match granularity
@@ -138,6 +154,10 @@ class _ProbeXchg:
     random receive arrays (the future pool regions)."""
 
     __slots__ = ("plan", "pools", "i", "payloads")
+
+    #: nobody reads what the schedule body returns under this adapter:
+    #: ``hier._assemble`` builds no result for it
+    dry_run = True
 
     def __init__(self, plan, pools: Dict[Tuple[int, int], list]) -> None:
         self.plan = plan
@@ -432,12 +452,9 @@ class NativePlan:
     )
 
     def close(self) -> None:
-        px, self.px = self.px, None
-        if px is not None:
-            try:
-                px.close()
-            except Exception:
-                pass
+        # the C side is destroyed with the executor's last reference:
+        # here, unless a slab view handed to a schedule still holds it
+        self.px = None
 
 
 def _sentinel_level() -> int:
@@ -679,19 +696,22 @@ class NativeXchg:
     round: round-0 sends come verbatim from the arrays the schedule
     just passed, later rounds compose from the proven byte-provenance
     maps, receives reassemble into the plan pool. Rounds >= 1 only
-    verify structure and hand back pool copies. Any per-fire safety
+    verify structure and hand back what arrived: read-only views of
+    the pool where ``views`` says the schedule reads them once and
+    keeps none (:data:`VIEW_OPS`), else copies. Any per-fire safety
     veto (stashed frames, lock contention) delegates the entire fire
     to a fresh :class:`~.plan.PlannedXchg` — same plan, same bytes."""
 
-    __slots__ = ("m", "plan", "np", "i", "ts", "args", "seq",
+    __slots__ = ("m", "plan", "np", "i", "ts", "args", "seq", "views",
                  "_delegate", "_pool", "_c_wait")
 
     def __init__(self, module, plan, npl: NativePlan,
-                 args: Tuple, seq: int = 0) -> None:
+                 args: Tuple, seq: int = 0, views: bool = False) -> None:
         self.m = module
         self.plan = plan
         self.np = npl
         self.i = 0
+        self.views = views
         #: the schedule's posting seq: joins this fire's span to its
         #: ``ompi.nbc.wait``
         self.seq = seq
@@ -702,7 +722,7 @@ class NativeXchg:
         #: seconds spent blocked in the C slice loop during the last
         #: exchange — wire-transport time, subtracted from the
         #: orchestration self-report (the ctypes entry/exit and pool
-        #: copies are Python orchestration; the descriptor walk isn't)
+        #: reads are Python orchestration; the descriptor walk isn't)
         self._c_wait = 0.0
 
     def _mismatch(self, detail: str) -> MPIError:
@@ -754,7 +774,7 @@ class NativeXchg:
             dg.ts = self.ts
             self._delegate = dg
             return dg.exchange(sends, recvs)
-        got = self._materialize(self.i)
+        got = self._arrivals(self.i)
         self.i += 1
         return got
 
@@ -969,15 +989,19 @@ class NativeXchg:
         _native_ring_yields.add(px.ring_yields())
         _native_fires.add()
 
-    def _materialize(self, r: int) -> Dict[int, list]:
+    def _arrivals(self, r: int) -> Dict[int, list]:
+        """Round ``r``'s arrivals, per source in message order, with
+        the frozen plan's shapes and dtypes."""
         npl = self.np
         pool = self._pool
         got: Dict[int, list] = {}
         for src, lst in npl.pool_rounds[r]:
             arrs = []
             for _pool_idx, off, shape, dt, nb in lst:
-                a = np.empty(shape, dtype=dt)
-                a.reshape(-1).view(np.uint8)[:] = pool[off:off + nb]
+                a = pool[off:off + nb].view(dt).reshape(shape)
+                if not self.views:
+                    a = a.copy()
+                    _pool_copy_bytes.add(nb)
                 arrs.append(a)
             got[src] = arrs
         return got

@@ -776,7 +776,8 @@ class SpanningPlanState:
         nx = self.native
         if nx is not None and nx.gen == plan.gen:
             from . import native_exec as _native
-            px = _native.NativeXchg(m, plan, nx, args, seq)
+            px = _native.NativeXchg(m, plan, nx, args, seq,
+                                    views=self.name in _native.VIEW_OPS)
         else:
             px = PlannedXchg(m, plan, seq)
         t0 = 0.0

@@ -969,8 +969,12 @@ class PlanExec:
         return int(self._lib.planexec_input_count(self._handle()))
 
     def pool_view(self):
-        """Zero-copy uint8 ndarray over the reassembly slab (valid
-        until close; reused across fires — consumers copy out)."""
+        """Read-only uint8 ndarray over the reassembly slab, no copy.
+        The slab is reused: the next fire of this plan overwrites it,
+        so whoever reads a slice must have finished before then. The
+        view (and every slice of it) keeps this executor alive — drop
+        the last reference instead of calling ``close`` while one may
+        still be read."""
         import numpy as _np
 
         total = self.pool_total
@@ -978,7 +982,10 @@ class PlanExec:
             return _np.empty(0, dtype=_np.uint8)
         ptr = self._lib.planexec_pool_ptr(self._handle())
         buf = (ctypes.c_uint8 * total).from_address(ptr)
-        return _np.frombuffer(buf, dtype=_np.uint8)
+        buf._owner = self  # numpy's base chain ends at ``buf``
+        # through a read-only buffer: no slice can be made writeable
+        return _np.frombuffer(memoryview(buf).toreadonly(),
+                              dtype=_np.uint8)
 
     def round_ts(self):
         """Per-round CLOCK_MONOTONIC end stamps from the last fire —

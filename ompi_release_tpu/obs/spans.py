@@ -34,6 +34,11 @@ keyword stats of the event, never folded into the name:
                            (``cid``, ``seq``, ``bytes`` sent)
 ``ompi.hier.d2h``          a device buffer fetched to the host (``bytes``)
 ``ompi.hier.h2d``          a host result placed on the device (``bytes``)
+``ompi.hier.assemble``     the result of a bcast, allgather, gather or
+                           alltoall built in one pass from the rank's
+                           own buffer and the arrivals, until nothing
+                           of it reads the arrivals any more (``bytes``
+                           of the result)
 ``ompi.wire.stash``        a sender draining one of its own inbound rings
                            because the peer's ring is full (``bytes``
                            queued in that ring)
@@ -77,6 +82,7 @@ PLAN_NATIVE_FIRE = "ompi.plan.native_fire"
 PLAN_XCHG = "ompi.plan.xchg"
 HIER_D2H = "ompi.hier.d2h"
 HIER_H2D = "ompi.hier.h2d"
+HIER_ASSEMBLE = "ompi.hier.assemble"
 WIRE_STASH = "ompi.wire.stash"
 PML_SEND = "ompi.pml.send"
 PML_D2H = "ompi.pml.d2h"
@@ -88,7 +94,7 @@ PML_H2D = "ompi.pml.h2d"
 NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
          PLAN_NATIVE_FIRE, PLAN_XCHG, HIER_D2H, HIER_H2D, WIRE_STASH,
          PML_SEND, PML_D2H, WIRE_P2P_SEND, PML_RECV_WAIT, WIRE_P2P_PUMP,
-         PML_H2D)
+         PML_H2D, HIER_ASSEMBLE)
 
 #: ``jax.profiler.TraceAnnotation`` and the ``obs`` package, bound on
 #: the first span: importing ``obs`` must not import jax (``obs

@@ -159,6 +159,9 @@ def profiler_trace(logdir: str):
       planned or interpreted (``cid``, ``seq``, ``bytes``)
     - ``ompi.hier.d2h`` / ``ompi.hier.h2d`` — a device buffer fetched
       to the host / a host result placed on the device (``bytes``)
+    - ``ompi.hier.assemble`` — the result of a spanning bcast,
+      allgather, gather or alltoall built in one pass from the rank's
+      own buffer and the arrivals (``bytes`` of the result)
     - ``ompi.wire.stash`` — a sender draining its own inbound ring
       because the peer's is full (``bytes``)
     - ``ompi.pml.send`` — a cross-process ``isend``/``send``, entry to

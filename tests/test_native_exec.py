@@ -300,6 +300,246 @@ class TestExecutorFullRing:
             pair.close()
 
 
+@needs_native
+class TestSlabViews:
+    """What a native fire hands a schedule (ISSUE 31): read-only views
+    of the executor's slab for the collectives that only move their
+    arrivals, copies for those that fold them."""
+
+    NB = 4096
+
+    def _fired(self, name, fill):
+        pair = _ExecPair(name, 1 << 20, self.NB, 1024, a_sends=True,
+                         b_sends=False)
+        data = np.full(self.NB // 4, fill, np.int32)
+        rcs = [None, None]
+        import threading
+
+        th = threading.Thread(target=pair.fire, args=(0, data, 10, rcs))
+        th.start()
+        pair.fire(1, data, 20, rcs)
+        th.join(30)
+        assert rcs == [0, 0], rcs
+        return pair, data
+
+    def _xchg(self, px, views):
+        npl = nx.NativePlan()
+        npl.pool_rounds = [[(0, [(0, 0, (2, self.NB // 8),
+                                  np.dtype("int32"), self.NB)])]]
+        x = nx.NativeXchg(None, None, npl, (), views=views)
+        x._pool = px.pool_view()
+        return x
+
+    def test_views_are_the_slab_read_only_and_hold_the_executor(self):
+        pair, data = self._fired("sv-view", 7)
+        try:
+            px = pair.px[1]
+            copied = nx._pool_copy_bytes.read()
+            (a,) = self._xchg(px, views=True)._arrivals(0)[0]
+            assert a.shape == (2, self.NB // 8) and a.dtype == np.int32
+            np.testing.assert_array_equal(a.reshape(-1), data)
+            assert not a.flags.writeable and not a.flags.owndata
+            with pytest.raises(ValueError):
+                a[0, 0] = 1
+            with pytest.raises(ValueError):
+                a.setflags(write=True)
+            assert nx._pool_copy_bytes.read() == copied
+            base = a
+            while isinstance(base, np.ndarray):
+                base = base.base
+            # a slice keeps the slab alive (the read-only buffer under
+            # numpy's base chain holds the executor)
+            assert base.readonly and base.obj._owner is px
+            # the slab is REUSED: the plan's next fire lands in it
+            rcs = [None, None]
+            import threading
+
+            fresh = np.full(self.NB // 4, 9, np.int32)
+            th = threading.Thread(target=pair.fire,
+                                  args=(0, fresh, 30, rcs))
+            th.start()
+            pair.fire(1, fresh, 40, rcs)
+            th.join(30)
+            assert rcs == [0, 0], rcs
+            np.testing.assert_array_equal(a.reshape(-1), fresh)
+        finally:
+            pair.close()
+
+    def test_copies_for_schedules_that_fold(self):
+        pair, data = self._fired("sv-copy", 3)
+        try:
+            copied = nx._pool_copy_bytes.read()
+            (a,) = self._xchg(pair.px[1], views=False)._arrivals(0)[0]
+            assert a.flags.writeable and a.flags.owndata
+            np.testing.assert_array_equal(a.reshape(-1), data)
+            assert nx._pool_copy_bytes.read() - copied == self.NB
+        finally:
+            pair.close()
+
+    def test_view_ops_are_the_data_movement_collectives(self):
+        assert nx.VIEW_OPS == {"allgather", "alltoall", "bcast", "gather"}
+        assert nx.VIEW_OPS <= cplan._PLANNABLE
+
+    def test_closing_a_plan_leaves_the_slab_to_its_views(self):
+        """``NativePlan.close`` drops its reference; the C side goes
+        with the last one, so a view read after a re-plan is sound."""
+        from ompi_release_tpu.native.bindings import PlanExec
+
+        rounds = [{"depth": 2, "streams": [],
+                   "rsrcs": [(0, [(0, 64, 0, 64, b"P", b"M")])]}]
+        npl = nx.NativePlan()
+        npl.px = PlanExec(nx.build_blob(7, [], [64], [3], rounds))
+        view = npl.px.pool_view()
+        npl.close()
+        assert npl.px is None
+        assert int(view.sum()) == 0 and view.base.obj._owner._h  # still there
+
+
+def _hier_counters():
+    from ompi_release_tpu.coll import hier
+
+    return (hier._assembled.read(), hier._assembled_bytes.read())
+
+
+class _JoinModule:
+    """``_HierModule``'s ``_assemble`` on a hand-made layout: two
+    processes of two members each, this one being process ``me``."""
+
+    def __new__(cls, host, local_n=2, me=0):
+        import types
+
+        from ompi_release_tpu.coll import hier
+
+        m = object.__new__(hier._HierModule)
+        m.local_n, m._host_join, m._xchg = local_n, host, object()
+        m.procs, m.my_pidx = [0, 1], me
+        m.comm = types.SimpleNamespace(size=2 * local_n)
+        # block 0 is this process's, block 1 the peer's
+        m._rows = tuple((int(r // local_n != me), r % local_n)
+                        for r in range(2 * local_n))
+        return m
+
+
+def _slab(shape, seed):
+    """An int32 'arrival' as a native fire hands it over: a read-only
+    view into a 64-byte aligned buffer, which the CPU backend would
+    keep instead of copying. Returns (view, the buffer to overwrite)."""
+    from ompi_release_tpu.coll import hier
+
+    n = int(np.prod(shape))
+    buf = hier._aligned_empty((n,), np.int32)
+    buf[:] = np.random.default_rng(seed).integers(0, 1 << 30, n)
+    view = buf.reshape(shape)[...]
+    view.setflags(write=False)
+    return view, buf
+
+
+class TestAssemble:
+    """One pass from the slab to the caller's array (ISSUE 31): the two
+    joins give the same array as the numpy the four collectives used to
+    run, and nothing of it aliases the slab, which the plan's next fire
+    overwrites. Each case fails on a tree that hands the caller the
+    arrival itself (``jnp.asarray`` of an aligned view keeps it)."""
+
+    JOINS = pytest.mark.parametrize("host", [True, False],
+                                    ids=["host_join", "device_join"])
+
+    @staticmethod
+    def _own(block):  # the caller's buffer, as jax holds it
+        import jax.numpy as jnp
+
+        return jnp.asarray(block)
+
+    @JOINS
+    @pytest.mark.parametrize("member", [None, 1],
+                             ids=["allgather", "gather"])
+    @pytest.mark.parametrize("row", [(), (3,), (4, 5)],
+                             ids=["0d", "1d", "2d"])
+    def test_rows(self, host, member, row):
+        m = _JoinModule(host, me=1)
+        block = np.arange(2 * int(np.prod(row)), dtype=np.int32).reshape(
+            (2,) + row) - 100
+        theirs, buf = _slab((2,) + row, 1)
+        rows = list(theirs) + list(block)  # process 0's rows come first
+        full = np.stack(rows) if not row else np.concatenate(rows)
+        want = np.zeros((2,) + full.shape, np.int32)
+        want[slice(None) if member is None else member] = full
+        before = _hier_counters()
+        out = m._assemble_rows(self._own(block), block, {0: theirs},
+                               member=member)
+        buf[:] = -1  # the plan's next fire
+        assert out.shape == want.shape and out.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(out), want)
+        assert _hier_counters() == (before[0] + 1,
+                                      before[1] + want.nbytes)
+
+    @JOINS
+    @pytest.mark.parametrize("local_n", [1, 2])
+    def test_bcast_off_the_root_and_at_it(self, host, local_n):
+        m = _JoinModule(host, local_n=local_n)
+        val, buf = _slab((6, 2), 2)
+        want = np.broadcast_to(np.array(val)[None], (local_n, 6, 2))
+        out = m._assemble(val, val, (), ((None, 0, 0, 12),),
+                          (local_n, 6, 2))
+        buf[:] = -1
+        np.testing.assert_array_equal(np.asarray(out), want)
+        # at the root the slice is cut from the caller's own buffer
+        x = np.arange(local_n * 12, dtype=np.int32).reshape(local_n, 6, 2)
+        out = m._assemble(self._own(x), x, (),
+                          ((None, 0, (local_n - 1) * 12, 12),),
+                          (local_n, 6, 2))
+        np.testing.assert_array_equal(
+            np.asarray(out), np.broadcast_to(x[-1][None], x.shape))
+
+    @JOINS
+    def test_alltoall_interleaves_by_source_rank(self, host):
+        m = _JoinModule(host, me=1)  # my members: comm ranks 2, 3
+        n, c = 4, 3
+        x = np.arange(2 * n * c * 2, dtype=np.int32).reshape(2, n * c, 2)
+        chunks = x.reshape(2, n, c, 2)
+        recv, buf = _slab((2, 2, c, 2), 3)  # [a, b]: 0's member a -> my b
+        want = np.empty_like(chunks)
+        for a, i in enumerate((2, 3)):
+            for b, j in enumerate((2, 3)):
+                want[b, i] = chunks[a, j]
+        for a, i in enumerate((0, 1)):
+            for b in range(2):
+                want[b, i] = recv[a, b]
+        cf = c * 2  # elements of one rank-pair chunk
+        pieces = tuple(
+            (b, 1, (i * 2 + b) * cf, cf) if i < 2 else
+            (b, 0, ((i - 2) * n + (2, 3)[b]) * cf, cf)
+            for b in range(2) for i in range(n))
+        out = m._assemble(self._own(x), x, [recv], pieces, x.shape)
+        buf[:] = -1
+        np.testing.assert_array_equal(np.asarray(out),
+                                      want.reshape(x.shape))
+
+    def test_the_probe_builds_nothing(self):
+        from ompi_release_tpu.coll import base
+
+        m = _JoinModule(True)
+        m._xchg = nx._ProbeXchg(None, {})
+        before = _hier_counters()
+        zeros = np.zeros(4, np.int32)
+        assert m._assemble(zeros, zeros, (), ((None, 0, 0, 4),),
+                           (2, 4)) is base.NO_RESULT
+        assert _hier_counters() == before
+
+    def test_host_join_hands_jax_a_buffer_it_keeps(self):
+        """The host rank's result is written once: the CPU backend takes
+        the aligned buffer as the array's own (no second copy)."""
+        from ompi_release_tpu.coll import hier
+
+        a = hier._aligned_empty((3, 1000), np.float32)
+        assert a.ctypes.data % 64 == 0 and a.shape == (3, 1000)
+        assert hier._aligned_empty((), np.int32).shape == ()
+        import jax.numpy as jnp
+
+        a[:] = 1.5
+        assert jnp.asarray(a).unsafe_buffer_pointer() == a.ctypes.data
+
+
 # ---------------------------------------------------------------------------
 # 1b. byte-provenance matcher (device-free)
 # ---------------------------------------------------------------------------
@@ -659,6 +899,10 @@ class TestNativeJobs:
             assert _pv("plan_pool_hits") >= fires
             assert _pv("plan_pool_bytes") > 0
             assert _pv("wire_native_fallback_copies") == 0
+            # an allreduce folds its arrivals in later jax calls: its
+            # native fires hand it copies, and no result is assembled
+            assert _pv("plan_pool_copy_bytes") > 0
+            assert _pv("hier_assembled_results") == 0
             world.barrier()
             print(f"NATIVE-OK {me} fires={fires}", flush=True)
             mpi.finalize()
@@ -686,8 +930,11 @@ class TestNativeJobs:
             fires = _pv("plan_native_fires")
             if me == 2:
                 assert fires == 0, fires
+                # replayed in Python: nothing comes out of a slab
+                assert _pv("plan_pool_copy_bytes") == 0
             else:
                 assert fires >= 2, fires
+            assert _pv("hier_assembled_results") == 0
             world.barrier()
             print(f"MIXED-OK {me} fires={fires}", flush=True)
             mpi.finalize()
@@ -696,42 +943,100 @@ class TestNativeJobs:
         for me in range(3):
             assert f"MIXED-OK {me} " in out
 
-    @pytest.mark.parametrize("op", ["allgather", "bcast"])
-    def test_many_rings_through_a_small_ring(self, tmp_path, capfd, op):
-        """Two ranks, 64 KiB rings, 1 MiB per process and fire: 16
-        rings each way at once (allgather) or one way (bcast). The
-        native fires return what the interpreted first call returned,
-        bit for bit; with opposing senders some fragment must have
-        met a full ring and yielded; a tx ring counts at most one
-        stall per fragment it carried. Counters, never wall time."""
+    @pytest.mark.parametrize("n", [2, 4])
+    @pytest.mark.parametrize("op", ["allgather", "bcast", "alltoall",
+                                    "gather"])
+    def test_many_rings_through_a_small_ring(self, tmp_path, capfd, op,
+                                             n):
+        """Two and four processes, 64 KiB rings, a message below one
+        ring (4 KiB) and one of many rings (1 MiB per process and fire).
+        The native fires return what the interpreted first call
+        returned, bit for bit; with opposing senders some fragment must
+        have met a full ring and yielded; a tx ring counts at most one
+        stall per fragment it carried. Counters, never wall time.
+
+        The executor's slab is reused (ISSUE 31): a result that was
+        KEPT is bit-identical after the plan's next fire has run with
+        other data, on the host join and on the device join; a fire
+        that is vetoed (``_clean_channel`` false) returns the same array
+        through the same consumer; no byte is copied out of the slab
+        (``plan_pool_copy_bytes`` 0) and every call's result was
+        assembled in one pass (``hier_assembled_results``), the probe's
+        two dry runs building none."""
         rc, out, _ = _run_job(tmp_path, capfd, """
             from ompi_release_tpu.btl import nativewire as nw
+            from ompi_release_tpu.coll import native_exec as nx
             OP = %r
-            per = (1 << 20) // 8  # int32 elems: 512 KiB per local rank
-            x = np.stack([(np.arange(per, dtype=np.int32) * 7
-                           + 1000 * (off + i)) for i in range(2)])
-            call = ((lambda: world.allgather(x)) if OP == "allgather"
-                    else (lambda: world.bcast(x, root=0)))
-            first = np.asarray(call())  # interpreted: records the plan
-            if OP == "allgather":
-                want = np.concatenate(
-                    [np.arange(per, dtype=np.int32) * 7 + 1000 * r
-                     for r in range(n)])
-                np.testing.assert_array_equal(first[0], want)
-            else:
-                np.testing.assert_array_equal(
-                    first[1], np.arange(per, dtype=np.int32) * 7)
-            for it in range(4):
-                np.testing.assert_array_equal(np.asarray(call()),
-                                              first)  # BITWISE
-            fires = _pv("plan_native_fires")
-            assert fires >= 2, fires
-            assert _pv("plan_native_fallbacks") == 0
+            mod = world._hier_module
+            calls = {"allgather": world.allgather,
+                     "alltoall": world.alltoall,
+                     "bcast": lambda v: world.bcast(v, root=1),
+                     "gather": lambda v: world.gather(v, root=n - 1)}
+            call = calls[OP]
+
+            def data(per, salt):
+                return np.stack([(np.arange(per, dtype=np.int32) * 7
+                                  + 1000 * (off + i) + salt)
+                                 for i in range(2)])
+
+            def want(per, salt):  # the result's row for local member 0/1
+                rows = [np.arange(per, dtype=np.int32) * 7 + 1000 * r
+                        + salt for r in range(n)]
+                if OP == "allgather":
+                    return [np.concatenate(rows)] * 2
+                if OP == "bcast":
+                    return [rows[1]] * 2
+                if OP == "gather":
+                    return [np.concatenate(rows) if off + i == n - 1
+                            else np.zeros(per * n, np.int32)
+                            for i in range(2)]
+                c = per // n
+                return [np.concatenate([r[(off + i) * c:(off + i + 1) * c]
+                                        for r in rows]) for i in range(2)]
+
+            # a gather builds a result on the root's process alone
+            builds = OP != "gather" or off + 2 == n
+            asm0 = _pv("hier_assembled_results")
+            made = vetoes = 0
+            for per in (1024, (1 << 20) // 8):  # int32: 4 KiB, 512 KiB a rank
+                xa, xb = data(per, 0), data(per, 5)
+                first = call(xa)  # interpreted: records the plan
+                made += 1
+                # the probe ran the schedule twice and built nothing
+                assert _pv("hier_assembled_results") - asm0 == made * builds
+                np.testing.assert_array_equal(np.asarray(first),
+                                              np.stack(want(per, 0)))
+                fires = _pv("plan_native_fires")
+                for host in (True, False):
+                    mod._host_join = host
+                    kept = call(xa)  # a native fire
+                    snap = np.array(kept)
+                    other = call(xb)  # the same plan's next fire
+                    made += 2
+                    np.testing.assert_array_equal(np.asarray(other),
+                                                  np.stack(want(per, 5)))
+                    np.testing.assert_array_equal(np.asarray(kept), snap)
+                    np.testing.assert_array_equal(snap,
+                                                  np.asarray(first))
+                mod._host_join = True
+                assert _pv("plan_native_fires") - fires == 4
+                assert _pv("plan_native_fallbacks") == vetoes
+                clean = nx.NativeXchg._clean_channel
+                nx.NativeXchg._clean_channel = lambda self: False
+                try:
+                    vetoed = call(xa)
+                finally:
+                    nx.NativeXchg._clean_channel = clean
+                made += 1
+                vetoes += 1
+                np.testing.assert_array_equal(np.asarray(vetoed),
+                                              np.asarray(first))
+                assert _pv("plan_native_fallbacks") == vetoes
+            assert _pv("plan_pool_copy_bytes") == 0
+            assert _pv("hier_assembled_results") - asm0 == made * builds
             yields = _pv("plan_native_ring_yields")
-            if OP == "allgather":
+            if OP in ("allgather", "alltoall"):
                 assert yields > 0, yields
-            elif me != 0:
-                assert yields == 0, yields  # sends nothing
             frames = stalls = 0
             for ring in list(nw._live_tx):
                 st = ring.stats()
@@ -740,13 +1045,13 @@ class TestNativeJobs:
                 stalls += st["w_stalls"]
             assert yields <= stalls, (yields, stalls)
             world.barrier()
-            print(f"RINGS-OK {me} fires={fires} yields={yields} "
+            print(f"RINGS-OK {me} yields={yields} "
                   f"frames={frames} stalls={stalls}", flush=True)
             mpi.finalize()
-        """ % op, n=2, mca=[("btl_nativewire_ring_bytes", "65536"),
+        """ % op, n=n, mca=[("btl_nativewire_ring_bytes", "65536"),
                             ("wire_pipeline_segsize", "16384")])
         assert rc == 0, out
-        for me in range(2):
+        for me in range(n):
             assert f"RINGS-OK {me} " in out
 
     def test_sigkill_mid_plan_fire_is_typed_and_fast(

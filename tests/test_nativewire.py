@@ -267,6 +267,65 @@ class TestSocketInterop:
                 if isinstance(mod, nw.NativeWireBtl):
                     mod._shutdown_rings()
 
+    @pytest.mark.parametrize("seg", [1 << 14], indirect=True)
+    def test_a_parked_reader_takes_its_other_rings(self, seg):
+        """Four processes can park around a cycle — 0 writes to 3, which
+        reads from 2, which writes to 1, which reads from 0 — when two
+        of them have posted all their sends and reap while the others
+        still send past one ring of bytes (an all-pairs allgather's
+        first, interpreted call; ISSUE 31, step 0). A sender on a full
+        ring drains its inbound rings; so must a reader whose ring stays
+        empty for a slice: here process 0 is mid-transfer on the ring
+        from 1, which sends nothing for a while, and the ring from 2 is
+        full of another transfer's frames. They have to be in the
+        stash — the ring empty, its writer free to go on — before
+        process 1 sends another byte."""
+        a, b = self._pair()
+        try:
+            cards = _cards(["hostX"] * 3)
+            rx = nw.NativeWireBtl()
+            rx.bind(cards, 0)
+            tx1 = nw.NativeWireBtl()
+            tx1.bind(cards, 1)
+            tx2 = nw.NativeWireBtl()
+            tx2.bind(cards, 2)
+            tag = USER_TAG + 8
+            ring2, _lk = tx2._tx_ring(0, nw._slot_of(tag, tx2._cap(0)[1]))
+            rec, queued = bytes(256 * 1024), 0
+            while ring2.writev(tag + 1, [rec], 5) == 0:
+                queued += 1  # until the ring from 2 is full
+            assert queued and ring2.stats()["w_stalls"] == 1
+            x = np.arange(100_000, dtype=np.float32)  # 25 fragments
+            frames = tx1.frame_stream(b, 0, tag, x)
+            got = []
+            th = threading.Thread(
+                target=lambda: got.append(
+                    rx.recv_staged(a, tag, timeout_ms=30_000)),
+                daemon=True)
+            th.start()
+            for _ in range(3):  # the header and two fragments
+                next(frames)
+            deadline = time.monotonic() + 5
+            while (ring2.stats()["r_frames"] < queued
+                   and time.monotonic() < deadline):
+                time.sleep(0.05)
+            drained = ring2.stats()["r_frames"]
+            room = ring2.writev(tag + 1, [rec], 5)
+            for _ in frames:  # the reader ends before anything closes
+                pass
+            th.join(timeout=30)
+            # every frame of the other transfer left the ring while the
+            # reader's own ring stayed empty: its writer could go on
+            assert drained == queued and room == 0
+            np.testing.assert_array_equal(np.asarray(got[0]), x)
+        finally:
+            a.close()
+            b.close()
+            for mod in (locals().get("rx"), locals().get("tx1"),
+                        locals().get("tx2")):
+                if isinstance(mod, nw.NativeWireBtl):
+                    mod._shutdown_rings()
+
     def test_shutdown_waits_for_unattached_consumer(self):
         """A completed send whose receiver hasn't attached yet must
         survive producer exit — the socket path parks such bytes in

@@ -337,12 +337,21 @@ def test_span_sites_exist_and_only_the_helper_annotates():
               "NBC_WAIT": "coll/nbc.py",
               "PLAN_NATIVE_FIRE": "coll/native_exec.py",
               "PLAN_XCHG": "coll/plan.py", "HIER_D2H": "coll/hier.py",
-              "HIER_H2D": "coll/hier.py", "WIRE_STASH": "btl/nativewire.py",
+              "HIER_H2D": "coll/hier.py", "HIER_ASSEMBLE": "coll/hier.py",
+              "WIRE_STASH": "btl/nativewire.py",
               "PML_SEND": "p2p/pml.py", "PML_D2H": "p2p/pml.py",
               "PML_RECV_WAIT": "p2p/pml.py",
               "WIRE_P2P_SEND": "runtime/wire.py",
               "WIRE_P2P_PUMP": "runtime/wire.py",
               "PML_H2D": "runtime/wire.py"}
+    import importlib.util  # obs/spans.py by path: the package pulls jax
+    spec = importlib.util.spec_from_file_location(
+        "_spans_only", os.path.join(REPO, SPANS_MODULE))
+    spans = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(spans)
+    # sixteen names since ISSUE 31, each with a site of its own
+    assert len(spans.NAMES) == 16
+    assert {getattr(spans, const) for const in wanted} == set(spans.NAMES)
     for const, rel in wanted.items():
         path = os.path.join(pkg, rel)
         tree = ast.parse(open(path).read())

@@ -381,8 +381,10 @@ def _calls_with_children(events):
 def check_spanning_calls(doc, events, want_exchange):
     """Every call of the traced rounds: one ``ompi.nbc.wait`` inside it,
     exchanges of the wanted kind inside the wait with its ``(cid,
-    seq)``, a fetch before the first exchange and a placement after the
-    last, nothing nested that must not nest."""
+    seq)``, a fetch before the first exchange and after the last either
+    a placement (allreduce) or the one-pass assembly of the result
+    (bcast, allgather: its placement inside it), nothing nested that
+    must not nest."""
     pairs = _calls_with_children(events)
     assert [c["stats"]["op"] for c, _ in pairs] == \
         list(OPS) * (len(pairs) // 3)
@@ -398,8 +400,20 @@ def check_spanning_calls(doc, events, want_exchange):
                 wait["stats"]["cid"], wait["stats"]["seq"])
             assert not any(inside(o, e) for o in xs if o is not e)
         d2h, h2d = named(kids, spans.HIER_D2H), named(kids, spans.HIER_H2D)
+        built = named(kids, spans.HIER_ASSEMBLE)
         assert d2h and d2h[0]["t1"] <= xs[0]["t0"]
-        assert h2d and xs[-1]["t1"] <= h2d[-1]["t0"]
+        if call["stats"]["op"] == "allreduce":
+            assert h2d and xs[-1]["t1"] <= h2d[-1]["t0"] and not built
+        else:
+            (done,) = built
+            assert xs[-1]["t1"] <= done["t0"] and inside(done, wait)
+            # the whole result: every local member's copy of it
+            assert done["stats"]["bytes"] == 2 * 512 * 4 * (
+                4 if call["stats"]["op"] == "allgather" else 1)
+            # a host rank hands jax the buffer it has just filled
+            (placed,) = h2d
+            assert inside(placed, done)
+            assert placed["stats"]["bytes"] == done["stats"]["bytes"]
         assert all(e["stats"]["bytes"] > 0 for e in d2h + h2d)
         moves = d2h + h2d
         assert not any(inside(a, b) for a in moves for b in moves
