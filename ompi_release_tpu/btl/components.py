@@ -58,9 +58,6 @@ _xfer_ids = itertools.count(1)
 #: writev / shm-ring memcpy / dlpack handoff); ``sliced`` counts bytes
 #: that moved as memoryview slices or preallocated-buffer views — one
 #: staging copy at the OOB boundary, no whole-array ``tobytes()``.
-#: The historical name ``wire_bytes_zero_copy`` (which used to count
-#: the sliced discipline) stays as a summing alias, the same way
-#: ``hier_inter_msgs`` aliases its sent+recvd split.
 _zero_copy_strict = _pvar.counter(
     "wire_bytes_zero_copy_strict",
     "payload bytes moved with no Python-side copy at all: vectored "
@@ -72,12 +69,6 @@ _sliced_bytes = _pvar.counter(
     "payload bytes shipped as memoryview slices over the source "
     "buffer or landed in preallocated-buffer views instead of "
     "whole-array copies (one staging copy at the OOB boundary)",
-)
-_zero_copy_bytes = _pvar.PVARS.register(
-    "wire_bytes_zero_copy", _pvar.PvarClass.COUNTER,
-    "zero-copy-discipline wire bytes "
-    "(alias: wire_bytes_zero_copy_strict + wire_bytes_sliced)",
-    getter=lambda: _zero_copy_strict.read() + _sliced_bytes.read(),
 )
 _frags_inflight = _pvar.highwatermark(
     "wire_frags_inflight",

@@ -41,10 +41,10 @@ _plan_cache = pvar.aggregate(
 #: Python time on the collective DISPATCH path — everything between a
 #: collective's dispatch entry and the moment the compiled program (or
 #: the wire transport) takes over: decision logic, plan/cache lookups,
-#: validation, schedule posting. THE witness for the interpreted-vs-
-#: compiled steady-state claim (bench.py ``steady_state``): the delta
-#: of this timer across a run isolates orchestration from device/wire
-#: time. Two clock reads per dispatch — measurement, not policy.
+#: validation, schedule posting. The delta of this timer across a run
+#: isolates orchestration from device/wire time (the benchmark's
+#: ``dispatch_us.*``). Two clock reads per dispatch — measurement, not
+#: policy.
 _orch = pvar.timer(
     "coll_orchestration_seconds",
     "Python orchestration seconds on the collective dispatch path "

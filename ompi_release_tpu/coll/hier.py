@@ -91,15 +91,6 @@ _inter_msgs_recvd = pvar.counter(
     "hier_inter_msgs_recvd",
     "inter-process messages RECEIVED by hier collectives",
 )
-# MPI_T-compat alias: the old ambiguous counter bumped on both sides
-# (one logical message counted twice per process); it lives on as a
-# read-only sum so existing tooling keeps a continuous series while
-# the split pvars make the O(P^2) -> O(log P) claim auditable.
-_inter_msgs = pvar.PVARS.register(
-    "hier_inter_msgs", pvar.PvarClass.COUNTER,
-    "inter-process messages in hier collectives (alias: sent + recvd)",
-    getter=lambda: _inter_msgs_sent.read() + _inter_msgs_recvd.read(),
-)
 _leader_combines = pvar.counter(
     "hier_leader_combines",
     "host-leader-tier combines performed by spanning collectives",

@@ -341,7 +341,7 @@ def dispatch(comm, name: str, fn: Callable, args: Tuple,
     if not _enabled():
         # fully interpreted (coll_compiled=0): still re-base the
         # orchestration timer at THIS entry so the interpreted and
-        # compiled legs of the steady_state bench time the same span
+        # compiled legs time the same span
         d = _lazy_driver()
         d.orch_mark(t0)
         try:

@@ -12,9 +12,11 @@ The reference stack maps as (SURVEY §5 checkpoint/resume):
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import shutil
+import threading
 import time
 from typing import Any, Dict, List, Optional
 
@@ -27,6 +29,25 @@ from ..utils.errors import ErrorCode, MPIError
 
 _log = output.stream("ft")
 _ckpt_count = pvar.counter("ft_checkpoints_taken", "checkpoints committed")
+
+#: snapshots of this process between their quiesce barrier and their
+#: commit. A rank that is evacuated inside that window has passed a
+#: barrier its peers count and holds no checkpoint for it: its next
+#: incarnation resumes one step back, enters that barrier a second time
+#: and waits for ever at its last save. The launcher's kill is obeyed by
+#: a thread of this process, so it can wait the window out.
+_open_windows = 0
+_windows = threading.Condition()
+
+
+@contextlib.contextmanager
+def between_snapshots(timeout_s: float):
+    """Enter once no snapshot of this process is between its barrier
+    and its commit, or after ``timeout_s``; none can start inside the
+    ``with`` body (``coordinator``'s die watcher exits there)."""
+    with _windows:
+        _windows.wait_for(lambda: _open_windows == 0, timeout_s)
+        yield
 
 
 class Checkpointer:
@@ -58,7 +79,18 @@ class Checkpointer:
         self.keep = keep
         self.comm = comm
         self._pending: List = []
+        self._in_window = False
         os.makedirs(directory, exist_ok=True)
+
+    def _window(self, opened: bool) -> None:
+        """This checkpointer's one snapshot enters or leaves the window
+        ``between_snapshots`` waits out; leaving twice counts once."""
+        global _open_windows
+        if opened != self._in_window:
+            self._in_window = opened
+            with _windows:
+                _open_windows += 1 if opened else -1
+                _windows.notify_all()
 
     # -- quiescence (crcp/bkmrk analogue) ----------------------------------
     def quiesce(self) -> None:
@@ -85,9 +117,18 @@ class Checkpointer:
     def save(self, step: int, state: Any, *, async_: bool = True,
              extra_meta: Optional[Dict] = None) -> None:
         """Snapshot ``state`` (pytree) for ``step``."""
+        self.wait()  # one checkpoint in flight at a time
+        self._window(True)
+        try:
+            self._snapshot(step, state, async_, extra_meta)
+        except BaseException:
+            self._window(False)
+            raise
+
+    def _snapshot(self, step: int, state: Any, async_: bool,
+                  extra_meta: Optional[Dict]) -> None:
         from ..utils import memchecker
 
-        self.wait()  # one checkpoint in flight at a time
         self.quiesce()
         # a snapshot must not contain donated/consumed buffers — the
         # memchecker liveness walk catches use-after-donation HERE,
@@ -105,11 +146,14 @@ class Checkpointer:
         futs = sharded.save_pytree(tmp, state, async_=True) or []
 
         def commit() -> None:
-            if os.path.exists(d):
-                shutil.rmtree(d)
-            os.rename(tmp, d)
-            with open(os.path.join(d, "COMMITTED"), "w") as f:
-                f.write(str(step))
+            try:
+                if os.path.exists(d):
+                    shutil.rmtree(d)
+                os.rename(tmp, d)
+                with open(os.path.join(d, "COMMITTED"), "w") as f:
+                    f.write(str(step))
+            finally:
+                self._window(False)
             _ckpt_count.add()
             _log.verbose(1, f"checkpoint step {step} committed -> {d}")
             self._gc()
@@ -124,8 +168,12 @@ class Checkpointer:
     def wait(self) -> None:
         """Block until the in-flight async checkpoint has committed."""
         for futs, commit in self._pending:
-            for fu in futs:
-                fu.result()
+            try:
+                for fu in futs:
+                    fu.result()
+            except BaseException:
+                self._window(False)  # a failed write commits nothing
+                raise
             commit()
         self._pending = []
 
@@ -144,6 +192,7 @@ class Checkpointer:
                 except Exception:
                     pass
         self._pending = []
+        self._window(False)
         for name in os.listdir(self.directory):
             if name.endswith(".tmp"):
                 shutil.rmtree(os.path.join(self.directory, name),

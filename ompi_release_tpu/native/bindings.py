@@ -2,7 +2,9 @@
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
 import os
 import struct
 import subprocess
@@ -68,6 +70,48 @@ def _stamp_current() -> bool:
     return True
 
 
+@contextlib.contextmanager
+def _one_builder():
+    """One process of a checkout builds at a time. The ranks of a job
+    and the workers of a test run start together on a fresh checkout;
+    each ran ``make`` into the same ``build/`` and one loaded the .so
+    another compiler was still writing — a library without its newer
+    symbols, and every capability behind them silently withdrawn."""
+    os.makedirs(os.path.dirname(_SO_PATH), exist_ok=True)
+    with open(os.path.join(os.path.dirname(_SO_PATH), ".lock"), "w") as f:
+        fcntl.flock(f, fcntl.LOCK_EX)  # released when the file closes
+        yield
+
+
+def _build() -> None:
+    with _one_builder():
+        if os.path.exists(_SO_PATH) and _stamp_current():
+            return  # another process built it while this one waited
+        _log.verbose(1, "building native control-plane library")
+        import shutil
+
+        missing = [t for t in ("make", os.environ.get("CXX", "g++"))
+                   if shutil.which(t) is None]
+        if missing:
+            raise MPIError(
+                ErrorCode.ERR_OTHER,
+                f"native library cannot be built: "
+                f"{' and '.join(missing)} not found on PATH. "
+                f"{_SO_PATH} is compiled from native/*.cc on first "
+                "use, and the OOB control plane and the native "
+                "wire datapath of every tpurun job need it",
+            )
+        r = subprocess.run(
+            ["make", "-s", "all"], cwd=_NATIVE_DIR,
+            capture_output=True, text=True,
+        )
+        if r.returncode != 0:
+            raise MPIError(
+                ErrorCode.ERR_OTHER,
+                f"native build failed:\n{r.stdout}\n{r.stderr}",
+            )
+
+
 def load_library() -> ctypes.CDLL:
     """Load (building if needed) the native library.
 
@@ -81,29 +125,7 @@ def load_library() -> ctypes.CDLL:
         if _lib is not None:
             return _lib
         if not os.path.exists(_SO_PATH) or not _stamp_current():
-            _log.verbose(1, "building native control-plane library")
-            import shutil
-
-            missing = [t for t in ("make", os.environ.get("CXX", "g++"))
-                       if shutil.which(t) is None]
-            if missing:
-                raise MPIError(
-                    ErrorCode.ERR_OTHER,
-                    f"native library cannot be built: "
-                    f"{' and '.join(missing)} not found on PATH. "
-                    f"{_SO_PATH} is compiled from native/*.cc on first "
-                    "use, and the OOB control plane and the native "
-                    "wire datapath of every tpurun job need it",
-                )
-            r = subprocess.run(
-                ["make", "-s", "all"], cwd=_NATIVE_DIR,
-                capture_output=True, text=True,
-            )
-            if r.returncode != 0:
-                raise MPIError(
-                    ErrorCode.ERR_OTHER,
-                    f"native build failed:\n{r.stdout}\n{r.stderr}",
-                )
+            _build()
         lib = ctypes.CDLL(_SO_PATH)
         _declare(lib)
         _lib = lib

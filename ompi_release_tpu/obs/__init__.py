@@ -155,7 +155,7 @@ def enable(size: int = None) -> None:
     # flight-recorder signal handlers when obs was already on): the
     # documented `kill -USR1` dump must work for mid-run enables too.
     # Only when a runtime is live — a bare tracing-unit enable() in a
-    # host process (pytest, bench) must not hijack its faulthandler —
+    # host process (pytest) must not hijack its faulthandler —
     # so probe sys.modules rather than importing the runtime (a live
     # runtime implies the module is imported; a light obs import must
     # not drag it in).

@@ -2,8 +2,8 @@
 per-process source.
 
 PRs 1 and 4 built the *event* side (span journal, flow ids,
-postmortems); pvars were still read only at snapshot points (bench
-labels, ``tpu_top --metrics`` polling one server page). This module is
+postmortems); pvars were still read only at snapshot points
+(``tpu_top --metrics`` polling one server page). This module is
 the *continuous* side: a gated background thread takes periodic
 **delta** snapshots of every registered pvar (COUNTER/TIMER deltas,
 AGGREGATE/HISTOGRAM element-wise deltas — the MPI_T session-delta

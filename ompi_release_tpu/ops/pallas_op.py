@@ -12,13 +12,14 @@ lose"): XLA is free to algebraically fold repeated affine updates
 across loop iterations (acc*c+a twice = acc*c^2 + (ac+a)), which
 silently turns a bandwidth loop into a flops one. A ``pallas_call`` is
 opaque to XLA, so a timing loop over it moves real HBM traffic every
-iteration. bench.py uses these kernels for exactly that reason; the op
-framework exposes them for large contiguous f32/bf16 reductions.
+iteration. The op framework exposes these kernels for large contiguous
+f32/bf16 reductions.
 
 Block shapes: the axpy (read acc, read a, write acc -> 3 streams) uses
 (256, 2048) f32 blocks, the 2-stream copy/scale kernel short, wide
 ones. They were picked on a v5e under an earlier jax/libtpu; their
-speed under the installed one is not measured (ROADMAP S1). What is
+speed under the installed one is not measured (no cell of the
+benchmark runs them). What is
 established is that they compile: three double-buffered (256, 2048)
 f32 streams are 12 MiB of VMEM, inside v5e's 16 MiB default scoped
 limit.
@@ -26,7 +27,6 @@ limit.
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Tuple
 
 import jax
@@ -37,15 +37,6 @@ from ..mca import component as mca_component
 #: measured-optimal f32 block shapes (rows, cols)
 AXPY_BLOCK: Tuple[int, int] = (256, 2048)
 SCALE_BLOCK: Tuple[int, int] = (128, 2048)
-#: second copy-ceiling candidate (also ~820-840 GB/s measured); the
-#: bench measures both and takes the per-round max as the ceiling
-SCALE_BLOCK_ALT: Tuple[int, int] = (32, 8192)
-#: third candidate: a 2026-07 re-sweep measured the shortest/widest
-#: block winning the copy kernel under that session's conditions
-#: (679 vs 657/653 GB/s for the other two) — candidates exist so the
-#: ceiling is the best the chip demonstrably does TODAY, whichever
-#: shape that takes
-SCALE_BLOCK_ALT2: Tuple[int, int] = (16, 16384)
 
 
 def _interpret() -> bool:
@@ -59,8 +50,7 @@ def _blocked_call(kernel, nin: int, rows: int, cols: int, blk_rows: int,
     from jax.experimental.pallas import tpu as pltpu
 
     if rows % blk_rows:
-        # a truncated grid would silently skip the tail — fatal in a
-        # bandwidth benchmark (unprocessed rows inflate the number)
+        # a truncated grid would silently skip the tail
         raise ValueError(
             f"rows ({rows}) must be a multiple of the block height "
             f"({blk_rows})"
@@ -203,140 +193,3 @@ class PallasOpComponent(mca_component.Component):
                                     4 * 1024 * 1024)):
             return None
         return make_pallas_sum()
-
-
-def make_axpy_loop(rows: int, cols: int, c: float = 0.999,
-                   blk_rows: int = None, dtype=jnp.float32):
-    """K-iteration benchmark loop over the axpy kernel (bench.py's
-    measurement body: per-iteration traffic = 3 x rows x cols x
-    itemsize). ``blk_rows`` overrides the tuned block height for
-    small-message sweep points whose whole array is below one block."""
-    if blk_rows is None:
-        blk_rows = min(AXPY_BLOCK[0], rows)
-
-    def kernel(a_ref, acc_ref, out_ref):
-        out_ref[:] = acc_ref[:] * c + a_ref[:]
-
-    call = _blocked_call(kernel, 2, rows, cols, blk_rows, dtype)
-
-    @partial(jax.jit, static_argnums=1)
-    def loop(a, k):
-        def body(i, acc):
-            return call(a, acc)
-
-        acc = jax.lax.fori_loop(
-            0, k, body, jnp.zeros((rows, cols), dtype)
-        )
-        return acc[0, 0] + acc[-1, -1]  # 8-byte completion checksum
-
-    return loop
-
-
-def make_scale_loop(rows: int, cols: int, c: float = 1.0001,
-                    blk_rows: int = None, dtype=jnp.float32):
-    """K-iteration loop over the 2-stream scale kernel (the measured
-    HBM copy ceiling: read + write per iteration)."""
-    if blk_rows is None:
-        blk_rows = min(SCALE_BLOCK[0], rows)
-
-    def kernel(x_ref, out_ref):
-        out_ref[:] = x_ref[:] * c
-
-    call = _blocked_call(kernel, 1, rows, cols, blk_rows, dtype)
-
-    @partial(jax.jit, static_argnums=1)
-    def loop(a, k):
-        def body(i, acc):
-            return call(acc)
-
-        acc = jax.lax.fori_loop(0, k, body, a)
-        return acc[0, 0] + acc[-1, -1]
-
-    return loop
-
-
-def make_transpose_loop(n: int, block: int = 256, dtype=jnp.int32):
-    """K-iteration loop over a blocked (n, n) transpose — the
-    single-chip analogue of the 2-D-torus MPI_Alltoall shuffle
-    (BASELINE config 5): every (i, j) block moves to (j, i), all-pairs
-    data movement through HBM.
-
-    The loop body applies the transpose TWICE, 4 streams (2 reads + 2
-    writes of the full array) per iteration, and callers must count
-    ``4 * n * n * itemsize`` bytes.  Why: a ``fori_loop`` carry lives
-    in a FIXED buffer across iterations (XLA while-loop buffer
-    assignment), so a single non-aliased kernel per iteration forces
-    XLA to copy its fresh output back into the carry buffer — 2N
-    uncounted extra bytes that halved the reported bandwidth for three
-    rounds (the r03 "alltoall at 0.49 of ceiling" gap was exactly
-    this, probes 5-7: square blocks, run length, 1-D vs 2-D grids all
-    measured identical; only aliasing moved the number).  With two
-    calls per body, call #1's input buffer is dead when call #2 runs,
-    XLA reuses it for #2's output, the carry address is stable and no
-    copy is inserted — measured at copy-ceiling parity.  A same-buffer
-    blocked transpose cannot use ``input_output_aliases`` directly
-    (block (j, i) would be clobbered before grid step (j, i) reads
-    it), which is why the scale/axpy kernels alias and this one
-    double-applies instead.  XLA cannot fold T(T(x)) = x across the
-    two calls: a pallas_call is opaque."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if n % block:
-        raise ValueError(f"n ({n}) must be a multiple of block ({block})")
-
-    def kernel(x_ref, out_ref):
-        out_ref[:] = x_ref[:].T
-
-    call = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n, n), dtype),
-        grid=(n // block, n // block),
-        in_specs=[pl.BlockSpec((block, block), lambda i, j: (i, j),
-                               memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((block, block), lambda i, j: (j, i),
-                               memory_space=pltpu.VMEM),
-        interpret=_interpret(),
-    )
-
-    @partial(jax.jit, static_argnums=1)
-    def loop(a, k):
-        def body(i, acc):
-            return call(call(acc))
-
-        acc = jax.lax.fori_loop(0, k, body, a)
-        return acc[0, 0] + acc[-1, -1]
-
-    return loop, call
-
-
-def make_chain_loop(hops: int = 4, dtype=jnp.float32):
-    """K-iteration loop over ``hops`` serially-dependent tiny (8, 128)
-    kernels — the single-chip analogue of examples/ring_c.c's 4-rank
-    token ring (each hop = one kernel dispatch, data-dependent on the
-    previous). Slope / hops = per-hop launch+HBM-roundtrip latency."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    spec = pl.BlockSpec((8, 128), lambda: (0, 0),
-                        memory_space=pltpu.VMEM)
-
-    def kernel(x_ref, out_ref):
-        out_ref[:] = x_ref[:] + 1
-
-    call = pl.pallas_call(
-        kernel, out_shape=jax.ShapeDtypeStruct((8, 128), dtype),
-        in_specs=[spec], out_specs=spec, interpret=_interpret(),
-    )
-
-    @partial(jax.jit, static_argnums=1)
-    def loop(a, k):
-        def body(i, acc):
-            for _ in range(hops):
-                acc = call(acc)
-            return acc
-
-        acc = jax.lax.fori_loop(0, k, body, a)
-        return acc[0, 0] + acc[-1, -1]
-
-    return loop

@@ -97,8 +97,7 @@ _templates_frozen = pvar.counter(
 _orch = pvar.timer(
     "osc_orchestration_seconds",
     "host time from epoch-close entry to device-program handoff "
-    "(both the interpreted and the planned path feed it — the bench's "
-    "steady_rma_* split reads this)",
+    "(both the interpreted and the planned path feed it)",
 )
 
 #: generation-cached cvar snapshot: (generation, enabled, max_ops) —

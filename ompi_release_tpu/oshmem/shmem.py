@@ -13,8 +13,8 @@ the delegate-to-MPI ``scoll/mpi`` component). TPU-native recast:
   BTL path, here spml → osc) and complete at ``quiet``/``barrier_all``
   — OpenSHMEM's own completion rule. Fetch AMOs and get are blocking
   (they flush), put/add are posted.
-- the **planned bulk path** (``shmem_bulk``, default on): posted
-  puts/AMOs between ``quiet()``/``fence()`` boundaries are batched
+- the **planned bulk path**: posted puts/AMOs between
+  ``quiet()``/``fence()`` boundaries are batched
   per symmetric allocation as light host-side tuples — no per-call
   ``jnp.asarray``, no per-call window queueing — and drained as ONE
   window epoch, which the osc access-plan machinery (``osc/plan``)
@@ -40,26 +40,11 @@ import jax.numpy as jnp
 from .. import obs as _obs
 from .. import ops as ops_mod
 from ..mca import pvar
-from ..mca import var as mca_var
 from ..osc.window import Window
 from ..utils import output
 from ..utils.errors import ErrorCode, MPIError
 
 _log = output.stream("shmem")
-
-
-def register_vars() -> None:
-    mca_var.register(
-        "shmem_bulk", "bool", True,
-        "Batch posted SHMEM puts/AMOs per symmetric allocation "
-        "between quiet()/fence() boundaries and drain them as one "
-        "planned window epoch (one fused device program per "
-        "(allocation, signature) via osc/plan); false restores "
-        "per-call window queueing",
-    )
-
-
-register_vars()
 
 _heap_bytes = pvar.highwatermark(
     "shmem_heap_bytes", "symmetric heap bytes allocated"
@@ -72,18 +57,6 @@ _bulk_flushes = pvar.counter(
     "shmem_bulk_flushes",
     "bulk-queue drains (one planned window epoch per allocation)",
 )
-
-#: generation-cached shmem_bulk snapshot — posted-op hot path reads
-#: one attribute + int compare, never the registry
-_conf: Tuple[int, bool] = (-1, True)
-
-
-def _bulk_on() -> bool:
-    global _conf
-    gen = mca_var.VARS.generation
-    if _conf[0] != gen:
-        _conf = (gen, bool(mca_var.get("shmem_bulk", True)))
-    return _conf[1]
 
 
 class SymmetricArray:
@@ -161,7 +134,7 @@ class ShmemCtx:
         )
         return arr
 
-    # -- the planned bulk path (shmem_bulk) --------------------------------
+    # -- the planned bulk path ---------------------------------------------
     def _post(self, sym: SymmetricArray, kind: str, pe: int, data,
               op, index) -> None:
         """Defer one posted op into ``sym``'s bulk queue (nbi
@@ -200,10 +173,7 @@ class ShmemCtx:
     # -- data movement (spml put/get) --------------------------------------
     def put(self, sym: SymmetricArray, data, pe: int) -> None:
         """shmem_put: posted; completes at quiet/barrier_all."""
-        if _bulk_on():
-            self._post(sym, "put", pe, data, None, None)
-            return
-        sym._win.put(jnp.asarray(data), pe)
+        self._post(sym, "put", pe, data, None, None)
 
     def get(self, sym: SymmetricArray, pe: int) -> jax.Array:
         """shmem_get: blocking (flushes pending ops first)."""
@@ -217,17 +187,11 @@ class ShmemCtx:
         """Scalar put at a flat index (shmem_p): a true single-element
         posted put — O(1) staged bytes, no read-modify-write of the
         whole slot."""
-        if _bulk_on():
-            self._post(sym, "put", pe, value, None, int(index))
-            return
-        sym._win.put(jnp.asarray(value), pe, index=int(index))
+        self._post(sym, "put", pe, value, None, int(index))
 
     # -- atomics (oshmem/mca/atomic) ---------------------------------------
     def atomic_add(self, sym: SymmetricArray, value, pe: int) -> None:
-        if _bulk_on():
-            self._post(sym, "acc", pe, value, ops_mod.SUM, None)
-            return
-        sym._win.accumulate(jnp.asarray(value), pe, op=ops_mod.SUM)
+        self._post(sym, "acc", pe, value, ops_mod.SUM, None)
 
     def atomic_fetch_add(self, sym: SymmetricArray, value, pe: int
                          ) -> jax.Array:
@@ -262,10 +226,7 @@ class ShmemCtx:
 
     def atomic_set(self, sym: SymmetricArray, value, pe: int) -> None:
         """shmem_atomic_set: unconditional replace (no fetch)."""
-        if _bulk_on():
-            self._post(sym, "acc", pe, value, ops_mod.REPLACE, None)
-            return
-        sym._win.accumulate(jnp.asarray(value), pe, op=ops_mod.REPLACE)
+        self._post(sym, "acc", pe, value, ops_mod.REPLACE, None)
 
     def atomic_fetch(self, sym: SymmetricArray, pe: int) -> jax.Array:
         """shmem_atomic_fetch: an atomic read = fetch_add(0)."""

@@ -28,8 +28,8 @@ Two execution modes:
     bucket size) and cached — the persistent-collective shape: plan
     once, fire per step. With the ``progress_thread`` cvar on, the
     engine runs the bucket schedules off the caller (true overlap,
-    measured by ``nbc_hidden_seconds`` and the bench ``overlap``
-    suite); in polling mode the buckets drain at ``wait()``.
+    measured by ``nbc_hidden_seconds``); in polling mode the buckets
+    drain at ``wait()``.
 """
 
 from __future__ import annotations
@@ -94,10 +94,6 @@ class GradientSync(_tree_mod.TreeSync):
         # precedence (tree_buckets rules > tree_bucket_bytes >
         # dp_bucket_bytes), so runtime cvar tuning still applies
         super().__init__(comm, mean=mean, bucket_bytes=bucket_bytes)
-
-
-#: back-compat alias: the pending handle is the shared tree-pass one
-PendingGradSync = _tree_mod.PendingTreePass
 
 
 def replicate_check(x: jax.Array, axis_name: str) -> jax.Array:

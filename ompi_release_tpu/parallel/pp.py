@@ -140,8 +140,7 @@ class HostPipeline:
     ``pp_boundary_wait_seconds`` pvar; with the ``progress_thread``
     cvar on, spanning transfers complete off the caller entirely).
     ``nonblocking=False`` is the blocking reference leg: every
-    boundary send+recv runs exposed between two computes — the shape
-    the bench's ``tree_pp`` lines compare against.
+    boundary send+recv runs exposed between two computes.
 
     The schedule is the same M+S-1-tick GPipe wavefront as
     :func:`pipeline`; results are bitwise-identical between the two

@@ -861,6 +861,11 @@ class HnpCoordinator:
             self.ep.close()
 
 
+#: how long an obeyed TAG_DIE lets an open checkpoint commit; under the
+#: 2-3 s after which the launcher signals the process anyway
+_DIE_COMMIT_WAIT_S = 1.5
+
+
 class WorkerAgent:
     """Per-process agent (the orted-equivalent participant)."""
 
@@ -1209,9 +1214,12 @@ class WorkerAgent:
         ssh client merely orphans the remote process — the reference
         kills through the remote orted, and this control-plane kill
         is that path here. Runs whenever heartbeats run (both are the
-        process-management channel)."""
+        process-management channel). A checkpoint that has passed its
+        barrier is let to commit first (``ft/checkpoint``): the next
+        incarnation then resumes at the step its peers count."""
 
         def run() -> None:
+            from ..ft import checkpoint as _ckpt
             from ..utils.errors import ErrorCode as _EC
 
             while not self._hb_stop.is_set():
@@ -1224,7 +1232,8 @@ class WorkerAgent:
                     return        # endpoint closed/torn down
                 except Exception:
                     return
-                os._exit(int(raw or b"143"))
+                with _ckpt.between_snapshots(_DIE_COMMIT_WAIT_S):
+                    os._exit(int(raw or b"143"))
 
         threading.Thread(target=run, daemon=True,
                          name="die-watcher").start()

@@ -24,10 +24,10 @@ module is that engine for the TPU runtime:
 - an opt-in DEDICATED PROGRESS THREAD (``progress_thread`` cvar,
   default off): when enabled it claims queued schedules and runs them
   off the caller, turning i-collectives into true compute/comm overlap
-  (measured by the ``nbc_hidden_seconds`` pvar and the bench
-  ``overlap`` suite). The default is the polling fallback — operations
-  execute at ``wait()`` in posting order on the caller's thread, so
-  tier-1 CPU tests stay deterministic and single-threaded.
+  (measured by the ``nbc_hidden_seconds`` pvar). The default is the
+  polling fallback — operations execute at ``wait()`` in posting order
+  on the caller's thread, so tier-1 CPU tests stay deterministic and
+  single-threaded.
 
 Execution model: an op is *claimed* (QUEUED -> RUNNING, exactly once)
 only when it is the head of its communicator's FIFO — two collectives

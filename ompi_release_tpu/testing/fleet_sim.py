@@ -60,8 +60,7 @@ Rabenseifner inter-process send bytes/rank = 2n(P-1)/P — every
 simulated rank is one process, so ``bytes_sent`` is exactly the
 ``hier_inter_bytes`` quantity of the real spanning collectives,
 while ``inter_bytes_sent`` separately counts the host-crossing
-subset) and ``bench.py``'s ``fleet_scaling`` suite emits them as
-gate-guarded ``sim_*`` metric lines.
+subset).
 
 **Forensics.** Per-rank span journals (sentinel signatures, ft
 events, coll rounds) dump as ``journal-p*.json`` files in the exact
@@ -275,8 +274,7 @@ class _RankState:
 
 
 class RunReport:
-    """Per-run metrology deltas — what the scaling assertions and the
-    ``fleet_scaling`` bench lines read."""
+    """Per-run metrology deltas — what the scaling assertions read."""
 
     def __init__(self, participants: List[int], outcomes: Dict,
                  start: Dict, end: Dict) -> None:
@@ -774,7 +772,7 @@ class FleetSim:
 
 
 # ---------------------------------------------------------------------------
-# scaling-law helpers (shared by tests and the bench suite)
+# scaling-law helpers
 # ---------------------------------------------------------------------------
 
 

@@ -146,8 +146,8 @@ def cascading_failure(P: int = 64, *, seed: int = 0,
 
 
 class MultiTenantResult:
-    """Everything the fairness/isolation tests and the bench
-    ``multi_tenant`` suite need from one scenario run."""
+    """Everything the fairness/isolation tests need from one scenario
+    run."""
 
     __slots__ = ("P", "seed", "classes", "share_lat", "fifo_share",
                  "lat_ranks", "bulk_ranks", "lat_cid", "bulk_cid",

@@ -1117,9 +1117,8 @@ def run_loopback_app(nprocs: int, app_src: str, env: dict,
                      job_kw: Optional[Dict] = None):
     """Spawn ``app_src`` as an ``nprocs``-process loopback Job with
     ``env`` exported for the workers, and return the JSON document the
-    app wrote to ``out_path`` (or None on failure). The shared harness
-    behind the bench micro-suites and the tpu-tune sweeps — the
-    tempdir/env-snapshot/Job/read-results dance lives exactly once.
+    app wrote to ``out_path`` (or None on failure). The harness behind
+    the tpu-tune sweeps.
 
     Note: mutates ``os.environ`` for the spawn window (workers inherit
     the parent environment) and restores it in a finally — callers

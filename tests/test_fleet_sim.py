@@ -453,44 +453,45 @@ class TestDeterminism:
 
 
 # ---------------------------------------------------------------------------
-# bench wiring: the fleet_scaling suite and its gate contract
+# the scaling observables at one small P, against their closed forms
 # ---------------------------------------------------------------------------
 
 
-class TestBenchWiring:
-    def test_fleet_suite_lines_are_sim_tier_and_gateable(self):
-        import bench
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        lines = bench._fleet_micro_suite(sizes=(64,))
-        assert lines
-        for ln in lines:
-            # sim_* = closed-form observables (lower-better), topo_* =
-            # topology-aware speedup ratios over the flat ring
-            # (higher-better)
-            assert ln["metric"].startswith(("sim_", "topo_"))
-            # satellite: distinct tier label so the gate NEVER fits
-            # sim numbers against loopback-cpu/tpu history
-            assert ln["tier_label"] == "sim"
-            assert gate.line_tier(ln) == "sim"
-            assert gate.gateable(ln)
-            want = 1 if ln["metric"].startswith("topo_") else -1
-            assert gate._direction(ln.get("unit"), ln["metric"]) == want
-        metrics = {ln["metric"] for ln in lines}
-        assert "sim_bcast_root_sends_p64" in metrics
-        assert "sim_rab_bytes_per_rank_p64" in metrics
-        # the emitted observables match the closed-form laws
-        by = {ln["metric"]: ln for ln in lines}
-        assert by["sim_bcast_root_sends_p64"]["value"] == 6
-        assert by["sim_rd_rounds_p64"]["value"] == 6
-        assert by["sim_rab_bytes_per_rank_p64"]["value"] \
+class TestScalingObservables:
+    def test_p64_counts_match_closed_forms(self):
+        """One fleet of 64 (8 per host) runs the three schedules the
+        O(log P) claims rest on; the counts are the simulator's own
+        (tier: sim), never a time."""
+        P = 64
+        fleet = fs.FleetSim(P, hosts_per=8, seed=1)
+        procs = fleet.procs
+        val = np.arange(16, dtype=np.int32)
+        rep = fleet.run(
+            lambda x, p: hs.bcast_binomial(
+                x, procs, p, 0, val if p == 0 else None),
+            label="bcast")
+        assert rep.msgs_sent[0] == 6 == fs.log2_rounds(P)
+        assert rep.makespan > 0
+        data = {p: np.full(8, p + 1, np.int64) for p in procs}
+        rep = fleet.run(
+            lambda x, p: hs.allgather_bruck(x, procs, p, data[p],
+                                            [8] * P),
+            label="allgather")
+        assert rep.max_rounds() == 6
+        n_el = 2 * P
+        fdata = {p: np.arange(n_el, dtype=np.float32) * ((p % 7) + 1)
+                 for p in procs}
+        rep = fleet.run(
+            lambda x, p: hs.allreduce_rabenseifner(
+                x, procs, p, fdata[p], np.add, 0.0),
+            label="allreduce")
+        assert rep.max_bytes_sent() \
             == fs.rabenseifner_bytes_per_rank(128, 4, 64)
+        assert rep.max_rounds() == 2 * 6
 
-    def test_suite_makespan_shrinks_vs_flat_wire(self):
+    def test_bcast_makespan_shrinks_vs_flat_wire(self):
         """The fabric model is doing real work: the same binomial
         bcast over an 8-per-host topology beats an all-DCN wire."""
-        import bench  # noqa: F401  (suite helper exercised above)
-
         def makespan(hosts_per):
             fleet = fs.FleetSim(64, hosts_per=hosts_per)
             procs = fleet.procs
@@ -566,6 +567,21 @@ class TestMultiTenant:
             r.p99(r.solo_durations) / r.share_lat * 1.10
         # the QoS win over head-of-line FIFO is large and measurable
         assert r.fifo_makespan > 2.0 * r.qos_makespan
+
+    def test_isolation_ratios_at_p64(self):
+        """Solo, contended-under-QoS and contended-FIFO p99 of the
+        latency tenant at a small P (virtual clocks, tier: sim): the
+        isolation ratio stays inside the inverse fair share, the FIFO
+        wire costs more than twice that."""
+        r = sc.multi_tenant(P=64, seed=1, kill_bulk=False)
+        solo_p99 = r.p99(r.solo_durations)
+        qos_p99 = r.p99(r.qos_durations)
+        fifo_p99 = r.p99(r.fifo_durations)
+        iso = qos_p99 / solo_p99
+        assert 1.0 <= iso <= (1.0 / r.share_lat) * 1.10
+        assert fifo_p99 / solo_p99 > 2.0 * iso
+        assert qos_p99 >= solo_p99
+        assert r.p99(r.bulk_durations) > 0
 
     def test_ft_isolation_at_p256(self, mt_result):
         """SIGKILLing a bulk-tenant rank mid-allreduce revokes ONLY

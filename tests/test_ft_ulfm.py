@@ -17,7 +17,6 @@ Layers under test:
 - ``comm/dpm.py``: FT-aware rendezvous (dead-port fast fail, stale
   epoch fence, mid-wait revocation).
 - ``tools/tpurun.py``: ``--ft-inject`` / ``--ft-continue`` plumbing.
-- ``tools/tpu_bench_gate.py``: ft metrics gate lower-better.
 - end-to-end: two REAL 3-process recovery jobs — a SIGKILLed rank
   mid-allreduce recovered by revoke+shrink (degraded world, exact
   loss) and by respawn+rebuild (full-size world, exact loss).
@@ -406,9 +405,12 @@ class TestHeartbeatMonitor:
 
         hnp, (w,) = self._pair(1)
         try:
-            t0 = time.perf_counter()
-            sum(range(10 ** 6))
-            per = max(time.perf_counter() - t0, 1e-4)
+            per = 1.0  # the fastest of five: a sample that was
+            for _ in range(5):  # descheduled would shorten the hold
+                t0 = time.perf_counter()
+                sum(range(10 ** 6))
+                per = min(per, time.perf_counter() - t0)
+            per = max(per, 1e-4)
             w.start_heartbeats(0.05)
             t0 = time.perf_counter()
             sum(range(int(10 ** 6 * 0.6 / per)))  # ~0.6 s, GIL held
@@ -759,29 +761,6 @@ class TestTpurunFtFlags:
         assert ulfm.failed_at_of({"failed_at": {"2": 5, "bad": "x",
                                                 "1": "3"}}) \
             == {2: 5, 1: 3}
-
-
-class TestBenchGateFtDirections:
-    def test_ft_metrics_gate_lower_better(self):
-        from ompi_release_tpu.tools.tpu_bench_gate import _direction
-
-        assert _direction("s", "ft_recovery_seconds") == -1
-        assert _direction("steps", "ft_steps_lost") == -1
-        assert _direction(None, "ft_steps_lost") == -1  # prefix rule
-
-    def test_gate_flags_recovery_regression(self):
-        from ompi_release_tpu.tools.tpu_bench_gate import evaluate
-
-        hist = [[{"metric": "ft_recovery_seconds", "value": v,
-                  "unit": "s", "tier_label": "loopback-cpu"}]
-                for v in (0.20, 0.22, 0.21, 0.19)]
-        bad = [{"metric": "ft_recovery_seconds", "value": 2.5,
-                "unit": "s", "tier_label": "loopback-cpu"}]
-        ok = [{"metric": "ft_recovery_seconds", "value": 0.21,
-               "unit": "s", "tier_label": "loopback-cpu"}]
-        assert any(r["metric"] == "ft_recovery_seconds"
-                   for r in evaluate(hist, bad)["regressions"])
-        assert not evaluate(hist, ok)["regressions"]
 
 
 # ---------------------------------------------------------------------------

@@ -405,9 +405,9 @@ class TestScheduleJobs:
             # (P-1)*n: at P=3 that is 4/3 n vs 2n per process
             assert bytes_by_alg["ring"] < bytes_by_alg["linear"], \\
                 bytes_by_alg
-            # the alias pvar stays the sum of the split counters
-            assert _pv("hier_inter_msgs") == \\
-                _pv("hier_inter_msgs_sent") + _pv("hier_inter_msgs_recvd")
+            # every process both sent and received across the boundary
+            assert _pv("hier_inter_msgs_sent") > 0
+            assert _pv("hier_inter_msgs_recvd") > 0
             world.barrier()
             print(f"ALLREDUCE-FAM-OK {off}")
             mpi.finalize()

@@ -162,3 +162,47 @@ def test_rejected_set_value_does_not_poison_registry(fresh_mca):
     with pytest.raises((TypeError, ValueError)):
         mca_var.set_value("poison_int", [1, 2])
     assert mca_var.get("poison_int") == 5
+
+
+_CENSUS = r"""
+import importlib, json, pkgutil, sys
+sys.path.insert(0, %(repo)r)
+import ompi_release_tpu as mpi
+from ompi_release_tpu.mca import pvar, var
+for m in pkgutil.walk_packages(mpi.__path__, mpi.__name__ + "."):
+    if not m.name.endswith("__main__"):
+        importlib.import_module(m.name)
+mpi.init()  # the frameworks' components register theirs when opened
+cvars = var.VARS.describe_all()
+print("CENSUS " + json.dumps({
+    "cvars": len(cvars),
+    "on_off": sorted(d["name"] for d in cvars if d["type"] == "bool"),
+    "pvars": len(pvar.PVARS.read_all())}))
+mpi.finalize()
+"""
+
+
+def test_census_of_options_and_counters():
+    """What a user can set, counted: every module of the package
+    imported and the runtime initialised, in a process of its own (a
+    worker that ran other tests has registered theirs). A PR that adds
+    or removes a cvar, an on/off cvar or a pvar changes a literal here,
+    so the count ROADMAP asks of every PR is a line of its diff."""
+    import json
+    import subprocess
+    import sys
+
+    from conftest import subprocess_env
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = subprocess_env()
+    for k in [k for k in env if k.startswith(ENV_PREFIX)]:
+        del env[k]
+    r = subprocess.run([sys.executable, "-c", _CENSUS % {"repo": repo}],
+                       env=env, capture_output=True, text=True,
+                       timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    got = json.loads(r.stdout.split("CENSUS ", 1)[1].splitlines()[0])
+    assert got["cvars"] == 104
+    assert len(got["on_off"]) == 17, got["on_off"]
+    assert got["pvars"] == 108

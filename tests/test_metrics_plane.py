@@ -1,6 +1,6 @@
 """The continuous fleet metrics plane.
 
-Four layers under test:
+Three layers under test:
 
 - unit: sampler delta snapshots (scalar + histogram deltas, zero-delta
   suppression, per-communicator scoping from journal spans, ring
@@ -9,9 +9,6 @@ Four layers under test:
 - in-process fleet: a live HnpCoordinator TAG_SERIES responder
   aggregating three WorkerAgents' pushes, queried through tpu_top's
   FleetClient and rendered as per-rank rows;
-- gate: tpu_bench_gate's noise-bound fit catching an injected 2x
-  latency regression (and a halved bandwidth) in synthetic BENCH
-  history while passing the repo's REAL history;
 - job: a 3-process tpurun run with the sampler armed — per-rank
   series dumps at finalize, clock-corrected merge, tpu_top rows, the
   HNP-side aggregation, and the skew report's sampled-rate annotation
@@ -553,596 +550,6 @@ class TestTpuTop:
             if client is not None:
                 client.close()
             srv.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# the bench gate
-# ---------------------------------------------------------------------------
-
-def _round_file(path, lines):
-    tail = "\n".join(json.dumps(ln) for ln in lines) + "\n"
-    path.write_text(json.dumps({"n": 1, "rc": 0, "tail": tail}))
-    return str(path)
-
-
-def _bw(v):
-    return {"metric": "allreduce_256MiB", "value": v, "unit": "GB/s",
-            "vs_baseline": 1.0, "tier_label": "tpu"}
-
-
-def _lat(v):
-    return {"metric": "ring_4hop_latency", "value": v, "unit": "us/hop",
-            "vs_baseline": None, "tier_label": "tpu"}
-
-
-class TestBenchGate:
-    def _history(self, tmp_path, n=4):
-        vals = [680.0, 686.0, 678.0, 683.0]
-        lats = [0.0085, 0.0088, 0.0082, 0.0086]
-        return [_round_file(tmp_path / f"BENCH_r{k:02d}.json",
-                            [_bw(vals[k]), _lat(lats[k])])
-                for k in range(n)]
-
-    def test_catches_2x_latency_regression(self, tmp_path):
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        hist = self._history(tmp_path)
-        cand = _round_file(tmp_path / "cand.json",
-                           [_bw(681.0), _lat(0.017)])  # 2x latency
-        rc = gate.main(hist + ["--candidate", cand])
-        assert rc == 1
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(cand))
-        regs = {r["metric"] for r in verdict["regressions"]}
-        assert regs == {"ring_4hop_latency"}
-
-    def test_catches_halved_bandwidth(self, tmp_path):
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        hist = self._history(tmp_path)
-        cand = _round_file(tmp_path / "cand.json",
-                           [_bw(340.0), _lat(0.0085)])
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(cand))
-        assert [r["metric"] for r in verdict["regressions"]] \
-            == ["allreduce_256MiB"]
-
-    def test_passes_within_noise(self, tmp_path):
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        hist = self._history(tmp_path)
-        cand = _round_file(tmp_path / "cand.json",
-                           [_bw(655.0), _lat(0.0095)])  # ~4%/10% off
-        rc = gate.main(hist + ["--candidate", cand])
-        assert rc == 0
-
-    def test_skips_unclean_and_tier_mismatched_lines(self, tmp_path):
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        hist = [gate.parse_round_file(p)
-                for p in self._history(tmp_path)]
-        cand = [
-            dict(_bw(100.0), unstable=True),          # flagged: skip
-            dict(_bw(100.0), partial_rounds=2),       # salvage: skip
-            {"metric": "allreduce_256MiB", "value": None, "unit":
-             "GB/s", "vs_baseline": None},            # null: skip
-            # cpu-tier line must NOT be judged against tpu history
-            dict(_bw(3.0), tier_label="loopback-cpu"),
-        ]
-        verdict = gate.evaluate(hist, cand)
-        assert verdict["regressions"] == []
-        assert verdict["checked"] == 0
-
-    def test_min_rounds_required(self, tmp_path):
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        hist = [gate.parse_round_file(p)
-                for p in self._history(tmp_path, n=2)]
-        verdict = gate.evaluate(hist, [_bw(10.0)])
-        assert verdict["checked"] == 0 and not verdict["regressions"]
-
-    def test_zero_on_a_steady_history(self, tmp_path):
-        """With no --candidate the newest round file is the candidate:
-        a steady trajectory passes its own gate, and the same
-        trajectory ending in a halved-bandwidth round does not."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        hist = self._history(tmp_path)
-        assert gate.main(hist) == 0
-        bad = _round_file(tmp_path / "BENCH_r04.json",
-                          [_bw(340.0), _lat(0.0085)])
-        assert gate.main(hist + [bad]) == 1
-
-    def test_legacy_backend_label_maps_to_cpu_tier(self):
-        from ompi_release_tpu.tools.tpu_bench_gate import line_tier
-
-        assert line_tier({"backend": "cpu"}) == "loopback-cpu"
-        assert line_tier({}) == "tpu"
-        assert line_tier({"tier_label": "loopback-cpu"}) \
-            == "loopback-cpu"
-
-    def test_sim_metrics_are_lower_better_in_their_own_tier(self,
-                                                            tmp_path):
-        """The fleet_scaling suite's sim_* lines: the sim_ prefix is
-        registered lower-better (more schedule rounds / more bytes
-        per rank / longer simulated makespan = regression), and the
-        "sim" tier label keeps the deterministic simulator numbers
-        out of the wall-clock tiers' noise fits."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        def sim(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "sim"}
-
-        assert gate._direction("rounds", "sim_rd_rounds_p256") == -1
-        assert gate._direction("bytes",
-                               "sim_rab_bytes_per_rank_p256") == -1
-        assert gate._direction("sim_ms",
-                               "sim_allreduce_makespan_p256") == -1
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [sim("sim_rd_rounds_p256", 8, "rounds"),
-             sim("sim_rab_bytes_per_rank_p256", 4080, "bytes")])
-            for k in range(4)]
-        # a schedule regression (log-round schedule degrading toward
-        # linear: 8 -> 16 rounds) trips the gate...
-        cand = _round_file(
-            tmp_path / "cand.json",
-            [sim("sim_rd_rounds_p256", 16, "rounds"),
-             sim("sim_rab_bytes_per_rank_p256", 4080, "bytes")])
-        rc = gate.main(hist + ["--candidate", str(cand)])
-        assert rc == 1
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(cand))
-        assert [r["metric"] for r in verdict["regressions"]] \
-            == ["sim_rd_rounds_p256"]
-        assert verdict["regressions"][0]["tier"] == "sim"
-        # ...the identical deterministic replay does not...
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [sim("sim_rd_rounds_p256", 8, "rounds"),
-             sim("sim_rab_bytes_per_rank_p256", 4080, "bytes")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-        # ...and a same-named line in ANOTHER tier is never judged
-        # against the sim history
-        other = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            [{"metric": "sim_rd_rounds_p256", "value": 99,
-              "unit": "rounds", "vs_baseline": None,
-              "tier_label": "loopback-cpu"}])
-        assert other["checked"] == 0 and not other["regressions"]
-
-    def test_steady_state_metric_directions(self, tmp_path):
-        """The steady_state suite's lines: steady_* (per-op wall /
-        Python-orchestration seconds) are registered lower-better,
-        compiled_* (interpreted-vs-compiled orchestration speedups)
-        higher-better — a slower orchestration OR a shrunk speedup is
-        a regression, never an improvement."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction(
-            "s", "steady_orch_allreduce_256KiB_compiled") == -1
-        assert gate._direction(
-            None, "steady_orch_allreduce_256KiB_interpreted") == -1
-        assert gate._direction(
-            "x_orchestration",
-            "compiled_allreduce_256KiB_orch_speedup") == 1
-        assert gate._direction(
-            None, "compiled_spanning_allreduce_orch_speedup") == 1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "loopback-cpu"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("steady_orch_allreduce_256KiB_compiled",
-                6.6e-5 + k * 1e-6, "s"),
-             ln("compiled_allreduce_256KiB_orch_speedup",
-                2.4 + 0.02 * k, "x_orchestration")])
-            for k in range(4)]
-        # orchestration doubling or the speedup collapsing trips it
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("steady_orch_allreduce_256KiB_compiled", 2.0e-4, "s"),
-             ln("compiled_allreduce_256KiB_orch_speedup", 1.0,
-                "x_orchestration")])
-        from ompi_release_tpu.tools import tpu_bench_gate as gate2
-
-        verdict = gate2.evaluate(
-            [gate2.parse_round_file(p) for p in hist],
-            gate2.parse_round_file(bad))
-        regressed = {r["metric"] for r in verdict["regressions"]}
-        assert regressed == {
-            "steady_orch_allreduce_256KiB_compiled",
-            "compiled_allreduce_256KiB_orch_speedup"}
-        # ...an in-band round passes
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("steady_orch_allreduce_256KiB_compiled", 6.7e-5, "s"),
-             ln("compiled_allreduce_256KiB_orch_speedup", 2.42,
-                "x_orchestration")])
-        assert gate2.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_native_rounds_metric_directions(self, tmp_path):
-        """The native_rounds suite's lines (frozen plans lowered into
-        the C plan executor): steady_native_orch_* seconds are
-        lower-better, compiled_native_* speedups (native over the
-        interpreted PlannedXchg replay — the executor's acceptance
-        factor) higher-better, and a drift in either direction trips
-        the gate against the fitted history."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction(
-            "s", "steady_native_orch_allreduce_256KiB") == -1
-        assert gate._direction(
-            None, "steady_native_orch_bcast_4KiB") == -1
-        assert gate._direction(
-            "x_orchestration",
-            "compiled_native_allreduce_256KiB_orch_speedup") == 1
-        assert gate._direction(
-            None, "compiled_native_allgather_64KiB_orch_speedup") == 1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "loopback-cpu"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("steady_native_orch_allreduce_256KiB",
-                3.1e-5 + k * 1e-6, "s"),
-             ln("compiled_native_allreduce_256KiB_orch_speedup",
-                2.6 + 0.02 * k, "x_orchestration")])
-            for k in range(4)]
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("steady_native_orch_allreduce_256KiB", 1.5e-4, "s"),
-             ln("compiled_native_allreduce_256KiB_orch_speedup", 0.9,
-                "x_orchestration")])
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(bad))
-        regressed = {r["metric"] for r in verdict["regressions"]}
-        assert regressed == {
-            "steady_native_orch_allreduce_256KiB",
-            "compiled_native_allreduce_256KiB_orch_speedup"}
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("steady_native_orch_allreduce_256KiB", 3.2e-5, "s"),
-             ln("compiled_native_allreduce_256KiB_orch_speedup",
-                2.63, "x_orchestration")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_rma_steady_metric_directions(self, tmp_path):
-        """The rma_steady suite's lines (frozen RMA access plans,
-        osc/plan): steady_rma_* / steady_shmem_* seconds are
-        lower-better, the compiled_* orchestration and bulk-path
-        speedups higher-better — slower epochs or a collapsed speedup
-        regress, never improve."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction(
-            "s", "steady_rma_fence_4KiB_planned") == -1
-        assert gate._direction(
-            None, "steady_rma_fence_4KiB_interpreted") == -1
-        assert gate._direction(
-            "x_orchestration",
-            "compiled_rma_fence_4KiB_orch_speedup") == 1
-        assert gate._direction(
-            "s", "steady_shmem_put_4KiB_bulk") == -1
-        assert gate._direction(
-            "x_wall", "compiled_shmem_put_4KiB_bulk_speedup") == 1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "loopback-cpu"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("steady_rma_fence_4KiB_planned",
-                7.0e-5 + k * 1e-6, "s"),
-             ln("compiled_shmem_put_4KiB_bulk_speedup",
-                1.8 + 0.02 * k, "x_wall")])
-            for k in range(4)]
-        # a doubled planned close or a collapsed bulk win trips it
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("steady_rma_fence_4KiB_planned", 2.0e-4, "s"),
-             ln("compiled_shmem_put_4KiB_bulk_speedup", 0.9,
-                "x_wall")])
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(bad))
-        regressed = {r["metric"] for r in verdict["regressions"]}
-        assert regressed == {
-            "steady_rma_fence_4KiB_planned",
-            "compiled_shmem_put_4KiB_bulk_speedup"}
-        # ...an in-band round passes
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("steady_rma_fence_4KiB_planned", 7.1e-5, "s"),
-             ln("compiled_shmem_put_4KiB_bulk_speedup", 1.83,
-                "x_wall")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_flight_recorder_metric_directions(self, tmp_path):
-        """The flight-recorder lines: steady_obs_* (obs-ON compiled
-        orchestration seconds and the obs-ON/obs-OFF overhead ratio —
-        the "tracing never de-optimizes the hot path" budget) and
-        ledger_* (bytes per fire record) are all lower-better, so the
-        gate trips when enabling obs gets more expensive or the
-        fixed-size record grows."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction(
-            "s", "steady_obs_orch_spanning_allreduce_256KiB_compiled"
-        ) == -1
-        assert gate._direction(
-            "ratio", "steady_obs_overhead_spanning_allreduce_256KiB"
-        ) == -1
-        assert gate._direction(
-            "bytes", "ledger_record_bytes_spanning_allreduce_256KiB"
-        ) == -1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "loopback-cpu"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("steady_obs_overhead_spanning_allreduce_256KiB",
-                1.05 + 0.01 * k, "ratio"),
-             ln("ledger_record_bytes_spanning_allreduce_256KiB",
-                55, "bytes")]) for k in range(4)]
-        # the obs-ON leg blowing past its 1.15x budget (tracing
-        # de-optimized the hot path again) or a fattened record trips
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("steady_obs_overhead_spanning_allreduce_256KiB",
-                4.0, "ratio"),
-             ln("ledger_record_bytes_spanning_allreduce_256KiB",
-                2048, "bytes")])
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(bad))
-        regressed = {r["metric"] for r in verdict["regressions"]}
-        assert regressed == {
-            "steady_obs_overhead_spanning_allreduce_256KiB",
-            "ledger_record_bytes_spanning_allreduce_256KiB"}
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("steady_obs_overhead_spanning_allreduce_256KiB",
-                1.06, "ratio"),
-             ln("ledger_record_bytes_spanning_allreduce_256KiB",
-                55, "bytes")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_native_wire_metric_directions(self, tmp_path):
-        """The native_wire suite's lines: wire_native_p2p_* bandwidths
-        (GB/s) are higher-better, while the wire_native_copies_per_mib
-        witness (byte-path materializations per MiB shipped — 0.0 is
-        the zero-copy acceptance target) is lower-better: a collapsed
-        bandwidth OR arrays sneaking back onto the copy path must both
-        trip the gate."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction("GB/s", "wire_native_p2p_256MiB") == 1
-        assert gate._direction("GB/s", "wire_native_p2p_shm_256MiB") == 1
-        assert gate._direction(
-            "copies/MiB", "wire_native_copies_per_mib") == -1
-        # ...and the prefix rule covers a unit-less round file too
-        assert gate._direction(None, "wire_native_copies_per_mib") == -1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "loopback-cpu"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("wire_native_p2p_256MiB", 2.0 + 0.05 * k, "GB/s"),
-             ln("wire_native_copies_per_mib", 0.0, "copies/MiB")])
-            for k in range(4)]
-        # bandwidth collapsing or copies reappearing trips the gate
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("wire_native_p2p_256MiB", 0.4, "GB/s"),
-             ln("wire_native_copies_per_mib", 3.0, "copies/MiB")])
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(bad))
-        regressed = {r["metric"] for r in verdict["regressions"]}
-        assert regressed == {"wire_native_p2p_256MiB",
-                             "wire_native_copies_per_mib"}
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("wire_native_p2p_256MiB", 2.1, "GB/s"),
-             ln("wire_native_copies_per_mib", 0.0, "copies/MiB")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_native_obs_metric_directions(self, tmp_path):
-        """The native_obs suite's lines: the C counter-block series
-        (stall count / cumulative stall seconds / ring occupancy HWM)
-        are LOWER-better — growth is backpressure, not throughput —
-        and native_obs_overhead_ratio (event-ring-on p2p wall over the
-        counters-only baseline, acceptance budget 1.05) is lower-better
-        via its metric prefix: its unit is 'ratio', NOT an 'x_*' unit,
-        which would flip it higher-better in the unit table."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction(
-            "stalls", "wire_native_stall_count") == -1
-        assert gate._direction(
-            "s", "wire_native_stall_seconds") == -1
-        assert gate._direction(
-            "frac", "wire_native_ring_hwm_frac") == -1
-        assert gate._direction(
-            "ratio", "native_obs_overhead_ratio") == -1
-        assert gate._direction("s", "native_obs_counters_wall_s") == -1
-        # the x_* unit family stays higher-better (speedups): the
-        # overhead ratio must never be filed under it
-        assert gate._direction("x_vs_staged", "anything") == 1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "loopback-cpu"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("native_obs_overhead_ratio", 1.01 + 0.002 * k,
-                "ratio"),
-             ln("wire_native_stall_seconds", 0.02, "s")])
-            for k in range(4)]
-        # observability cost ballooning or stalls growing both trip
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("native_obs_overhead_ratio", 1.8, "ratio"),
-             ln("wire_native_stall_seconds", 4.0, "s")])
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(bad))
-        regressed = {r["metric"] for r in verdict["regressions"]}
-        assert regressed == {"native_obs_overhead_ratio",
-                             "wire_native_stall_seconds"}
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("native_obs_overhead_ratio", 1.012, "ratio"),
-             ln("wire_native_stall_seconds", 0.019, "s")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_topo_metric_directions(self, tmp_path):
-        """The fleet_scaling suite's topo_* lines (topology-aware
-        schedule speedups over the flat ring: inter-host byte ratio,
-        virtual-makespan ratio) are registered higher-better in the
-        sim tier — a shrunk ratio means the torus/multiring advantage
-        regressed, and it must trip the gate."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction(
-            "x_inter_bytes", "topo_torus_inter_bytes_x_p1024") == 1
-        assert gate._direction(
-            "x_makespan", "topo_torus_makespan_x_p256") == 1
-        assert gate._direction(
-            None, "topo_multiring_makespan_x_p256") == 1
-        # ...while the sim_torus_* observables stay lower-better
-        assert gate._direction(
-            "bytes", "sim_torus_inter_bytes_per_rank_p1024") == -1
-        assert gate._direction("rounds", "sim_torus_rounds_p256") == -1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "sim"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("topo_torus_inter_bytes_x_p1024", 8.0, "x_inter_bytes")])
-            for k in range(4)]
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("topo_torus_inter_bytes_x_p1024", 1.0,
-                "x_inter_bytes")])
-        assert gate.main(hist + ["--candidate", str(bad)]) == 1
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("topo_torus_inter_bytes_x_p1024", 8.0,
-                "x_inter_bytes")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_tenant_metric_directions(self, tmp_path):
-        """The multi_tenant suite's tenant_* lines (service plane):
-        latency-tenant p99s and the tenant_latency_isolation
-        degradation ratio are registered lower-better in the sim tier
-        — a GROWN isolation ratio means the weighted-fair wire lets a
-        bulk tenant degrade a latency tenant further, and it must
-        trip the gate at the sim tier's tight floor."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        assert gate._direction(
-            "p99_ratio", "tenant_latency_isolation_p256") == -1
-        assert gate._direction(
-            "sim_ms", "tenant_lat_contended_p99_p256") == -1
-        assert gate._direction(
-            None, "tenant_fifo_hol_ratio_p256") == -1
-
-        def ln(metric, v, unit):
-            return {"metric": metric, "value": v, "unit": unit,
-                    "vs_baseline": None, "tier_label": "sim"}
-
-        hist = [_round_file(
-            tmp_path / f"BENCH_r{k:02d}.json",
-            [ln("tenant_latency_isolation_p256", 1.22, "p99_ratio"),
-             ln("tenant_lat_contended_p99_p256", 0.81, "sim_ms")])
-            for k in range(4)]
-        # fairness eroding (1.22 -> 1.9, still under the FIFO blowup)
-        # IS a regression at the 2% sim floor...
-        bad = _round_file(
-            tmp_path / "cand.json",
-            [ln("tenant_latency_isolation_p256", 1.9, "p99_ratio"),
-             ln("tenant_lat_contended_p99_p256", 0.81, "sim_ms")])
-        verdict = gate.evaluate(
-            [gate.parse_round_file(p) for p in hist],
-            gate.parse_round_file(bad))
-        assert [r["metric"] for r in verdict["regressions"]] \
-            == ["tenant_latency_isolation_p256"]
-        assert verdict["regressions"][0]["tier"] == "sim"
-        # ...the deterministic replay passes
-        ok = _round_file(
-            tmp_path / "ok.json",
-            [ln("tenant_latency_isolation_p256", 1.22, "p99_ratio"),
-             ln("tenant_lat_contended_p99_p256", 0.81, "sim_ms")])
-        assert gate.main(hist + ["--candidate", str(ok)]) == 0
-
-    def test_multi_tenant_bench_lines_are_gateable(self):
-        """The bench suite itself (small P for speed): emits the
-        solo/contended/FIFO p99 legs per QoS class + the isolation
-        ratio, sim-tiered, with the in-band fairness bound holding."""
-        import bench
-
-        lines = bench._multi_tenant_micro_suite(sizes=(64,))
-        by_metric = {l["metric"]: l for l in lines}
-        iso = by_metric["tenant_latency_isolation_p64"]
-        assert iso["tier_label"] == "sim"
-        assert 1.0 <= iso["value"] <= iso["bound"] * 1.10
-        assert by_metric["tenant_fifo_hol_ratio_p64"]["value"] \
-            > 2.0 * iso["value"]
-        solo = by_metric["tenant_lat_solo_p99_p64"]
-        cont = by_metric["tenant_lat_contended_p99_p64"]
-        assert solo["qos"] == "latency" and cont["value"] \
-            >= solo["value"]
-        assert by_metric["tenant_bulk_contended_p99_p64"]["qos"] \
-            == "bulk"
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        for l in lines:
-            assert gate._direction(l["unit"], l["metric"]) == -1
-
-    def test_sim_tier_band_is_tight_not_wall_clock_wobble(self,
-                                                          tmp_path):
-        """Sim lines are deterministic replays: the ±25% wall-clock
-        noise floor must NOT apply, or a 8 -> 10 round schedule
-        regression (+25%) would pass silently. The sim tier's floor
-        is 2%."""
-        from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-        def sim(v, tier="sim"):
-            return {"metric": "sim_rd_rounds_p256", "value": v,
-                    "unit": "rounds", "vs_baseline": None,
-                    "tier_label": tier}
-
-        hist = [[sim(8)] for _ in range(4)]      # bit-identical
-        verdict = gate.evaluate(hist, [sim(10)])  # +25%: a real
-        assert len(verdict["regressions"]) == 1   # regression, trips
-        assert gate.evaluate(hist, [sim(8)])["regressions"] == []
-        # the wall-clock tiers keep the wobble floor: +25% on a quiet
-        # tpu-tier history stays inside the band
-        thist = [[{"metric": "steps_used", "value": 8.0, "unit":
-                   "steps", "vs_baseline": None, "tier_label": "tpu"}]
-                 for _ in range(4)]
-        tcand = [{"metric": "steps_used", "value": 9.9, "unit":
-                  "steps", "vs_baseline": None, "tier_label": "tpu"}]
-        assert gate.evaluate(thist, tcand)["regressions"] == []
 
 
 # ---------------------------------------------------------------------------

@@ -294,3 +294,42 @@ def test_closed_endpoint_raises_not_segfaults():
         ep.recv(tag=5, timeout_ms=50)
     with pytest.raises(MPIError):
         ep.connect(1, "127.0.0.1", port)
+
+
+def test_processes_that_start_together_build_once(tmp_path):
+    """A fresh checkout (no ``native/build``), four processes reaching
+    for the library at once, as xdist workers and ``tpurun`` ranks do:
+    the compiler runs once and every process loads a whole library —
+    before, each ran ``make`` into the same directory and one could
+    load the .so another compiler was still writing (35 tests of
+    ``test_native_exec.py`` skipped as "planexec symbols not in the
+    loaded .so" in one whole run of two)."""
+    import os
+    import shutil
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    shutil.copytree(os.path.join(repo, "ompi_release_tpu"),
+                    tmp_path / "ompi_release_tpu",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copytree(os.path.join(repo, "native"), tmp_path / "native",
+                    ignore=shutil.ignore_patterns("build"))
+    log = tmp_path / "compiles.log"
+    cxx = tmp_path / "cxx"
+    cxx.write_text(f'#!/bin/sh\necho run >> {log}\nexec g++ "$@"\n')
+    cxx.chmod(0o755)
+    probe = ("import sys; sys.path.insert(0, %r)\n"
+             "from ompi_release_tpu.native import bindings as b\n"
+             "assert b.__file__.startswith(%r), b.__file__\n"
+             "print('WHOLE', b.planexec_symbols_available()"
+             " and b.wire_symbols_available())\n"
+             % (str(tmp_path), str(tmp_path)))
+    env = dict(os.environ, CXX=str(cxx))
+    procs = [subprocess.Popen([sys.executable, "-c", probe], env=env,
+                              stdout=subprocess.PIPE, text=True)
+             for _ in range(4)]
+    outs = [p.communicate(timeout=300)[0] for p in procs]
+    assert [p.returncode for p in procs] == [0] * 4, outs
+    assert all("WHOLE True" in o for o in outs), outs
+    assert log.read_text().count("run") == 1

@@ -953,7 +953,9 @@ class TestNativeJobs:
         The native fires return what the interpreted first call
         returned, bit for bit; with opposing senders some fragment must
         have met a full ring and yielded; a tx ring counts at most one
-        stall per fragment it carried. Counters, never wall time.
+        stall per fragment it carried. Counters, never wall time: the
+        processes meet in a barrier after every call, so which fires
+        the C executor takes does not depend on who runs ahead.
 
         The executor's slab is reused (ISSUE 31): a result that was
         KEPT is bit-identical after the plan's next fire has run with
@@ -972,7 +974,18 @@ class TestNativeJobs:
                      "alltoall": world.alltoall,
                      "bcast": lambda v: world.bcast(v, root=1),
                      "gather": lambda v: world.gather(v, root=n - 1)}
-            call = calls[OP]
+
+            def call(v):
+                # The counts below are exact only if no process is a
+                # call ahead of another: a bcast root returns once its
+                # sends are posted, a receiver still reaping the call
+                # before pops that early frame and restashes it, and its
+                # next fire is then rightly delegated to the Python leg
+                # (a counted fallback). The barrier's tokens ride the
+                # OOB endpoint, not the rings.
+                out = calls[OP](v)
+                world.barrier()
+                return out
 
             def data(per, salt):
                 return np.stack([(np.arange(per, dtype=np.int32) * 7

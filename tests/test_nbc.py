@@ -119,15 +119,29 @@ def test_disjoint_icollectives_both_in_flight(halves):
     pending concurrently, which is the property that turns into
     wall-clock overlap on TPU where disjoint device sets are disjoint
     hardware."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     lo, hi = halves
-    x = np.ones((4, 4 << 20), np.float32)
 
+    def resident(comm):
+        # on the comm's devices already: a host array would be copied
+        # to them inside the dispatch, 64 MiB at a time, and the second
+        # dispatch then takes as long as the first collective runs
+        return jax.device_put(np.ones((4, 4 << 20), np.float32),
+                              NamedSharding(comm.submesh, P("rank")))
+
+    xa, xb = resident(lo), resident(hi)
     # warm both compiled programs
-    jax.block_until_ready(lo.allreduce(x, ops.SUM))
-    jax.block_until_ready(hi.allreduce(x, ops.SUM))
+    jax.block_until_ready(lo.allreduce(xa, ops.SUM))
+    jax.block_until_ready(hi.allreduce(xb, ops.SUM))
 
-    ra = lo.iallreduce(x, ops.SUM)
-    rb = hi.iallreduce(x, ops.SUM)
+    # four allreduces in a row on lo, each fed the one before: the last
+    # cannot complete before ~256 MiB have been reduced, while the
+    # dispatch on hi is a few hundred microseconds of Python
+    ra = lo.iallreduce(xa, ops.SUM)
+    for _ in range(3):
+        ra = lo.iallreduce(ra.value, ops.SUM)
+    rb = hi.iallreduce(xb, ops.SUM)
     # both dispatched, neither complete: concurrently in flight
     a_pending = not ra.test()[0]
     b_pending = not rb.test()[0]
@@ -137,6 +151,8 @@ def test_disjoint_icollectives_both_in_flight(halves):
         f"a_pending={a_pending} b_pending={b_pending} — the second "
         "dispatch did not happen while the first was in flight"
     )
+    np.testing.assert_array_equal(np.asarray(ra.value)[0, :4],
+                                  np.full(4, 4.0 ** 4, np.float32))
 
 
 def test_icollectives_complete_with_values(world):

@@ -194,72 +194,6 @@ class TestPallasOpKernels:
         out = pallas_op.scale(jnp.asarray(x), 2.0)
         np.testing.assert_allclose(np.asarray(out), x * 2.0, rtol=1e-6)
 
-    def test_bench_loops_run(self):
-        from ompi_release_tpu.ops import pallas_op
-
-        rows, cols = pallas_op.AXPY_BLOCK[0], pallas_op.AXPY_BLOCK[1]
-        loop = pallas_op.make_axpy_loop(rows, cols)
-        v = loop(jnp.ones((rows, cols), jnp.float32), 3)
-        assert np.isfinite(float(v))
-        rows, cols = pallas_op.SCALE_BLOCK
-        loop = pallas_op.make_scale_loop(rows, cols)
-        v = loop(jnp.ones((rows, cols), jnp.float32), 3)
-        assert np.isfinite(float(v))
-
-    def test_transpose_loop_semantics(self):
-        """The bench's alltoall analogue: call is a real blocked
-        transpose, and the loop body applies it TWICE (4 counted
-        streams/iter — the carry-copy fix, see make_transpose_loop),
-        so the carry after any k equals the input."""
-        from ompi_release_tpu.ops import pallas_op
-
-        n, block = 16, 8
-        loop, call = pallas_op.make_transpose_loop(n, block=block)
-        x = jnp.arange(n * n, dtype=jnp.int32).reshape(n, n)
-        np.testing.assert_array_equal(np.asarray(call(x)),
-                                      np.asarray(x).T)
-        # loop returns corner-sum of the carry; double-apply => carry
-        # is x itself for every k
-        expect = int(x[0, 0] + x[-1, -1])
-        for k in (0, 1, 3):
-            assert int(loop(x, k)) == expect
-
-
-def test_bench_end_to_end_on_simulator_mesh():
-    """bench.py's full multi-device path (the scoreboard the driver
-    runs) must execute on the 8-device simulator mesh and emit valid
-    JSON metric lines with the headline LAST — a crash here would
-    silence the round's BENCH file."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    from conftest import subprocess_env
-
-    # subprocess_env: a bare subprocess would bench whatever
-    # accelerator the machine has instead of the simulator mesh
-    env = subprocess_env(XLA_FLAGS=(os.environ.get("XLA_FLAGS", "")
-                         + " --xla_force_host_platform_device_count=8"))
-    r = subprocess.run(
-        [sys.executable, "bench.py"], cwd="/root/repo", env=env,
-        capture_output=True, text=True, timeout=900,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    lines = [json.loads(ln) for ln in r.stdout.strip().splitlines()
-             if ln.startswith("{")]
-    metrics = [ln for ln in lines if "metric" in ln]
-    assert len(metrics) >= 5, lines
-    for ln in metrics:
-        assert "value" in ln and "unit" in ln
-        if ln.get("vs_baseline") is not None:
-            assert ln["vs_baseline"] <= 1.0 + 1e-9  # by construction
-    # every metric line travels with a pvar snapshot (obs plane)
-    assert any("pvars" in ln for ln in lines), lines
-    headline = lines[-1]
-    assert "allreduce" in headline["metric"] or "op_sum" in \
-        headline["metric"]
-
 
 def test_reduce_local():
     """MPI_Reduce_local: inout = in OP inout, no communication; pair

@@ -404,26 +404,6 @@ def test_tpu_top_desync_flag():
         [{"meta": {"pidx": 0}, "points": pts[1:]}])
 
 
-def test_bench_gate_sentinel_metrics_are_lower_better():
-    from ompi_release_tpu.tools.tpu_bench_gate import _direction
-
-    assert _direction("frac_overhead",
-                      "sentinel_allreduce_overhead_frac") == -1
-    assert _direction("s", "sentinel_allreduce_1MiB_disabled") == -1
-    # regression trips on overhead GROWTH past the fitted band
-    from ompi_release_tpu.tools.tpu_bench_gate import evaluate
-
-    hist = [[{"metric": "sentinel_allreduce_overhead_frac",
-              "value": 0.01, "unit": "frac_overhead",
-              "tier_label": "loopback-cpu"}] for _ in range(4)]
-    bad = [{"metric": "sentinel_allreduce_overhead_frac", "value": 0.8,
-            "unit": "frac_overhead", "tier_label": "loopback-cpu"}]
-    assert evaluate(hist, bad)["regressions"]
-    ok = [{"metric": "sentinel_allreduce_overhead_frac", "value": 0.012,
-           "unit": "frac_overhead", "tier_label": "loopback-cpu"}]
-    assert not evaluate(hist, ok)["regressions"]
-
-
 def test_inline_frame_template_renders_byte_identical_payload():
     """The planned path's precomposed ctl frame: for any (seq, epoch)
     the template's render must be byte-for-byte what the interpreted

@@ -559,7 +559,16 @@ class TestMigration:
         rank there is terminated, remapped to nodeA (which stays
         excluded for later respawns), respawned, and resumes from its
         last committed checkpoint; the job completes rc=0 and the
-        failure-restart budget is untouched."""
+        failure-restart budget is untouched.
+
+        The request is made while rank 1 stands between the world
+        barrier of its fifth save and that save's commit (it says so
+        through a file and lingers there): the kill waits the commit
+        out, so the new incarnation resumes at step 5, the step rank 0
+        counts, and not one barrier behind it (M5's race: that job
+        hung at its last save). Rank 0 stays out of the next barrier
+        until the new incarnation is up: a barrier token sent to an
+        incarnation that is dying is lost with it (M5, open)."""
         import threading
         import time as _time
 
@@ -577,6 +586,8 @@ class TestMigration:
         agent.chmod(0o755)
         ckdir = tmp_path / "ck"
         ckdir.mkdir()
+        in_window = tmp_path / "rank1_between_barrier_and_commit"
+        resumed = tmp_path / "rank1_resumed"
         app = _write_app(tmp_path, """
             import time
             from ompi_release_tpu.ft import Checkpointer
@@ -592,14 +603,30 @@ class TestMigration:
                 state = ck.restore(state, step=latest)
                 start = int(state["step"])
                 print(f"RESUMED {pi} from {start}", flush=True)
+                open(%r, "w").close()
+            elif pi == 1:
+                quiesce = ck.quiesce
+
+                def quiesce_then_linger():
+                    quiesce()  # the world barrier of this save
+                    if int(state["step"]) == 5:
+                        open(%r, "w").close()
+                        time.sleep(0.3)
+
+                ck.quiesce = quiesce_then_linger
             for step in range(start, 16):
                 state["step"] = jax.numpy.asarray(step + 1)
                 ck.save(step + 1, state)
                 ck.wait()
                 time.sleep(0.25)
+                if pi == 0 and step + 1 == 5:
+                    for _ in range(6000):
+                        if os.path.exists(%r):
+                            break
+                        time.sleep(0.01)
             print(f"DONE {pi}", flush=True)
             mpi.finalize()
-        """ % str(ckdir))
+        """ % (str(ckdir), str(resumed), str(in_window), str(resumed)))
         job = Job(2, [sys.executable, app], [], heartbeat_s=0.3,
                   hosts=[HostSpec("nodeA", 2), HostSpec("nodeB", 2)],
                   map_by="node", launch_agent=str(agent),
@@ -607,11 +634,10 @@ class TestMigration:
         results = {}
 
         def migrate_when_running():
-            for _ in range(600):
-                if job.job_state.visited(JobState.RUNNING):
+            for _ in range(6000):
+                if in_window.exists():
                     break
-                _time.sleep(0.05)
-            _time.sleep(1.2)  # let the app commit a few checkpoints
+                _time.sleep(0.01)
             results["reply"] = request_migration(
                 "127.0.0.1", job.hnp.port, "nodeB")
 
@@ -631,7 +657,7 @@ class TestMigration:
         # and the OLD incarnation actually died (TAG_DIE through the
         # control plane: killing only the local fake-ssh client would
         # orphan it to run to completion, printing DONE 1 twice)
-        assert "RESUMED 1 from" in out
+        assert "RESUMED 1 from 5" in out, out
         assert "DONE 0" in out and "DONE 1" in out
         assert out.count("DONE 1") == 1, out
         assert out.count("RESUMED 1") == 1, out

@@ -595,17 +595,3 @@ class TestTreeJob:
         assert job.job_state.visited(JobState.TERMINATED)
         for pidx in range(3):
             assert f"TREE-JOB-OK {pidx}" in out.out
-
-
-# ---------------------------------------------------------------------------
-# bench-gate direction for the new suite's lines
-# ---------------------------------------------------------------------------
-
-def test_gate_directions_for_tree_lines():
-    from ompi_release_tpu.tools import tpu_bench_gate as gate
-
-    assert gate._direction("frac_hidden", "tree_allreduce_hidden_frac") == 1
-    assert gate._direction("x_vs_blocking", "tree_planned_pass_speedup") == 1
-    assert gate._direction(None, "tree_pp_overlap_speedup") == 1
-    assert gate.gateable({"metric": "tree_overlap_speedup",
-                          "value": 4.2, "unit": "x_vs_blocking"})

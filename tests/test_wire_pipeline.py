@@ -175,17 +175,22 @@ class TestStagedPipelineParity:
         a, b = self._pair()
         m = DcnBtl()
         try:
-            zc = pvar_mod.PVARS.lookup("wire_bytes_zero_copy")
             fi = pvar_mod.PVARS.lookup("wire_frags_inflight")
-            assert zc is not None and fi is not None
-            before = float(zc.read())
+
+            def zc():
+                got = pvar_mod.PVARS.read_all()
+                return float(got["wire_bytes_zero_copy_strict"]
+                             + got["wire_bytes_sliced"])
+
+            assert fi is not None
+            before = zc()
             with _Segsize(1024):
                 x = np.ones(4096, np.uint8)
                 m.send_staged(b, 0, 155, x)
                 np.testing.assert_array_equal(
                     np.asarray(m.recv_staged(a, 155)), x)
             # sender slices + receiver view: 2 x 4096 bytes accounted
-            assert float(zc.read()) - before >= 2 * 4096
+            assert zc() - before >= 2 * 4096
             assert float(fi.read()) >= 4  # 4 fragments announced
         finally:
             a.close()
@@ -281,7 +286,9 @@ class TestWireJobs:
                 np.testing.assert_array_equal(np.asarray(v1), big1)
                 np.testing.assert_array_equal(np.asarray(v2), big2)
             world.barrier()
-            zc = pvar.PVARS.read_all().get("wire_bytes_zero_copy", 0)
+            got = pvar.PVARS.read_all()
+            zc = (got.get("wire_bytes_zero_copy_strict", 0)
+                  + got.get("wire_bytes_sliced", 0))
             assert zc > 0, "fragment path never carried a byte"
             print(f"WIREPIPE-OK {off}")
             mpi.finalize()
