@@ -12,6 +12,14 @@
 // endpoint/ring handles, and then a steady-state fire is one
 // fire_begin + a fire_step loop that walks every round C-side.
 //
+// Live rounds: a round whose send bytes the provenance probe could
+// not locate (a schedule that folds what arrives and sends the fold
+// on) carries no scatter-gather map. The walk pauses in front of it
+// (RC_PAUSE), Python hands over the arrays the schedule body has just
+// made (fire_supply) and the same fire walks on — round 0's "inputs
+// come from the caller" at a later round. The fire, its xfer ids, its
+// slab and the locks Python holds around it stay one.
+//
 // Wire parity is structural, not aspirational: headers are composed
 // from the SAME precomposed pre/mid byte strings FrameTemplate uses
 // (pre + int64rec(xfer) + mid + int64rec(crc)), fragments carry the
@@ -77,6 +85,7 @@ namespace {
 constexpr int RC_DONE = 0;
 constexpr int RC_AGAIN = 1;        // slice expired; call fire_step again
 constexpr int RC_FTSTOP = 2;       // fault word set; Python runs check_wait
+constexpr int RC_PAUSE = 3;        // live round ahead: fire_supply, then step
 constexpr int RC_BADARG = -1;
 constexpr int RC_PEERDEAD = -2;    // err_peer() names the pidx
 constexpr int RC_TIMEOUT = -3;     // plan timeout exhausted
@@ -85,7 +94,7 @@ constexpr int RC_TRUNCATED = -5;   // reassembled payload failed CRC
 constexpr int RC_WOULDBLOCK = -100;  // internal: ring full, try later
 
 constexpr uint64_t kBlobMagic = 0x314345584C504FULL;  // "OPLXEC1"
-constexpr int64_t kBlobVersion = 1;
+constexpr int64_t kBlobVersion = 2;
 
 // DSS int64 single-value record marker: type tag DSS_INT64 (1) +
 // u32 LE count 1 — the 5 bytes btl/components._int64_rec prepends.
@@ -227,7 +236,8 @@ void nap_us(long us) {
 // ---- frozen descriptor (parsed once from the Python-built blob) ----
 
 struct Seg {          // one scatter-gather span of a composed payload
-  int64_t kind;       // 0 = input region (live pointer), 1 = pool
+  int64_t kind;       // 0 = input region (live pointer), 1 = pool,
+                      // 2 = message idx of a live round (fire_supply)
   int64_t idx;        // region index within its kind
   int64_t off;
   int64_t len;
@@ -257,6 +267,7 @@ struct RecvSrc {
 
 struct Round {
   int64_t depth;
+  bool live = false;  // sends come from Python, at the pause before it
   std::vector<Stream> streams;
   std::vector<RecvSrc> rsrcs;
 };
@@ -317,6 +328,9 @@ struct PlanExec {
   // fire state
   bool firing = false;
   std::vector<const uint8_t*> inputs;
+  std::vector<const uint8_t*> supplied;  // the current live round's sends
+  bool supplied_ok = false;
+  int64_t timeout_ms = 0;
   int64_t xfer_next = 0;
   double deadline_total = 0.0;
   size_t cur_round = 0;
@@ -381,6 +395,7 @@ PlanExec* parse_blob(const uint8_t* blob, int64_t len) {
   for (int64_t r = 0; c.ok && r < n_rounds; ++r) {
     Round rd;
     rd.depth = c.i64();
+    rd.live = c.i64() != 0;
     int64_t n_streams = c.i64();
     for (int64_t s = 0; c.ok && s < n_streams; ++s) {
       Stream st;
@@ -428,15 +443,24 @@ PlanExec* parse_blob(const uint8_t* blob, int64_t len) {
   // structural sanity: every index in range, sizes consistent
   if (c.ok) {
     for (auto& rd : x->rounds) {
+      int64_t flat = 0;  // message index within the round, stream order
       for (auto& st : rd.streams) {
         if (st.peer < 0 ||
             st.peer >= static_cast<int64_t>(x->peers.size()))
           c.ok = false;
         for (auto& sm : st.msgs) {
           int64_t tot = 0;
+          // a live round's message is one supplied array, whole
+          if (rd.live && (sm.segs.size() != 1 || sm.segs[0].kind != 2 ||
+                          sm.segs[0].idx != flat))
+            c.ok = false;
+          ++flat;
           for (auto& sg : sm.segs) {
             tot += sg.len;
-            if (sg.kind == 0) {
+            if (sg.kind == 2) {
+              if (!rd.live || sg.off != 0 || sg.len != sm.nbytes)
+                c.ok = false;
+            } else if (sg.kind == 0) {
               if (sg.idx < 0 ||
                   sg.idx >= static_cast<int64_t>(x->input_lens.size()) ||
                   sg.off < 0 || sg.off + sg.len > x->input_lens[sg.idx])
@@ -502,13 +526,20 @@ int send_header(PlanExec* x, const PeerBind& pb, const SendMsg& m,
                   h.data(), static_cast<int32_t>(h.size()));
 }
 
+// Where a span's bytes live right now: a caller's array (fire_begin's
+// or, in a live round, fire_supply's) or the reassembly slab.
+const uint8_t* seg_base(PlanExec* x, const Seg& sg) {
+  size_t i = static_cast<size_t>(sg.idx);
+  if (sg.kind == 0) return x->inputs[i];
+  if (sg.kind == 2) return x->supplied[i];
+  return x->slab.data() + x->pool[i].off;
+}
+
 uint32_t crc_of_msg(PlanExec* x, const SendMsg& m) {
   uint32_t crc = 0;
   for (auto& sg : m.segs) {
-    const uint8_t* base = sg.kind == 0
-        ? x->inputs[static_cast<size_t>(sg.idx)]
-        : x->slab.data() + x->pool[static_cast<size_t>(sg.idx)].off;
-    crc = crc32_update(crc, base + sg.off, static_cast<size_t>(sg.len));
+    crc = crc32_update(crc, seg_base(x, sg) + sg.off,
+                       static_cast<size_t>(sg.len));
   }
   return crc;
 }
@@ -562,10 +593,7 @@ int send_frag(PlanExec* x, const PeerBind& pb, const SendMsg& m,
     if (s1 <= lo || s0 >= hi) continue;
     int64_t a = lo > s0 ? lo : s0;
     int64_t b = hi < s1 ? hi : s1;
-    const uint8_t* base = sg.kind == 0
-        ? x->inputs[static_cast<size_t>(sg.idx)]
-        : x->slab.data() + x->pool[static_cast<size_t>(sg.idx)].off;
-    push(base + sg.off + (a - s0), b - a);
+    push(seg_base(x, sg) + sg.off + (a - s0), b - a);
   }
   const uint8_t** P = pvec.empty() ? parts : pvec.data();
   int64_t* L = lvec.empty() ? lens : lvec.data();
@@ -763,6 +791,7 @@ void enter_round(PlanExec* x) {
   x->rst.assign(rd.rsrcs.size(), SrcState());
   for (size_t i = 0; i < rd.rsrcs.size(); ++i)
     if (rd.rsrcs[i].msgs.empty()) x->rst[i].done = true;
+  x->supplied_ok = false;
 }
 
 }  // namespace
@@ -811,6 +840,7 @@ int planexec_fire_begin(void* h, const uint8_t** inputs,
       return RC_BADARG;
   x->inputs.assign(inputs, inputs + n);
   x->xfer_next = xfer_base;
+  x->timeout_ms = timeout_ms;
   x->deadline_total = mono_s() + 1e-3 * static_cast<double>(timeout_ms);
   x->cur_round = 0;
   x->ts.assign(x->rounds.size(), 0.0);
@@ -822,7 +852,41 @@ int planexec_fire_begin(void* h, const uint8_t** inputs,
   return 0;
 }
 
-// Walk rounds until done, error, fault-word stop, or slice expiry.
+// The sends of the live round the walk has paused at, one pointer per
+// message in stream order (sorted peer, then the peer's message list):
+// the arrays must stay where they are until the next return that is
+// not RC_AGAIN / RC_FTSTOP. The wait bound starts anew, as a replayed
+// round's does: the time Python took between two segments is not the
+// wire's.
+int planexec_fire_supply(void* h, const uint8_t** ptrs,
+                         const int64_t* lens, int64_t n) {
+  auto* x = static_cast<PlanExec*>(h);
+  if (!x->firing || x->cur_round >= x->rounds.size()) return RC_BADARG;
+  Round& rd = x->rounds[x->cur_round];
+  if (!rd.live || x->supplied_ok) return RC_BADARG;
+  int64_t flat = 0;
+  for (auto& st : rd.streams)
+    for (auto& sm : st.msgs) {
+      if (flat >= n || lens[flat] != sm.nbytes) return RC_BADARG;
+      ++flat;
+    }
+  if (flat != n) return RC_BADARG;
+  x->supplied.assign(ptrs, ptrs + n);
+  x->supplied_ok = true;
+  x->deadline_total = mono_s() + 1e-3 * static_cast<double>(x->timeout_ms);
+  return 0;
+}
+
+// The fire is over for Python: walked to its end, or left where it
+// stands (its schedule raised between two segments). What the reap
+// set aside stays in the stash for drain, the next fire_begin starts
+// clean.
+void planexec_fire_end(void* h) {
+  static_cast<PlanExec*>(h)->firing = false;
+}
+
+// Walk rounds until done, error, fault-word stop, slice expiry, or a
+// live round whose sends have not been supplied.
 // Send legs stripe round-robin across peer streams in depth-sized
 // bursts (the _stripe discipline). A ring write never waits: on a
 // full ring the send phase drains its own arrivals until a sweep
@@ -837,8 +901,9 @@ int planexec_fire_step(void* h, int64_t slice_ms) {
 
   while (x->cur_round < x->rounds.size()) {
     Round& rd = x->rounds[x->cur_round];
+    if (rd.live && !x->supplied_ok) return RC_PAUSE;
 
-    // ---- send phase: striped depth bursts over live streams ----
+    // ---- send phase: striped depth bursts over open streams ----
     bool sends_left = false;
     for (auto& ss : x->sst) sends_left |= !ss.done;
     while (sends_left) {

@@ -28,6 +28,20 @@ so the wire traffic is bitwise-identical to the interpreted path's
 (the mixed-fleet contract — a peer without the .so interoperates
 frame-for-frame).
 
+Live rounds: a schedule that FOLDS what arrives and sends the fold on
+(ring, Rabenseifner, the torus and multiring allreduces) makes bytes
+no region holds. A round with such a message — or with one that is
+part fold, part region, or too short to prove — is *live*: the fire
+runs in segments. The C walk pauses in front of a live round, the
+schedule body (in lockstep through :meth:`NativeXchg.exchange`, having
+just folded the arrivals it was handed) supplies that round's send
+arrays, and the same fire walks on to the next live round or the end.
+Round 0, whose sends "come from the arrays the schedule just passed",
+is the first case of it. Which rounds are live is what the probe
+observed, twice and alike; the folds stay in the schedule body, in its
+operand order. One call is still ONE fire: one set of locks held from
+the first segment to the last, one ``plan_native_fires`` count.
+
 Selection follows the MCA discipline: the ``coll_plan_native`` cvar
 plus a capability check — native symbols present, every round peer on
 the nativewire card, every send slot frame-templated, no QoS arbiter
@@ -89,6 +103,19 @@ _native_fallbacks = pvar.counter(
     "ring-lock contention)",
 )
 
+_native_live_rounds = pvar.counter(
+    "plan_native_live_rounds",
+    "rounds whose sends the schedule body supplied to a running "
+    "native fire (the fire paused before them: their bytes are folds "
+    "of earlier arrivals, which no region holds)",
+)
+_python_replays = pvar.counter(
+    "plan_python_replays",
+    "calls replayed round by round through PlannedXchg because their "
+    "frozen plan was never lowered into the C executor (neither a "
+    "plan_native_fires nor a plan_native_fallbacks count)",
+)
+
 _pool_copy_bytes = pvar.counter(
     "plan_pool_copy_bytes",
     "bytes copied out of a native plan's reassembly slab into fresh "
@@ -106,7 +133,7 @@ _pool_copy_bytes = pvar.counter(
 VIEW_OPS = frozenset({"allgather", "alltoall", "bcast", "gather"})
 
 _BLOB_MAGIC = 0x314345584C504F  # "OPLXEC1" little-endian
-_BLOB_VERSION = 1
+_BLOB_VERSION = 2
 _WIN = 16        # provenance-window bytes: unique-match granularity
 _SEP = 32        # random separator bytes between arena regions
 _SLICE_MS = 100  # matches runtime.wire._FT_SLICE_S
@@ -326,8 +353,20 @@ def _match_payload(pay: bytes, arena: bytes, a_arr: np.ndarray,
 
 
 def _infer_maps(plan, m, fn, args, kw, arg_idx):
-    """Byte-provenance maps for every round >= 1 send message, proven
-    identical across two independently-seeded probes."""
+    """Byte-provenance maps for every round >= 1 send message and the
+    set of LIVE rounds, both proven identical across two
+    independently-seeded probes. Returns ``(maps, live)``: ``maps[r]``
+    holds one seg tuple per message of a mapped round and is None for
+    round 0 and for a live round.
+
+    A round is live when one of its messages cannot be covered whole
+    by region bytes (:func:`_match_payload` refuses it: a fold, a
+    payload that is part fold and part region, one too short to
+    prove): the schedule body supplies such a round's sends to the
+    running fire. So is a round that comes after a live one and reads
+    a caller's array (kind 0): the body has run on between two
+    segments and may have written to what it holds (Rabenseifner
+    accumulates in place); only the slab is the executor's own."""
     results = []
     for seed in (0x5EED01 ^ (plan.cid & 0xFFFF),
                  0x5EED02 ^ (plan.cid & 0xFFFF)):
@@ -348,13 +387,24 @@ def _infer_maps(plan, m, fn, args, kw, arg_idx):
         arena, bounds = _build_arena(rng, inputs, pool_list)
         a_arr = np.frombuffer(arena, dtype=np.uint8)
         maps: List[Optional[Tuple]] = [None]  # round 0 is identity
+        live = set()
         for r in range(1, len(plan.rounds)):
-            maps.append(tuple(_match_payload(p, arena, a_arr, bounds)
-                              for p in payloads[r]))
-        results.append(tuple(maps[1:]))
+            try:
+                segs = tuple(_match_payload(p, arena, a_arr, bounds)
+                             for p in payloads[r])
+            except _ProbeFail:
+                segs = None
+            if segs is not None and live and any(
+                    sg[0] == 0 for msg in segs for sg in msg):
+                segs = None
+            if segs is None:
+                live.add(r)
+            maps.append(segs)
+        results.append((tuple(maps), frozenset(live)))
     if results[0] != results[1]:
-        raise _ProbeFail("independent probes inferred different maps")
-    return (None,) + results[0]
+        raise _ProbeFail("independent probes inferred different maps "
+                         "or live rounds")
+    return results[0]
 
 
 # ---------------------------------------------------------------------------
@@ -370,8 +420,10 @@ def build_blob(tag: int, input_lens, pool_sizes, peer_pidx,
     """Serialize the flat descriptor table ``planexec_create``
     consumes (all fields little-endian int64; byte fields carry an
     int64 length prefix). ``rounds`` entries are dicts with ``depth``,
-    ``streams`` = [(peer_idx, [msg...])] where a send msg is
-    (pre, mid, nbytes, nchunks, chunk, segs) and segs are
+    ``live`` (optional; 1 = the sends are supplied at the pause before
+    the round, each message's segs then ((2, its index in the round,
+    0, nbytes),)), ``streams`` = [(peer_idx, [msg...])] where a send
+    msg is (pre, mid, nbytes, nchunks, chunk, segs) and segs are
     (kind, idx, off, len); ``rsrcs`` = [(peer_idx, [recv msg...])]
     where a recv msg is (pool_idx, nbytes, nchunks, chunk, pre, mid).
     Exposed module-level so ``obs --selftest`` compiles a descriptor
@@ -407,6 +459,7 @@ def build_blob(tag: int, input_lens, pool_sizes, peer_pidx,
     w(len(rounds))
     for rd in rounds:
         w(rd["depth"])
+        w(rd.get("live", 0))
         w(len(rd["streams"]))
         for peer_idx, msgs in rd["streams"]:
             w(peer_idx)
@@ -448,7 +501,7 @@ class NativePlan:
         "r0_specs", "pool_rounds", "timeout_ms", "ftword", "router",
         "rx_entries", "fire_locks", "send_msgs", "send_bytes",
         "recv_msgs", "recv_bytes", "send_frames", "recv_frames",
-        "xfer_total", "pool_count", "pool_total",
+        "xfer_total", "pool_count", "pool_total", "live",
     )
 
     def close(self) -> None:
@@ -546,8 +599,9 @@ def _compile(state, m, fn, args, kw, t0):
     for p in peers:
         if router._btl_for(p) is not nw:
             raise _Ineligible(f"peer {p} not on nativewire")
-    # byte-provenance probe (two seeds, identical maps required)
-    maps = _infer_maps(plan, m, fn, args, kw, arg_idx)
+    # byte-provenance probe (two seeds; identical maps and live
+    # rounds required)
+    maps, live = _infer_maps(plan, m, fn, args, kw, arg_idx)
 
     seg = min(tuning.segsize, max(1, nw.max_send_size))
     from ..btl.components import plan_frame_template
@@ -598,6 +652,8 @@ def _compile(state, m, fn, args, kw, t0):
                 nb = _nbytes_of(shape, dt)
                 if i == 0:
                     segs = ((0, r0_base + flat, 0, nb),)
+                elif i in live:
+                    segs = ((2, flat, 0, nb),)
                 else:
                     segs = maps[i][flat]
                     tot = 0
@@ -628,6 +684,7 @@ def _compile(state, m, fn, args, kw, t0):
                 recv_frames += int(tpl.nchunks) + 1
             rsrcs.append((peer_index[src], msgs))
         rounds_desc.append({"depth": int(rnd.depth),
+                            "live": int(i in live),
                             "streams": streams, "rsrcs": rsrcs})
 
     blob = build_blob(plan.rounds[0].tag, input_lens, pool_sizes,
@@ -680,6 +737,7 @@ def _compile(state, m, fn, args, kw, t0):
     npl.xfer_total = max(1, send_msgs)
     npl.pool_count = len(pool_sizes)
     npl.pool_total = px.pool_total
+    npl.live = live
     _pool_bytes.add(npl.pool_total)
     if _obs.enabled:
         _obs.record("plan_native_compile", "plan", t0,
@@ -692,18 +750,28 @@ def _compile(state, m, fn, args, kw, t0):
 # ---------------------------------------------------------------------------
 
 class NativeXchg:
-    """Exchange adapter that fires the WHOLE plan C-side on its first
-    round: round-0 sends come verbatim from the arrays the schedule
-    just passed, later rounds compose from the proven byte-provenance
-    maps, receives reassemble into the plan pool. Rounds >= 1 only
-    verify structure and hand back what arrived: read-only views of
-    the pool where ``views`` says the schedule reads them once and
-    keeps none (:data:`VIEW_OPS`), else copies. Any per-fire safety
-    veto (stashed frames, lock contention) delegates the entire fire
-    to a fresh :class:`~.plan.PlannedXchg` — same plan, same bytes."""
+    """Exchange adapter that fires the WHOLE plan C-side, in one
+    segment per live round: round-0 sends come verbatim from the
+    arrays the schedule just passed and the walk runs on — composing
+    mapped rounds from the proven byte-provenance maps, reassembling
+    receives into the plan pool — until it stands before a live round
+    or at the end; the exchange of a live round supplies that round's
+    sends and lets it run on again. Every other exchange only verifies
+    structure. Each hands back what arrived in its round: read-only
+    views of the pool where ``views`` says the schedule reads them
+    once and keeps none (:data:`VIEW_OPS`), else copies.
+
+    One call is one fire: the channel, ring and rx-entry locks are
+    taken before the first segment and released after the last (or by
+    :meth:`close`, which ``SpanningPlanState.run`` calls whatever the
+    schedule body did), so between two segments nobody pops this
+    channel's frames. A safety veto before the first segment (stashed
+    frames, lock contention) delegates the entire call to a fresh
+    :class:`~.plan.PlannedXchg` — same plan, same bytes; once bytes
+    have moved a failure is an error."""
 
     __slots__ = ("m", "plan", "np", "i", "ts", "args", "seq", "views",
-                 "_delegate", "_pool", "_c_wait")
+                 "_delegate", "_pool", "_c_wait", "_px", "_held")
 
     def __init__(self, module, plan, npl: NativePlan,
                  args: Tuple, seq: int = 0, views: bool = False) -> None:
@@ -712,7 +780,7 @@ class NativeXchg:
         self.np = npl
         self.i = 0
         self.views = views
-        #: the schedule's posting seq: joins this fire's span to its
+        #: the schedule's posting seq: joins this fire's spans to its
         #: ``ompi.nbc.wait``
         self.seq = seq
         self.ts: Optional[List[float]] = None
@@ -724,6 +792,10 @@ class NativeXchg:
         #: orchestration self-report (the ctypes entry/exit and pool
         #: reads are Python orchestration; the descriptor walk isn't)
         self._c_wait = 0.0
+        #: the executor of the OPEN fire (None before the first
+        #: segment and after the last) and the locks held for it
+        self._px = None
+        self._held: List[threading.Lock] = []
 
     def _mismatch(self, detail: str) -> MPIError:
         return MPIError(
@@ -767,13 +839,18 @@ class NativeXchg:
             raise self._mismatch(
                 f"sends/recvs {meta}/{rl} != frozen "
                 f"{rnd.sends_meta}/{rnd.recvs}")
-        if self.i == 0 and not self._fire(sends_f):
-            _native_fallbacks.add()
-            from .plan import PlannedXchg
-            dg = PlannedXchg(self.m, plan, self.seq)
-            dg.ts = self.ts
-            self._delegate = dg
-            return dg.exchange(sends, recvs)
+        if self.i == 0:
+            if not self._begin(sends_f):
+                _native_fallbacks.add()
+                from .plan import PlannedXchg
+                dg = PlannedXchg(self.m, plan, self.seq)
+                dg.ts = self.ts
+                self._delegate = dg
+                return dg.exchange(sends, recvs)
+            self._segment()
+        elif self.i in self.np.live:
+            self._supply(sends_f)
+            self._segment()
         got = self._arrivals(self.i)
         self.i += 1
         return got
@@ -786,6 +863,11 @@ class NativeXchg:
         _fallback_copies.add()
         return np.ascontiguousarray(a)
 
+    def _stream(self, sends_f) -> List[np.ndarray]:
+        """One round's send arrays in the blob's stream order."""
+        return [self._contig(a) for p in sorted(sends_f)
+                for a in sends_f[p]]
+
     def _inputs(self, sends_f) -> Optional[List[np.ndarray]]:
         npl = self.np
         out = []
@@ -794,14 +876,10 @@ class NativeXchg:
             if tuple(a.shape) != shape or str(a.dtype) != dt:
                 return None
             out.append(a)
-        flat: List[np.ndarray] = []
-        for p in sorted(sends_f):
-            flat.extend(sends_f[p])
+        flat = self._stream(sends_f)
         if len(flat) != len(npl.r0_specs):
             return None
-        for a, (_p, shape, dt, _nb) in zip(flat, npl.r0_specs):
-            out.append(self._contig(a))
-        return out
+        return out + flat
 
     def _clean_channel(self) -> bool:
         """True when no stashed/early frame could race the C reap."""
@@ -820,22 +898,25 @@ class NativeXchg:
                     return False
         return True
 
-    def _fire(self, sends_f) -> bool:
+    def _check_ft(self) -> None:
+        from ..runtime.wire import _ft
         npl = self.np
-        m = self.m
+        _ft().check_wait(npl.cid, npl.peers, "native plan fire",
+                         epoch0=getattr(self.m.comm, "_ft_epoch0", 0))
+
+    def _begin(self, sends_f) -> bool:
+        """Take the call's locks and arm the fire. False = a veto,
+        nothing held, no byte moved: the call replays in Python."""
+        npl = self.np
         router = npl.router
         inputs = self._inputs(sends_f)
         if inputs is None:
             return False
-        comm = m.comm
-        epoch0 = getattr(comm, "_ft_epoch0", 0)
-        from ..runtime.wire import _ft
-        held: List[threading.Lock] = []
         chan = router._chan_lock("collrx", npl.cid)
         if not chan.acquire(blocking=False):
             return False
-        held.append(chan)
-        fired = False
+        held = self._held = [chan]
+        armed = False
         try:
             for _p, _kind, lk in npl.fire_locks:
                 if not lk.acquire(blocking=False):
@@ -846,8 +927,7 @@ class NativeXchg:
             for _src, (_ring, _lk, rstash) in npl.rx_entries.items():
                 if rstash.get(npl.tag):
                     return False
-            _ft().check_wait(npl.cid, npl.peers, "native plan fire",
-                             epoch0=epoch0)
+            self._check_ft()
             from ..btl import components as _btlc
             base = next(_btlc._xfer_ids)
             for _ in range(npl.xfer_total - 1):
@@ -856,27 +936,66 @@ class NativeXchg:
             px = npl.px
             if px.fire_begin(inputs, base, npl.timeout_ms) != 0:
                 return False
-            fired = True
-            # every veto above withdrew before a byte moved: the span
-            # is the C walk itself, and closes before a delegate's
-            # ``ompi.plan.xchg`` could open
-            with _obs.span(_spans.PLAN_NATIVE_FIRE,
-                           journal=("plan_native_fire", "plan"),
-                           cid=npl.cid, seq=self.seq):
-                self._run(px, npl, epoch0)
-                self._harvest(px, npl)
+            self._px = px
+            self._pool = px.pool_view()
+            armed = True
             return True
         finally:
-            if fired:
-                # the rx entry locks are still held here — the
-                # restash below needs them
-                self._drain_stash(npl)
-            for lk in reversed(held):
-                lk.release()
+            if not armed:
+                self._release()
 
-    def _run(self, px, npl: NativePlan, epoch0: int) -> None:
+    def _supply(self, sends_f) -> None:
+        """The sends of the live round the walk stands before."""
+        px = self._px
+        self._check_ft()
+        if px is None or px.fire_supply(self._stream(sends_f)) != 0:
+            raise self._mismatch(
+                "the native walk does not stand before a live round "
+                "of these sends")
+        _native_live_rounds.add()
+
+    def _segment(self) -> None:
+        """Walk from where the fire stands to the next live round or
+        to the end; the end harvests, drains and releases (a failure
+        leaves that to :meth:`close`). Every veto withdrew before a
+        byte moved, so the span is the C walk alone (one per segment)
+        and closes before a delegate's ``ompi.plan.xchg`` could open."""
+        npl, px = self.np, self._px
+        with _obs.span(_spans.PLAN_NATIVE_FIRE,
+                       journal=("plan_native_fire", "plan"),
+                       cid=npl.cid, seq=self.seq):
+            done = self._run(px, npl)
+            if done:
+                self._harvest(px, npl)
+        if done:
+            self.close()
+
+    def _release(self) -> None:
+        held, self._held = self._held, []
+        for lk in reversed(held):
+            lk.release()
+
+    def close(self) -> None:
+        """End the open fire, finished or not: hand the frames the
+        reap set aside back to the Python stashes (the rx entry locks
+        are still held: the restash needs them), let go of the
+        caller's arrays, release the call's locks. Idempotent, and
+        ``SpanningPlanState.run`` calls it in its ``finally``: a
+        segment that fails, or a schedule body that raises between
+        two, leaks nothing."""
+        px, self._px = self._px, None
+        if px is None:
+            return
+        try:
+            self._drain_stash(px)
+            px.fire_end()
+        finally:
+            self._release()
+
+    def _run(self, px, npl: NativePlan) -> bool:
+        """One segment in C. True = the plan's last round is done,
+        False = paused before a live round."""
         from ..obs import watchdog as _watchdog
-        from ..runtime.wire import _ft
         tok = None
         if _watchdog.enabled:
             tok = _watchdog.arm(
@@ -888,14 +1007,14 @@ class NativeXchg:
             while True:
                 rc = px.fire_step(_SLICE_MS)
                 if rc == px.RC_DONE:
-                    return
+                    return True
+                if rc == px.RC_PAUSE:
+                    return False
                 if rc in (px.RC_AGAIN, px.RC_FTSTOP):
                     # the detection interval: mirror FtState into the
                     # fault word, surface death/revocation typed
                     try:
-                        _ft().check_wait(npl.cid, npl.peers,
-                                         "native plan fire",
-                                         epoch0=epoch0)
+                        self._check_ft()
                     except MPIError:
                         npl.ftword[0] = 1
                         raise
@@ -935,12 +1054,12 @@ class NativeXchg:
         raise MPIError(ErrorCode.ERR_INTERN,
                        f"native plan executor returned rc {rc}")
 
-    def _drain_stash(self, npl: NativePlan) -> None:
+    def _drain_stash(self, px) -> None:
         """Re-inject frames the C reap popped but does not own into
         the shared Python stashes (kind 0 = endpoint frame, kind 1 =
         ring record) — the portable consumers find them exactly where
         the interpreted path would have stashed them."""
-        px = npl.px
+        npl = self.np
         try:
             entries = px.drain_stash()
         except Exception:
@@ -963,7 +1082,6 @@ class NativeXchg:
                                      []).append(raw)
 
     def _harvest(self, px, npl: NativePlan) -> None:
-        self._pool = px.pool_view()
         if self.ts is not None:
             self.ts[:] = px.round_ts()
         # pvar continuity: the C fire IS these sends/recvs — MPI_T

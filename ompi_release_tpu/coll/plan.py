@@ -774,12 +774,13 @@ class SpanningPlanState:
                 return fn(*args, **kw)
         seq = _progress.executing_seq()
         nx = self.native
+        from . import native_exec as _native
         if nx is not None and nx.gen == plan.gen:
-            from . import native_exec as _native
             px = _native.NativeXchg(m, plan, nx, args, seq,
                                     views=self.name in _native.VIEW_OPS)
         else:
             px = PlannedXchg(m, plan, seq)
+            _native._python_replays.add()
         t0 = 0.0
         if rec:
             if plan.ledger_id is None:
@@ -803,6 +804,11 @@ class SpanningPlanState:
             raise
         finally:
             m._xchg = old
+            if isinstance(px, _native.NativeXchg):
+                # a native fire runs in segments with the call's wire
+                # locks held between them: whatever the schedule body
+                # did, they are released here
+                px.close()
             if rec:
                 _active_replays.pop(id(self), None)
         _compiled_hits.observe(1)

@@ -286,6 +286,12 @@ def _declare(lib: ctypes.CDLL) -> None:
         lib.planexec_stash_data.argtypes = [P, ctypes.c_int64]
         lib.planexec_stash_data.restype = P
         lib.planexec_stash_clear.argtypes = [P]
+    if hasattr(lib, "planexec_fire_supply"):
+        lib.planexec_fire_supply.argtypes = [P, vpp, i64p,
+                                             ctypes.c_int64]
+        lib.planexec_fire_supply.restype = ctypes.c_int
+        lib.planexec_fire_end.argtypes = [P]
+        lib.planexec_fire_end.restype = None
     if hasattr(lib, "planexec_ring_yields"):
         lib.planexec_ring_yields.argtypes = [P]
         lib.planexec_ring_yields.restype = ctypes.c_int64
@@ -332,6 +338,7 @@ def planexec_symbols_available() -> bool:
     except Exception:
         return False
     return (hasattr(lib, "planexec_create")
+            and hasattr(lib, "planexec_fire_supply")
             and hasattr(lib, "wire_sendv")
             and hasattr(lib, "shmring_create"))
 
@@ -900,13 +907,15 @@ class PlanExec:
 
     Return codes (native/planexec.cc): 0 done, 1 slice expired
     (call ``fire_step`` again), 2 fault-word stop (run check_wait,
-    then resume), -1 bad call, -2 peer dead (``err_peer`` names the
+    then resume), 3 paused before a live round (``fire_supply`` its
+    sends, then resume), -1 bad call, -2 peer dead (``err_peer`` names the
     pidx), -3 plan timeout, -4 inbound header diverged from the
     frozen expectation, -5 reassembled payload failed CRC."""
 
     RC_DONE = 0
     RC_AGAIN = 1
     RC_FTSTOP = 2
+    RC_PAUSE = 3
     RC_BADARG = -1
     RC_PEERDEAD = -2
     RC_TIMEOUT = -3
@@ -924,6 +933,7 @@ class PlanExec:
             raise MPIError(ErrorCode.ERR_OTHER,
                            "plan descriptor blob rejected")
         self._ftword = None  # keepalive for the fault-word buffer
+        self._fire_keep = None  # ... and for the open fire's arrays
 
     def _handle(self):
         h = self._h
@@ -956,23 +966,48 @@ class PlanExec:
             self._handle(),
             ctypes.cast(word_buf, ctypes.POINTER(ctypes.c_int64)))
 
+    @staticmethod
+    def _regions(arrays):
+        n = len(arrays)
+        ptrs = (ctypes.c_void_p * n)()
+        lens = (ctypes.c_int64 * n)()
+        for i, a in enumerate(arrays):
+            ptrs[i] = ctypes.c_void_p(a.ctypes.data)
+            lens[i] = int(a.nbytes)
+        return ptrs, lens, n
+
     def fire_begin(self, input_arrays, xfer_base: int,
                    timeout_ms: int) -> int:
         """Arm a fire with the round-0 input regions (contiguous
         ndarrays, pointers live until the fire completes)."""
-        n = len(input_arrays)
-        ptrs = (ctypes.c_void_p * n)()
-        lens = (ctypes.c_int64 * n)()
-        for i, a in enumerate(input_arrays):
-            ptrs[i] = ctypes.c_void_p(a.ctypes.data)
-            lens[i] = int(a.nbytes)
-        self._fire_keep = input_arrays
+        ptrs, lens, n = self._regions(input_arrays)
+        self._fire_keep = [input_arrays]
         return int(self._lib.planexec_fire_begin(
             self._handle(), ptrs, lens, n, xfer_base, timeout_ms))
 
     def fire_step(self, slice_ms: int) -> int:
         return int(self._lib.planexec_fire_step(self._handle(),
                                                 slice_ms))
+
+    def fire_supply(self, send_arrays) -> int:
+        """After ``RC_PAUSE``: the paused (live) round's sends, one
+        contiguous ndarray per message in stream order; kept alive
+        with the fire's inputs. 0, or ``RC_BADARG`` when the walk does
+        not stand before a live round of these sizes."""
+        ptrs, lens, n = self._regions(send_arrays)
+        rc = int(self._lib.planexec_fire_supply(
+            self._handle(), ptrs, lens, n))
+        if rc == 0:
+            self._fire_keep.append(send_arrays)
+        return rc
+
+    def fire_end(self) -> None:
+        """The fire is over, walked to its end or left by a schedule
+        that will not finish it: nothing reads the caller's arrays any
+        more."""
+        if self._h:
+            self._lib.planexec_fire_end(self._h)
+        self._fire_keep = None
 
     @property
     def pool_total(self) -> int:

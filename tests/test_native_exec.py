@@ -147,7 +147,7 @@ class _ExecPair:
     PRE, MID = b"SGH2-pre", b"-mid-"
 
     def __init__(self, tmp_name, ring_bytes, nbytes, chunk,
-                 a_sends=True, b_sends=True):
+                 a_sends=True, b_sends=True, blob_of=None):
         from ompi_release_tpu.native.bindings import (
             OobEndpoint, PlanExec, ShmRing)
 
@@ -174,7 +174,7 @@ class _ExecPair:
             rounds = [{"depth": 2,
                        "streams": [(0, [send_msg] if sends else [])],
                        "rsrcs": [(0, [recv_msg] if recvs else [])]}]
-            px = PlanExec(nx.build_blob(
+            px = PlanExec(blob_of(me) if blob_of else nx.build_blob(
                 self.TAG, [nbytes], [nbytes] if recvs else [],
                 [1 - me], rounds))
             px.bind(self.eps[me]._h, me + 1, [2 - me],
@@ -298,6 +298,148 @@ class TestExecutorFullRing:
                 assert rcs[1] == px.RC_TRUNCATED, rcs
         finally:
             pair.close()
+
+
+def _live_pair_blob(me, nbytes, chunk, pre, mid, live=1, segs=None):
+    """Two rounds with the one peer: round 0 sends input 0 and receives
+    into pool 0; round 1 (live) sends what is supplied and receives
+    into pool 1."""
+    nchunks = -(-nbytes // chunk)
+
+    def rnd(is_live, pool_idx, sg):
+        return {"depth": 2, "live": is_live,
+                "streams": [(0, [(pre, mid, nbytes, nchunks, chunk, sg)])],
+                "rsrcs": [(0, [(pool_idx, nbytes, nchunks, chunk, pre,
+                                mid)])]}
+
+    return nx.build_blob(
+        _ExecPair.TAG, [nbytes], [nbytes, nbytes], [1 - me],
+        [rnd(0, 0, ((0, 0, 0, nbytes),)),
+         rnd(live, 1, segs or ((2, 0, 0, nbytes),))])
+
+
+@needs_native
+class TestExecutorLiveRounds:
+    """ISSUE 33: a fire runs in segments. The walk pauses in front of a
+    live round (``RC_PAUSE``), ``fire_supply`` hands it that round's
+    sends, and the same fire — same xfer ids, same slab — walks on."""
+
+    NB, CHUNK = 40_000, 16 << 10
+
+    def _pair(self, name):
+        return _ExecPair(
+            name, 1 << 20, self.NB, self.CHUNK,
+            blob_of=lambda me: _live_pair_blob(
+                me, self.NB, self.CHUNK, _ExecPair.PRE, _ExecPair.MID))
+
+    @staticmethod
+    def _walk(px):
+        rc = px.RC_AGAIN
+        while rc == px.RC_AGAIN:
+            rc = px.fire_step(100)
+        return rc
+
+    def test_pause_supply_and_walk_on(self):
+        import threading
+
+        pair = self._pair("live-ok")
+        try:
+            rng = np.random.default_rng(33)
+            data = [rng.integers(0, 256, self.NB, dtype=np.uint8)
+                    for _ in range(2)]
+            folds, rcs = [None, None], [[], []]
+
+            def rank(me):
+                px = pair.px[me]
+                assert px.fire_begin([data[me]], 100 * (me + 1),
+                                     20_000) == 0
+                rcs[me].append(self._walk(px))      # round 0, then pause
+                # nothing to supply twice, nothing of another size
+                got = np.array(px.pool_view()[:self.NB])
+                folds[me] = data[me] + got          # the schedule's fold
+                rcs[me].append(px.fire_supply([folds[me][:-1]]))
+                rcs[me].append(px.fire_supply([folds[me]]))
+                rcs[me].append(px.fire_supply([folds[me]]))
+                rcs[me].append(self._walk(px))      # round 1, to the end
+
+            ths = [threading.Thread(target=rank, args=(me,))
+                   for me in range(2)]
+            for t in ths:
+                t.start()
+            for t in ths:
+                t.join(60)
+            px = pair.px[0]
+            want = [px.RC_PAUSE, px.RC_BADARG, 0, px.RC_BADARG,
+                    px.RC_DONE]
+            assert rcs == [want, want], rcs
+            off1 = nx._align8(self.NB)
+            for me in range(2):
+                pool = pair.px[me].pool_view()
+                np.testing.assert_array_equal(pool[:self.NB],
+                                              data[1 - me])
+                np.testing.assert_array_equal(
+                    pool[off1:off1 + self.NB], folds[1 - me])
+                # both rounds' end stamps, in order
+                ts = pair.px[me].round_ts()
+                assert 0 < ts[0] <= ts[1]
+                pair.px[me].fire_end()
+        finally:
+            pair.close()
+
+    def test_an_abandoned_fire_leaves_the_executor_usable(self):
+        """A schedule that raises between two segments: ``fire_end``
+        leaves the fire, and the executor's next fire starts clean."""
+        import threading
+
+        pair = self._pair("live-abort")
+        try:
+            data = [np.full(self.NB, 3 + me, np.uint8) for me in range(2)]
+            for attempt in range(2):
+                rcs = [None, None]
+
+                def rank(me):
+                    px = pair.px[me]
+                    assert px.fire_begin([data[me]], 1000 * attempt
+                                         + 100 * (me + 1), 20_000) == 0
+                    rc = self._walk(px)
+                    if attempt == 0:
+                        px.fire_end()   # both sides leave at the pause
+                        assert px.fire_step(100) == px.RC_BADARG
+                        assert px.fire_supply([data[me]]) == px.RC_BADARG
+                    else:
+                        assert px.fire_supply([data[me]]) == 0
+                        rc = self._walk(px)
+                    rcs[me] = rc
+
+                ths = [threading.Thread(target=rank, args=(me,))
+                       for me in range(2)]
+                for t in ths:
+                    t.start()
+                for t in ths:
+                    t.join(60)
+                px = pair.px[0]
+                assert rcs == ([px.RC_PAUSE] * 2 if attempt == 0
+                               else [px.RC_DONE] * 2), rcs
+        finally:
+            pair.close()
+
+    @pytest.mark.parametrize("case", ["mapped_as_live", "live_as_mapped",
+                                      "partial", "wrong_index"])
+    def test_parser_holds_a_live_round_to_its_form(self, case):
+        """A live round's message is ONE supplied array, whole; a
+        mapped round never reads a supplied one."""
+        from ompi_release_tpu.native.bindings import PlanExec
+
+        nb = 64
+        live, segs = {
+            "mapped_as_live": (1, ((0, 0, 0, nb),)),
+            "live_as_mapped": (0, ((2, 0, 0, nb),)),
+            "partial": (1, ((2, 0, 0, nb // 2), (1, 0, 0, nb // 2))),
+            "wrong_index": (1, ((2, 1, 0, nb),)),
+        }[case]
+        with pytest.raises(MPIError):
+            PlanExec(_live_pair_blob(0, nb, nb, b"P", b"M", live, segs))
+        PlanExec(_live_pair_blob(0, nb, nb, b"P", b"M")).close()
 
 
 @needs_native
@@ -612,6 +754,193 @@ class TestMatchPayload:
         arena, a_arr, bounds = _arena_of(r0)
         with pytest.raises(nx._ProbeFail):
             nx._match_payload(r0[:8], arena, a_arr, bounds)
+
+
+# ---------------------------------------------------------------------------
+# 1b'. which rounds are live: found by the probe, not declared (device-free)
+# ---------------------------------------------------------------------------
+
+class _LoopXchg:
+    """One rank's exchange adapter over in-process queues: P threads
+    run one schedule in lockstep, the way P processes do."""
+
+    def __init__(self, queues, me):
+        self.q, self.me = queues, me
+
+    def exchange(self, sends, recvs):
+        for dst in sorted(sends):
+            for a in sends[dst]:
+                self.q[(self.me, dst)].put(np.array(a))
+        return {src: [self.q[(src, self.me)].get(timeout=30)
+                      for _ in range(int(c))]
+                for src, c in recvs.items() if int(c) > 0}
+
+
+class _ProbedModule:
+    """What ``_infer_maps`` needs of a hier module: ``_xchg``."""
+
+    def __init__(self, xchg):
+        self._xchg = xchg
+
+
+def _frozen_probe(schedule, P, me, mine_of, cid=5):
+    """Record ``schedule(x, procs, rank, mine)`` on P threads, freeze
+    rank ``me``'s rounds by hand (no wire: nothing is templated) and
+    return ``_infer_maps``'s arguments for it."""
+    import queue
+    import threading
+
+    procs = list(range(P))
+    qs = {(a, b): queue.Queue() for a in procs for b in procs if a != b}
+    recs = [cplan.RoundRecorder(_LoopXchg(qs, r)) for r in procs]
+    errs = []
+
+    def rank(r):
+        try:
+            schedule(recs[r], procs, r, mine_of(r))
+        except BaseException as e:  # surfaced below, with its rank
+            errs.append((r, e))
+
+    ths = [threading.Thread(target=rank, args=(r,)) for r in procs]
+    for t in ths:
+        t.start()
+    for t in ths:
+        t.join(60)
+    assert not errs, errs
+    rec = recs[me]
+    rounds = [cplan.WireRound(sm, rt, tuple((p, (None,) * len(a))
+                                            for p, a in sm),
+                              9, 2, recvs_meta=meta)
+              for (sm, rt), meta in zip(rec.rounds, rec.recv_metas)]
+    plan = _Plan()
+    plan.rounds, plan.cid = rounds, cid
+    m = _ProbedModule(None)
+    # hier hands a schedule the fetched partial: a read-only array
+    fn = lambda mine: schedule(m._xchg, procs, me, np.array(mine))  # noqa: E731
+    return plan, m, fn, (mine_of(me),), {}, (0,)
+
+
+def _mine(n, dtype):
+    return lambda r: (np.arange(n) * (r + 3) % 251).astype(dtype)
+
+
+class TestLiveRounds:
+    """ISSUE 33, item 1: ``_infer_maps`` marks a round live where a
+    message cannot be covered whole by region bytes, both probes alike;
+    no schedule names, no size test."""
+
+    OPS = pytest.mark.parametrize(
+        "op,dtype", [(np.add, "int32"), (np.maximum, "float32")],
+        ids=["sum", "max"])
+
+    @staticmethod
+    def _reduce(fn, op, dtype):
+        from ompi_release_tpu.coll import hier_schedules as hs
+
+        ident = 0 if op is np.add else -np.inf
+        return lambda x, procs, me, mine: getattr(hs, fn)(
+            x, procs, me, mine, op, np.dtype(dtype).type(ident))
+
+    @OPS
+    @pytest.mark.parametrize("P,me,want", [(2, 0, {1}), (2, 1, {1}),
+                                           (4, 0, {1, 2, 3}),
+                                           (4, 3, {1, 2, 3})])
+    def test_rabenseifner_sends_folds_after_round_0(self, op, dtype, P,
+                                                    me, want):
+        """Halving sends what it has just folded; the last doubling
+        round at four sends a fold beside a slab region: live as a
+        whole."""
+        probe = _frozen_probe(
+            self._reduce("allreduce_rabenseifner", op, dtype), P, me,
+            _mine(4096, dtype))
+        maps, live = nx._infer_maps(*probe)
+        assert len(probe[0].rounds) == 2 * (P.bit_length() - 1)
+        assert live == want
+        assert all((maps[r] is None) == (r == 0 or r in live)
+                   for r in range(len(maps)))
+
+    @OPS
+    @pytest.mark.parametrize("me", [0, 2])
+    def test_ring_at_three_forwards_its_last_arrival(self, op, dtype, me):
+        """Rounds 1 and 2 send folds; round 3 sends on what round 2
+        brought: mapped, from the slab, C's own."""
+        probe = _frozen_probe(self._reduce("allreduce_ring", op, dtype),
+                              3, me, _mine(3 * 1024, dtype))
+        maps, live = nx._infer_maps(*probe)
+        assert len(probe[0].rounds) == 4 and live == {1, 2}
+        (segs,) = maps[3]  # one message, one span: round 2's arrival
+        assert [sg[0] for sg in segs] == [1] and segs[0][2:] == (0, 4096)
+
+    @pytest.mark.parametrize("name", ["bcast", "allgather", "alltoall",
+                                      "alltoall_bruck"])
+    def test_schedules_that_only_move_have_no_live_round(self, name):
+        from ompi_release_tpu.coll import hier_schedules as hs
+
+        P, n = 4, 1024
+        sched = {
+            "bcast": lambda x, procs, me, mine: hs.bcast_binomial(
+                x, procs, me, 0, mine),
+            "allgather": lambda x, procs, me, mine: hs.allgather_bruck(
+                x, procs, me, mine, [n] * P),
+            "alltoall": lambda x, procs, me, mine: hs.alltoall_pairwise(
+                x, procs, me, {p: mine[p * n:(p + 1) * n] for p in procs}),
+            "alltoall_bruck": lambda x, procs, me, mine: hs.alltoall_bruck(
+                x, procs, me, [mine[p * n:(p + 1) * n] for p in procs],
+                [[n] * P] * P),
+        }[name]
+        size = n if name in ("bcast", "allgather") else n * P
+        # rank 2 of a binomial bcast receives, then forwards
+        probe = _frozen_probe(sched, P, 2, _mine(size, "int32"))
+        maps, live = nx._infer_maps(*probe)
+        assert live == frozenset() and len(probe[0].rounds) >= 2
+        assert all(m is not None for m in maps[1:])
+
+    def test_a_short_payload_is_live_not_a_withdrawal(self):
+        """Eight bytes cannot be proven (no 16-byte window): the
+        schedule supplies them, which is always right."""
+        probe = _frozen_probe(
+            self._reduce("allreduce_ring", np.add, "int32"), 3, 1,
+            _mine(6, "int32"))
+        _maps, live = nx._infer_maps(*probe)
+        assert live == {1, 2, 3}
+
+    def test_a_mapped_round_after_a_live_one_reads_only_the_slab(
+            self, monkeypatch):
+        """Between two segments the schedule body runs on and may write
+        to the arrays it holds: a later round mapped onto a caller's
+        array (kind 0) is made live instead."""
+        probe = _frozen_probe(
+            self._reduce("allreduce_ring", np.add, "int32"), 3, 0,
+            _mine(3 * 1024, "int32"))
+        real = nx._match_payload
+
+        def from_an_input(pay, arena, a_arr, bounds):
+            segs = real(pay, arena, a_arr, bounds)
+            return tuple((0, 0, sg[2], sg[3]) if sg[0] == 1 else sg
+                         for sg in segs)
+
+        monkeypatch.setattr(nx, "_match_payload", from_an_input)
+        _maps, live = nx._infer_maps(*probe)
+        assert live == {1, 2, 3}
+
+    def test_probes_that_disagree_on_the_live_set_withdraw(
+            self, monkeypatch):
+        probe = _frozen_probe(
+            self._reduce("allreduce_ring", np.add, "int32"), 3, 0,
+            _mine(3 * 1024, "int32"))
+        real, calls = nx._match_payload, []
+
+        def second_probe_loses_round_3(pay, arena, a_arr, bounds):
+            calls.append(1)
+            if len(calls) == 6:  # three rounds a probe, one message each
+                raise nx._ProbeFail("planted")
+            return real(pay, arena, a_arr, bounds)
+
+        monkeypatch.setattr(nx, "_match_payload",
+                            second_probe_loses_round_3)
+        with pytest.raises(nx._ProbeFail, match="live rounds"):
+            nx._infer_maps(*probe)
+        assert len(calls) == 6
 
 
 # ---------------------------------------------------------------------------
