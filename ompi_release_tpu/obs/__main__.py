@@ -411,8 +411,12 @@ def selftest() -> int:
     rs3 = _osc_plan.epoch_signature(_rma_todo(tgt=0))
     assert rs1 == rs2 and rs1 != rs3, (rs1, rs3)
     todo = _rma_todo()
-    tpl3 = _osc_plan.BatchTemplate(_var.VARS.generation, todo)
-    assert tpl3.render(todo).tobytes() == _pack(todo).tobytes(), (
+    seg = 1 << 20
+    tpl3 = _osc_plan.BatchTemplate(_var.VARS.generation, todo, seg)
+    got3, want3 = tpl3.render(todo), _pack(todo, seg)
+    assert (got3.meta, got3.frames) == (want3.meta, want3.frames) and [
+        a.tobytes() for a in got3.arrays] == [
+        a.tobytes() for a in want3.arrays], (
         "frozen frame template must render byte-identical to "
         "_pack_batch")
     rlid = _ledger.register_rma_plan(9, "epoch[2]", 32, rs1)

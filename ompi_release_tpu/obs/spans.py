@@ -60,6 +60,31 @@ keyword stats of the event, never folded into the name:
                            ``bytes``)
 ``ompi.pml.h2d``           the ``device_put`` of a p2p arrival until it
                            returns (``bytes``; nested in ``p2p_pump``)
+``ompi.osc.sync``          a call that closes or flushes an epoch of a
+                           window on a spanning communicator (``flush``,
+                           ``unlock``, ``fence``, ``complete``), entry to
+                           return (``cid``, ``win``, ``ops``, ``bytes``
+                           of payload queued)
+``ompi.osc.pack``          composing one home's batch: its header and its
+                           payloads on the host (``bytes`` of the frame;
+                           nested in ``sync``)
+``ompi.osc.d2h``           the fetch of the batch's device payloads to
+                           the host (``bytes``; nested in ``pack``)
+``ompi.osc.request``       ``WinService.request``, entry to return
+                           (``kind``, ``peer``, ``bytes`` sent)
+``ompi.osc.reply_wait``    inside it, from the payload sent to the reply
+                           routed to its slot: both wire legs and the
+                           home's turn (``kind``, ``peer``)
+``ompi.osc.unpack``        the read values of a reply off the wire
+                           (``bytes``; runs in whichever thread pumps
+                           the reply channel)
+``ompi.osc.h2d``           placing read values on the origin's device
+                           until it returns (``bytes``)
+``ompi.osc.apply``         at the home, on the service thread: a batch
+                           from its envelope to its reply sent
+                           (``origin``, ``ops``, ``bytes``)
+``ompi.osc.program``       the call of an epoch program, interpreted or
+                           planned, wherever it runs (``ops``)
 
 ``seq`` is the posted schedule's ``ScheduledOp.seq`` (process-local): a
 schedule may run on another thread than its ``ompi.coll.call``
@@ -90,11 +115,21 @@ WIRE_P2P_SEND = "ompi.wire.p2p_send"
 PML_RECV_WAIT = "ompi.pml.recv_wait"
 WIRE_P2P_PUMP = "ompi.wire.p2p_pump"
 PML_H2D = "ompi.pml.h2d"
+OSC_SYNC = "ompi.osc.sync"
+OSC_PACK = "ompi.osc.pack"
+OSC_D2H = "ompi.osc.d2h"
+OSC_REQUEST = "ompi.osc.request"
+OSC_REPLY_WAIT = "ompi.osc.reply_wait"
+OSC_UNPACK = "ompi.osc.unpack"
+OSC_H2D = "ompi.osc.h2d"
+OSC_APPLY = "ompi.osc.apply"
+OSC_PROGRAM = "ompi.osc.program"
 
 NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
          PLAN_NATIVE_FIRE, PLAN_XCHG, HIER_D2H, HIER_H2D, WIRE_STASH,
          PML_SEND, PML_D2H, WIRE_P2P_SEND, PML_RECV_WAIT, WIRE_P2P_PUMP,
-         PML_H2D, HIER_ASSEMBLE)
+         PML_H2D, HIER_ASSEMBLE, OSC_SYNC, OSC_PACK, OSC_D2H, OSC_REQUEST,
+         OSC_REPLY_WAIT, OSC_UNPACK, OSC_H2D, OSC_APPLY, OSC_PROGRAM)
 
 #: ``jax.profiler.TraceAnnotation`` and the ``obs`` package, bound on
 #: the first span: importing ``obs`` must not import jax (``obs

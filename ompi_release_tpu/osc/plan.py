@@ -11,7 +11,7 @@ and over, so this module freezes per-(window, epoch-signature)
 
 - the signature is the epoch's op sequence as hashable metadata —
   (kind, target, payload shape/dtype, the frozen Op OBJECT, index,
-  read-request flag) per op — derived with the same descriptor rules
+  read-request flag, displacement and count of a ranged op) per op — derived with the same descriptor rules
   ``coll/plan`` uses (``arg_desc``), so a same-named user op can never
   alias a predefined op's program;
 - a plan holds ONE fused XLA program for the epoch's local/device
@@ -21,10 +21,10 @@ and over, so this module freezes per-(window, epoch-signature)
   target/index arrays), reusing ``Window._branch_fn`` so planned and
   interpreted closes are BITWISE identical;
 - for the remote side, :class:`BatchTemplate` precomposes the wire
-  request record (the per-op meta JSON) at freeze time and re-renders
-  only the payload arrays, byte-identical to ``_pack_batch`` output —
-  ``WinService``, the sentinel, and tpu-doctor are unchanged on the
-  wire;
+  request records (the per-op meta JSON with displacement and count,
+  the payload descriptors, the payloads per frame) at freeze time and
+  fetches only the payload arrays, identical to ``_pack_batch`` output
+  — ``WinService`` sees no difference on the wire;
 - plans are generation-stamped against the MCA write generation
   exactly like ``SchedulePlan``: any cvar write re-plans at the next
   epoch close. The first close of a new signature runs the
@@ -40,9 +40,8 @@ window must not pin fused programs. Callers hold the window's
 
 from __future__ import annotations
 
-import json
 import time as _time
-from typing import Any, List, Optional, Tuple
+from typing import List, Optional, Tuple
 
 import numpy as np
 
@@ -51,7 +50,7 @@ from ..coll.plan import arg_desc
 from ..mca import pvar
 from ..mca import var as mca_var
 from ..obs import ledger as _ledger
-from ..request.request import Status
+from ..obs import spans as _spans
 from ..utils import output
 
 _log = output.stream("osc")
@@ -156,6 +155,8 @@ def epoch_signature(todo: List) -> Optional[Tuple]:
             -1 if p.index is None else int(p.index),
             p.request is not None,
             -1 if p.status_rank is None else int(p.status_rank),
+            -1 if p.disp is None else int(p.disp),
+            -1 if p.count is None else int(p.count),
         ))
     return tuple(sig)
 
@@ -167,11 +168,12 @@ def epoch_signature(todo: List) -> Optional[Tuple]:
 class EpochPlan:
     """One frozen access plan: the epoch's op metadata baked into an
     unrolled fused program over the window state. ``steps`` holds per
-    op (kind, target, has_data, has_compare, index, op, status_rank,
-    has_request) — everything but the payload bytes, which arrive as
+    op (kind, target, has_data, has_compare, index, op, has_request,
+    disp, count) — everything but the payload bytes, which arrive as
     program arguments at replay."""
 
-    __slots__ = ("gen", "sig", "steps", "prog", "nbytes", "lid")
+    __slots__ = ("gen", "sig", "steps", "prog", "nbytes", "lid",
+                 "writes")
 
     def __init__(self, gen: int, sig: Tuple, todo: List) -> None:
         self.gen = gen
@@ -180,9 +182,12 @@ class EpochPlan:
             (p.kind, int(p.target), p.data is not None,
              p.compare is not None,
              -1 if p.index is None else int(p.index), p.op,
-             p.status_rank, p.request is not None)
+             p.request is not None,
+             -1 if p.disp is None else int(p.disp), p.count)
             for p in todo
         )
+        # an epoch of gets alone hands no window back
+        self.writes = any(p.kind != "get" for p in todo)
         self.prog = None  # compiled lazily at first replay
         self.nbytes = sum(
             int(getattr(p.data, "nbytes", 0) or 0)
@@ -193,56 +198,76 @@ class EpochPlan:
         #                                 observed fire
 
     def _build(self, win):
-        """Compile the fused program: targets/kinds/indices are Python
-        constants, payloads are arguments, each op reuses the SAME
-        branch lambda the interpreted ``lax.scan`` program dispatches
-        through — so replays are bitwise-identical to captures."""
+        """Compile the fused program: targets/kinds/indices/ranges are
+        Python constants, payloads are arguments, each op reuses the
+        SAME branch lambda the interpreted ``lax.scan`` programs
+        dispatch through — so replays are bitwise-identical to
+        captures. A ranged step slices its block out of the flattened
+        window and writes it back in place: its payload, its compare
+        and its read have the block's size, never the slot's."""
         import jax
         import jax.numpy as jnp
+        from jax import lax
 
         from .window import Window
 
         dtype = win._data.dtype
         block = win.shape
         steps = self.steps
+        writes = self.writes
         fns = []
-        for (kind, _t, _hd, _hc, index, op, _sr, _hr) in steps:
+        for (kind, _t, _hd, _hc, index, op, _hr, _d, _c) in steps:
             bkind = "acc" if kind in ("acc", "get_acc") else kind
             fns.append(Window._branch_fn((bkind, op, index >= 0), op))
 
         def fused(data, *bufs):
-            zeros = jnp.zeros(block, dtype)
+            flat = data.reshape(data.shape[0], -1)
             reads = []
             bi = 0
-            for fn, (kind, tgt, has_d, has_c, idx, op, _sr, has_r) in zip(
-                    fns, steps):
+            for fn, (kind, tgt, has_d, has_c, idx, op, has_r, disp,
+                     count) in zip(fns, steps):
+                shape = block if disp < 0 else (count,)
+
+                def arg(x, shape=shape, ranged=disp >= 0):
+                    x = jnp.asarray(x).astype(dtype)
+                    return jnp.broadcast_to(
+                        x.reshape(-1) if ranged else x, shape)
+
+                pay = cmp = None
                 if has_d:
-                    pay = jnp.broadcast_to(
-                        jnp.asarray(bufs[bi]).astype(dtype), block)
+                    pay = arg(bufs[bi])
                     bi += 1
-                else:
-                    pay = zeros
                 if has_c:
-                    cmp = jnp.broadcast_to(
-                        jnp.asarray(bufs[bi]).astype(dtype), block)
+                    cmp = arg(bufs[bi])
                     bi += 1
+                if disp < 0:
+                    cur = flat[tgt].reshape(block)
+                    zeros = jnp.zeros(block, dtype)
+                    new, read = fn(cur, zeros if pay is None else pay,
+                                   zeros if cmp is None else cmp,
+                                   max(idx, 0))
+                    if writes:
+                        flat = flat.at[tgt].set(new.reshape(-1))
                 else:
-                    cmp = zeros
-                new, read = fn(data[tgt], pay, cmp, max(idx, 0))
-                data = data.at[tgt].set(new)
+                    cur = lax.dynamic_slice(flat, (tgt, disp),
+                                            (1, count))[0]
+                    new, read = fn(cur, pay, cmp, 0)
+                    if writes and kind != "get":
+                        flat = lax.dynamic_update_slice(
+                            flat, new[None], (tgt, disp))
                 if has_r:
                     reads.append(read)
-            return data, (jnp.stack(reads) if reads else None)
+            return (flat.reshape(data.shape) if writes else None,
+                    tuple(reads))
 
         _plan_programs.add()
         return jax.jit(fused)
 
-    def replay(self, win, todo: List, t0: float) -> None:
-        """Fire the fused program for one epoch close and complete its
-        read requests. Caller holds ``win._op_lock``; raises on any
-        divergence (the caller drops the plan)."""
-        import jax.numpy as jnp
-
+    def replay(self, win, todo: List, t0: float) -> List:
+        """Fire the fused program for one epoch close and return the
+        pre-op values the epoch asked for, as host arrays in op order.
+        Caller holds ``win._op_lock``; raises on any divergence (the
+        caller drops the plan)."""
         from .window import _dispatch_lock, _epoch_dispatches
 
         prog = self.prog
@@ -257,44 +282,40 @@ class EpochPlan:
         _orch.add(_time.perf_counter() - t0)
         with _dispatch_lock:
             _epoch_dispatches.add()
-            new_data, reads = prog(win._data, *args)
-        # read completion mirrors the interpreted path: ONE host copy
+            with _obs.span(_spans.OSC_PROGRAM, ops=len(todo)):
+                new_data, reads = prog(win._data, *args)
+        # read completion mirrors the interpreted path: host copies
         # outside _dispatch_lock (per-shard fetches, not a program —
         # the rendezvous-deadlock rule in window.py)
-        reads_np = None
-        ri = 0
-        for p in todo:
-            if p.request is not None:
-                if reads_np is None:
-                    reads_np = np.asarray(reads)
-                value = reads_np[ri]
-                ri += 1
-                if p.index is not None:
-                    value = value.reshape(-1)[p.index]
-                src = (p.target if p.status_rank is None
-                       else p.status_rank)
-                p.request.complete(value=jnp.asarray(value),
-                                   status=Status(source=src))
-        win._data = new_data
+        out = []
+        wants = (p for p in todo if p.request is not None)
+        for p, r in zip(wants, reads):
+            value = np.asarray(r)
+            if p.index is not None:
+                value = value.reshape(-1)[p.index]
+            out.append(value)
+        if self.writes:
+            win._data = new_data
+        return out
 
 
-def close_epoch(win, todo: List, t0: float) -> bool:
-    """Close one epoch through the access-plan cache. True = a frozen
-    plan replayed (requests completed, ``win._data`` rebound); False =
-    the caller must run the interpreted epoch program — either plans
-    are off/unplannable, or this close is the capturing run of a
-    freshly frozen plan."""
+def close_epoch(win, todo: List, t0: float) -> Optional[List]:
+    """Close one epoch through the access-plan cache. A list (the
+    epoch's read values, host arrays in op order) = a frozen plan
+    replayed and ``win._data`` is rebound; None = the caller must run
+    the interpreted epoch programs — either plans are off/unplannable,
+    or this close is the capturing run of a freshly frozen plan."""
     gen, enabled, max_ops = _refresh_conf()
     if not enabled or not todo or len(todo) > max_ops:
-        return False
+        return None
     sig = epoch_signature(todo)
     if sig is None:
-        return False
+        return None
     plans = win._access_plans
     plan = plans.get(sig)
     if plan is not None and plan.gen == gen:
         try:
-            plan.replay(win, todo, t0)
+            reads = plan.replay(win, todo, t0)
         except Exception as e:
             # divergence: drop the plan LOUDLY and re-record at the
             # next close; this close falls back to the interpreted
@@ -303,7 +324,7 @@ def close_epoch(win, todo: List, t0: float) -> bool:
             _log.verbose(
                 1, f"dropping diverged RMA access plan on {win.name}: "
                    f"{type(e).__name__}: {e}; re-recording")
-            return False
+            return None
         _plan_hits.observe(1)
         if _obs.enabled:
             t1 = _time.perf_counter()
@@ -316,13 +337,13 @@ def close_epoch(win, todo: List, t0: float) -> bool:
                                 t0, t1)
             _obs.record("rma_epoch_replay", "osc", t0, t1 - t0,
                         nbytes=plan.nbytes, comm_id=win.comm.cid)
-        return True
+        return reads
     # first sight (or stale generation): freeze now, capture via the
     # interpreted program this close
     plans[sig] = EpochPlan(gen, sig, todo)
     _plans_frozen.add()
     _plan_hits.observe(0)
-    return False
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -330,54 +351,42 @@ def close_epoch(win, todo: List, t0: float) -> bool:
 # ---------------------------------------------------------------------------
 
 class BatchTemplate:
-    """Precomposed wire frame for one remote-batch signature: the
-    per-op request records (the meta JSON ``_pack_batch`` builds per
-    call) are composed ONCE at freeze time; :meth:`render` re-packs
-    only the payload arrays through the same deterministic writer, so
-    the frame is byte-identical to ``_pack_batch`` output —
-    ``WinService``, the wire sentinel, and tpu-doctor flows are
-    unchanged on the wire."""
+    """Precomposed wire header for one remote-batch signature: the
+    per-op request records with displacement and count, the payload
+    descriptors and how the payloads fall into wire frames (what
+    ``_pack_batch`` derives per call from shapes alone) are composed
+    ONCE at freeze time; :meth:`render` fetches only the payloads, so
+    the batch is identical to ``_pack_batch``'s — ``WinService`` sees
+    no difference on the wire."""
 
-    __slots__ = ("gen", "meta_arr", "picks")
+    __slots__ = ("gen", "header")
 
-    def __init__(self, gen: int, todo: List) -> None:
-        from .wire_win import _batch_meta
+    def __init__(self, gen: int, todo: List, seg: int) -> None:
+        from .wire_win import _batch_header
 
         self.gen = gen
-        self.meta_arr = np.frombuffer(
-            json.dumps(_batch_meta(todo)).encode(), dtype=np.uint8
-        ).copy()
-        self.picks = tuple(
-            (i, p.data is not None, p.compare is not None)
-            for i, p in enumerate(todo)
-        )
+        self.header = _batch_header(todo, seg)
 
-    def render(self, todo: List) -> np.ndarray:
-        from .wire_win import _savez_bytes
+    def render(self, todo: List):
+        from .wire_win import _pack_batch
 
-        arrays = {}
-        for i, has_d, has_c in self.picks:
-            p = todo[i]
-            if has_d:
-                arrays[f"d{i}"] = np.asarray(p.data)
-            if has_c:
-                arrays[f"c{i}"] = np.asarray(p.compare)
-        arrays["meta"] = self.meta_arr
-        return np.frombuffer(_savez_bytes(arrays), dtype=np.uint8).copy()
+        return _pack_batch(todo, 0, self.header)
 
 
-def batch_payload(win, todo: List) -> np.ndarray:
-    """Serialize one remote batch: replay the signature's frozen
-    :class:`BatchTemplate` in steady state, else pack interpreted and
-    freeze. Output bytes are identical either way."""
+def batch_payload(win, todo: List):
+    """One remote batch as it goes on the wire (``wire_win.Batch``):
+    replay the signature's frozen :class:`BatchTemplate` in steady
+    state, else pack interpreted and freeze. The batch is identical
+    either way."""
     from .wire_win import _pack_batch
 
+    seg = win.service.tuning().segment
     gen, enabled, max_ops = _refresh_conf()
     if not enabled or len(todo) > max_ops:
-        return _pack_batch(todo)
+        return _pack_batch(todo, seg)
     sig = epoch_signature(todo)
     if sig is None:
-        return _pack_batch(todo)
+        return _pack_batch(todo, seg)
     tpls = win._batch_templates
     tpl = tpls.get(sig)
     if tpl is not None and tpl.gen == gen:
@@ -385,8 +394,8 @@ def batch_payload(win, todo: List) -> np.ndarray:
         return tpl.render(todo)
     # the interpreted pack runs first: it owns the predefined-op
     # validation, so an unshippable batch raises before any freeze
-    payload = _pack_batch(todo)
-    tpls[sig] = BatchTemplate(gen, todo)
+    payload = _pack_batch(todo, seg)
+    tpls[sig] = BatchTemplate(gen, todo, seg)
     _templates_frozen.add()
     _plan_hits.observe(0)
     return payload

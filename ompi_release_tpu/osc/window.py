@@ -22,6 +22,8 @@ outside any epoch raises; fence/lock/PSCW cannot be mixed.
 from __future__ import annotations
 
 import enum
+import itertools
+import math
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -30,7 +32,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import obs as _obs
 from ..mca import pvar
+from ..obs import spans as _spans
 from ..ops.op import Op, REPLACE, SUM
 from ..request.request import Request, Status
 from ..utils import output
@@ -76,10 +80,11 @@ class _EpochKind(enum.Enum):
 
 class _PendingOp:
     __slots__ = ("kind", "target", "data", "op", "request", "compare",
-                 "index", "status_rank")
+                 "index", "status_rank", "disp", "count")
 
     def __init__(self, kind, target, data=None, op=None, request=None,
-                 compare=None, index=None, status_rank=None) -> None:
+                 compare=None, index=None, status_rank=None,
+                 disp=None, count=None) -> None:
         self.kind = kind
         self.target = target
         self.data = data
@@ -89,6 +94,11 @@ class _PendingOp:
         # flat element offset within the target slot (MPI target_disp
         # for single-element ops); None = whole-slot operation
         self.index = index
+        # a RANGED operation: ``count`` consecutive elements of the
+        # target slot (flattened in C order) from flat offset ``disp``
+        # (MPI target_disp + target_count); None = not ranged
+        self.disp = disp
+        self.count = count
         # the COMM rank to report in the request's Status when target
         # has been remapped to a storage row (spanning windows)
         self.status_rank = status_rank
@@ -402,6 +412,20 @@ class Window:
             return jax.device_put(x, NamedSharding(self._shard.mesh, P()))
         return x
 
+    def _slot_elems(self) -> int:
+        return math.prod(self.shape)
+
+    def _check_range(self, disp: int, count: int) -> None:
+        """``count`` elements from flat offset ``disp`` lie inside a
+        slot, or ``ERR_RMA_RANGE``."""
+        slot_elems = self._slot_elems()
+        if disp < 0 or count < 1 or disp + count > slot_elems:
+            raise MPIError(
+                ErrorCode.ERR_RMA_RANGE,
+                f"RMA range [{disp}, {disp + count}) leaves the slot of "
+                f"{slot_elems} elements",
+            )
+
     def _queue(self, op: _PendingOp) -> Optional[Request]:
         self._require(_EpochKind.FENCE, _EpochKind.LOCK, _EpochKind.PSCW)
         if (self._epoch is _EpochKind.LOCK
@@ -412,9 +436,7 @@ class Window:
             raise MPIError(ErrorCode.ERR_RANK,
                            f"RMA target {op.target} out of range")
         if op.index is not None:
-            slot_elems = 1
-            for d in self.shape:
-                slot_elems *= d
+            slot_elems = self._slot_elems()
             if not 0 <= op.index < slot_elems:
                 raise MPIError(
                     ErrorCode.ERR_ARG,
@@ -425,6 +447,41 @@ class Window:
         with self._op_lock:
             self._pending.append(op)
         return op.request
+
+    def _op(self, kind, target, data=None, op=None, request=None,
+            compare=None, index=None, disp=None, count=None
+            ) -> Optional[Request]:
+        """Queue one operation: on the whole slot, on one element
+        (``index``) or on ``count`` consecutive elements of the slot
+        (flattened in C order) from flat offset ``disp`` — the
+        payload's element count when it has one, ``count`` for a get.
+        A range that leaves the slot is refused HERE, at the call
+        site, before anything is queued."""
+        if disp is None:
+            if count is not None:
+                raise MPIError(ErrorCode.ERR_ARG,
+                               "count= needs disp= (a ranged get)")
+            return self._queue(_PendingOp(kind, target, data, op, request,
+                                          compare, index=index))
+        if index is not None:
+            raise MPIError(ErrorCode.ERR_ARG,
+                           "index= (one element) and disp= (a range) "
+                           "exclude each other")
+        if data is not None:
+            count = int(data.size)
+        elif count is None:
+            raise MPIError(ErrorCode.ERR_ARG,
+                           "a ranged get needs count= beside disp=")
+        disp, count = int(disp), int(count)
+        self._check_range(disp, count)
+        if compare is not None and int(compare.size) not in (1, count):
+            raise MPIError(
+                ErrorCode.ERR_ARG,
+                f"ranged compare_and_swap: {compare.size} compare "
+                f"values for {count} elements",
+            )
+        return self._queue(_PendingOp(kind, target, data, op, request,
+                                      compare, disp=disp, count=count))
 
     def _rma_request(self, target: int) -> Request:
         """A Request completable by ``wait()`` ALONE: its block_fn
@@ -440,77 +497,91 @@ class Window:
             block_fn=lambda: self._apply_pending(only_target=target)
         )
 
-    def put(self, data, target: int, index: Optional[int] = None) -> None:
+    def put(self, data, target: int, index: Optional[int] = None,
+            disp: Optional[int] = None) -> None:
         """Put a whole slot, or (``index`` given) a single element at a
-        flat offset within the slot (MPI target_disp addressing)."""
-        self._queue(_PendingOp("put", target, jnp.asarray(data), REPLACE,
-                               index=index))
+        flat offset within the slot, or (``disp`` given) ``data.size``
+        consecutive elements from that flat offset (MPI target_disp
+        addressing)."""
+        self._op("put", target, jnp.asarray(data), REPLACE, index=index,
+                 disp=disp)
 
-    def get(self, target: int) -> Request:
+    def get(self, target: int, disp: Optional[int] = None,
+            count: Optional[int] = None) -> Request:
+        """The whole slot, or (``disp``, ``count``) that many
+        consecutive elements from the flat offset, 1-D, as they were
+        when the operation applied."""
         req = self._rma_request(target)
-        self._queue(_PendingOp("get", target, request=req))
+        self._op("get", target, request=req, disp=disp, count=count)
         return req
 
     def accumulate(self, data, target: int, op: Op = SUM,
-                   index: Optional[int] = None) -> None:
-        self._queue(_PendingOp("acc", target, jnp.asarray(data), op,
-                               index=index))
+                   index: Optional[int] = None,
+                   disp: Optional[int] = None) -> None:
+        self._op("acc", target, jnp.asarray(data), op, index=index,
+                 disp=disp)
 
     def get_accumulate(self, data, target: int, op: Op = SUM,
-                       index: Optional[int] = None) -> Request:
+                       index: Optional[int] = None,
+                       disp: Optional[int] = None) -> Request:
         req = self._rma_request(target)
-        self._queue(
-            _PendingOp("get_acc", target, jnp.asarray(data), op, req,
-                       index=index)
-        )
+        self._op("get_acc", target, jnp.asarray(data), op, req,
+                 index=index, disp=disp)
         return req
 
     def fetch_and_op(self, value, target: int, op: Op = SUM,
-                     index: Optional[int] = None) -> Request:
+                     index: Optional[int] = None,
+                     disp: Optional[int] = None) -> Request:
         """MPI_Fetch_and_op: single element when ``index`` is given
         (the MPI call is defined on ONE element at target_disp —
         ``osc.h:310``); whole-slot elementwise otherwise."""
-        return self.get_accumulate(value, target, op, index=index)
+        return self.get_accumulate(value, target, op, index=index,
+                                   disp=disp)
 
     # -- request-based RMA (MPI-3 MPI_Rput/Rget/Raccumulate) ---------------
     # Each returns a Request completable INSIDE the epoch (wait =
     # per-op flush semantics, osc.h:341-366). get/get_accumulate are
     # already request-based; the R-forms of put/accumulate attach a
     # request that completes when the op applies (epoch close or
-    # flush), carrying the pre-op slice like the reference's
-    # origin-completion semantics allow.
-    def rput(self, data, target: int,
-             index: Optional[int] = None) -> Request:
+    # flush), carrying the pre-op slice (the pre-op BLOCK of a ranged
+    # operation) like the reference's origin-completion semantics
+    # allow.
+    def rput(self, data, target: int, index: Optional[int] = None,
+             disp: Optional[int] = None) -> Request:
         req = self._rma_request(target)
-        self._queue(_PendingOp("put", target, jnp.asarray(data), REPLACE,
-                               request=req, index=index))
+        self._op("put", target, jnp.asarray(data), REPLACE, req,
+                 index=index, disp=disp)
         return req
 
     def raccumulate(self, data, target: int, op: Op = SUM,
-                    index: Optional[int] = None) -> Request:
+                    index: Optional[int] = None,
+                    disp: Optional[int] = None) -> Request:
         req = self._rma_request(target)
-        self._queue(_PendingOp("acc", target, jnp.asarray(data), op,
-                               request=req, index=index))
+        self._op("acc", target, jnp.asarray(data), op, req, index=index,
+                 disp=disp)
         return req
 
-    def rget(self, target: int) -> Request:
-        return self.get(target)
+    def rget(self, target: int, disp: Optional[int] = None,
+             count: Optional[int] = None) -> Request:
+        return self.get(target, disp=disp, count=count)
 
     def rget_accumulate(self, data, target: int, op: Op = SUM,
-                        index: Optional[int] = None) -> Request:
-        return self.get_accumulate(data, target, op, index=index)
+                        index: Optional[int] = None,
+                        disp: Optional[int] = None) -> Request:
+        return self.get_accumulate(data, target, op, index=index,
+                                   disp=disp)
 
     def compare_and_swap(self, value, compare, target: int,
-                         index: Optional[int] = None) -> Request:
+                         index: Optional[int] = None,
+                         disp: Optional[int] = None) -> Request:
         """MPI_Compare_and_swap. With ``index``, true single-element
-        CAS at a flat offset (MPI semantics, ``osc.h:324``); without,
-        an elementwise CAS over the whole slot (a documented
-        whole-block extension)."""
+        CAS at a flat offset (MPI semantics, ``osc.h:324``); with
+        ``disp``, elementwise over ``value.size`` elements from that
+        offset; with neither, an elementwise CAS over the whole slot
+        (a documented whole-block extension)."""
         req = self._rma_request(target)
-        self._queue(
-            _PendingOp("cas", target, jnp.asarray(value), None, req,
-                       compare=jnp.asarray(compare), index=index)
-        )
+        self._op("cas", target, jnp.asarray(value), None, req,
+                 compare=jnp.asarray(compare), index=index, disp=disp)
         return req
 
     # -- application -------------------------------------------------------
@@ -616,122 +687,80 @@ class Window:
         todo = self._take_pending(only_target)
         if not todo:
             return
-        t0 = time.perf_counter()
-        from . import plan as _osc_plan
-
-        # a repeated epoch replays its frozen access plan (one fused
-        # program, no per-close branch dispatch); the first close of a
-        # new signature captures through the interpreted program below
-        if not _osc_plan.close_epoch(self, todo, t0):
-            self._run_epoch_program(todo, _t0=t0)
+        self._run_epoch_program(todo, _t0=time.perf_counter())
 
     def _run_epoch_program(self, todo: List[_PendingOp],
                            _t0: Optional[float] = None) -> None:
-        """Apply ``todo`` (targets = storage row indices) as one
-        compiled program and complete its read requests. Callers hold
-        ``_op_lock``. ``_t0`` (close-entry clock) feeds the shared
-        orchestration timer so the interpreted and planned paths are
+        """Apply ``todo`` (targets = storage row indices) and complete
+        its read requests. Callers hold ``_op_lock``."""
+        if todo:
+            self._complete_reads(todo, self._close(todo, _t0))
+
+    def _close(self, todo: List[_PendingOp], t0: Optional[float] = None
+               ) -> List[Any]:
+        """Apply ``todo`` and return, as host arrays in op order, the
+        pre-op value of every operation that asked for one. A repeated
+        epoch replays its frozen access plan (one fused program, no
+        per-close branch dispatch); the first close of a new signature
+        captures through the interpreted programs. ``t0`` (close-entry
+        clock) feeds the shared orchestration timer so both paths are
         measured over identical spans."""
-        if not todo:
+        from . import plan as _osc_plan
+
+        if t0 is None:
+            t0 = time.perf_counter()
+        reads = _osc_plan.close_epoch(self, todo, t0)
+        return self._interpret(todo, t0) if reads is None else reads
+
+    @staticmethod
+    def _complete_reads(todo: List[_PendingOp], reads: List[Any]) -> None:
+        """Hand each read value to its request, as an array on this
+        process's device (``ompi.osc.h2d`` where a profiler listens)."""
+        want = [p for p in todo if p.request is not None]
+        if not want:
             return
-        from jax import lax
+        with _obs.span(_spans.OSC_H2D,
+                       bytes=sum(int(v.nbytes) for v in reads)):
+            for p, v in zip(want, reads):
+                src = (p.target if p.status_rank is None
+                       else p.status_rank)
+                p.request.complete(value=jnp.asarray(v),
+                                   status=Status(source=src))
 
-        dtype = self._data.dtype
-        block = self.shape
+    def _interpret(self, todo: List[_PendingOp], t0: float) -> List[Any]:
+        """The interpreted close: consecutive whole-slot and
+        single-element operations run as one scan over slots, each run
+        of ranged operations of one count as one scan over blocks of
+        that count — so what a run stages and returns follows what it
+        moves, not the slot's size. Submission order is kept across
+        the runs."""
+        from . import plan as _osc_plan
 
-        # Scalar-payload epochs (the common AMO pattern: many scalar
-        # accumulates/CAS on a large window) keep payloads as (n,)
-        # scalars — broadcast happens INSIDE the kernel, so host-side
-        # staging is n scalars, not n x slot bytes.
-        scalar_mode = all(
-            (p.data is None or jnp.ndim(p.data) == 0)
-            and (p.compare is None or jnp.ndim(p.compare) == 0)
-            for p in todo
-        ) and block != ()
+        reads: List[Any] = []
+        for (ranged, _count), run in itertools.groupby(
+                todo, key=lambda p: (p.disp is not None, p.count)):
+            run = list(run)
+            staged = (self._stage_ranged(run) if ranged
+                      else self._stage_slots(run))
+            if t0 is not None:
+                _osc_plan.orch_add(time.perf_counter() - t0)
+                t0 = None
+            reads.extend(self._fire(run, *staged))
+        return reads
 
-        branch_keys: List[Tuple[str, Any, bool]] = []
-        branch_fns = []
-        codes: List[int] = []
-        for p in todo:
-            k = self._branch_key(p)
-            if k not in branch_keys:
-                branch_keys.append(k)
-                branch_fns.append(self._branch_fn(k, p.op))
-            codes.append(branch_keys.index(k))
-
-        # Pad the op count to the next power of two with no-op entries
-        # so the program cache holds O(log n) programs per branch set
-        # instead of one per distinct epoch length. The noop branch is
-        # ALWAYS part of the branch set so padded and exact-power-of-two
-        # epochs share one program.
-        n = len(todo)
-        n_pad = 1 << (n - 1).bit_length() if n > 1 else 1
-        noop_key = ("noop", "", False)
-        if noop_key not in branch_keys:
-            branch_keys.append(noop_key)
-            branch_fns.append(self._branch_fn(noop_key, None))
-        codes.extend([branch_keys.index(noop_key)] * (n_pad - n))
-
-        pay_shape = () if scalar_mode else block
-        zeros = jnp.zeros(pay_shape, dtype)  # shared by all pad slots
-
-        def pay(x):
-            if x is None:
-                return zeros
-            return jnp.broadcast_to(
-                jnp.asarray(self._origin(x)).astype(dtype), pay_shape)
-
-        codes_a = jnp.asarray(codes, jnp.int32)
-        targets_a = jnp.asarray(
-            [p.target for p in todo] + [0] * (n_pad - n), jnp.int32
-        )
-        zero_pad = [None] * (n_pad - n)
-        payloads = jnp.stack([pay(p.data) for p in todo]
-                             + [pay(x) for x in zero_pad])
-        compares = jnp.stack([pay(p.compare) for p in todo]
-                             + [pay(x) for x in zero_pad])
-        indices = jnp.asarray(
-            [p.index if p.index is not None else 0 for p in todo]
-            + [0] * (n_pad - n), jnp.int32
-        )
-
-        sig = (n_pad, block, str(dtype), tuple(branch_keys), scalar_mode)
-        if _t0 is not None:
-            from . import plan as _osc_plan
-
-            _osc_plan.orch_add(time.perf_counter() - _t0)
+    def _fire(self, run: List[_PendingOp], sig: Tuple, build, args,
+              writes: bool = True) -> List[Any]:
+        """One interpreted epoch program: looked up (or built) and
+        called under the process-wide dispatch lock, its reads fetched
+        as ONE host copy outside it."""
         with _dispatch_lock:
             prog = _program_cache.get(sig)
             if prog is None:
                 _epoch_programs.add()
-
-                def close_epoch(data, codes, targets, payloads,
-                                compares, indices):
-                    def step(data, xs):
-                        code, tgt, payv, cmpv, idx = xs
-                        cur = lax.dynamic_index_in_dim(
-                            data, tgt, 0, keepdims=False
-                        )
-                        new, read = lax.switch(
-                            code, branch_fns, cur, payv, cmpv, idx
-                        )
-                        data = lax.dynamic_update_index_in_dim(
-                            data, new, tgt, 0
-                        )
-                        return data, read
-
-                    return lax.scan(
-                        step, data,
-                        (codes, targets, payloads, compares, indices)
-                    )
-
-                prog = jax.jit(close_epoch)
-                _program_cache[sig] = prog
+                prog = _program_cache[sig] = jax.jit(build())
             _epoch_dispatches.add()
-            new_data, reads = prog(
-                self._data, codes_a, targets_a, payloads, compares,
-                indices
-            )
+            with _obs.span(_spans.OSC_PROGRAM, ops=len(run)):
+                new_data, reads = prog(self._data, *args)
         # Complete read requests from ONE host copy of the outputs.
         # ``reads[i]`` on the sharded program output would dispatch an
         # eager multi-device gather OUTSIDE _dispatch_lock; a
@@ -744,22 +773,198 @@ class Window:
         # at the rendezvous). Device work stays exclusively under
         # _dispatch_lock; the host fetch is per-shard copies, not a
         # program, and epochs with no read requests skip it entirely.
-        reads_np = None
-        for i, p in enumerate(todo):
-            if p.request is not None:
-                if reads_np is None:
-                    import numpy as _np
+        out: List[Any] = []
+        if reads is not None:
+            import numpy as _np
 
-                    reads_np = _np.asarray(reads)
-                value = reads_np[i]
-                if p.index is not None:
-                    # single-element op: hand back the element itself
-                    value = value.reshape(-1)[p.index]
-                src = (p.target if p.status_rank is None
-                       else p.status_rank)
-                p.request.complete(value=jnp.asarray(value),
-                                   status=Status(source=src))
-        self._data = new_data
+            reads_np = _np.asarray(reads)
+            for i, p in enumerate(run):
+                if p.request is not None:
+                    value = reads_np[i]
+                    if p.index is not None:
+                        # single-element op: hand back the element
+                        value = value.reshape(-1)[p.index]
+                    out.append(value)
+        if writes:
+            self._data = new_data
+        return out
+
+    def _branches(self, run: List[_PendingOp]):
+        """(padded op count, ordered distinct branch keys, their
+        functions, one code per step of the padded run). The op count
+        is padded to the next power of two with no-op steps so the
+        program cache holds O(log n) programs per branch set instead
+        of one per distinct epoch length; the noop branch is ALWAYS
+        part of the set so padded and exact-power-of-two epochs share
+        one program."""
+        n = len(run)
+        n_pad = 1 << (n - 1).bit_length() if n > 1 else 1
+        branch_keys: List[Tuple[str, Any, bool]] = []
+        branch_fns = []
+        codes: List[int] = []
+        for p in run:
+            k = self._branch_key(p)
+            if k not in branch_keys:
+                branch_keys.append(k)
+                branch_fns.append(self._branch_fn(k, p.op))
+            codes.append(branch_keys.index(k))
+        noop_key = ("noop", "", False)
+        if noop_key not in branch_keys:
+            branch_keys.append(noop_key)
+            branch_fns.append(self._branch_fn(noop_key, None))
+        codes.extend([branch_keys.index(noop_key)] * (n_pad - n))
+        return (n_pad, branch_keys, branch_fns,
+                jnp.asarray(codes, jnp.int32))
+
+    @staticmethod
+    def _steps(values: List[int], n_pad: int):
+        """One int per step of a padded run (0 for the no-op steps)."""
+        return jnp.asarray(values + [0] * (n_pad - len(values)), jnp.int32)
+
+    def _stage_slots(self, run: List[_PendingOp]):
+        """Whole-slot and single-element operations: a ``lax.scan``
+        over the op list — step i reads slice ``targets[i]``,
+        dispatches ``codes[i]`` through a ``lax.switch`` over the
+        run's distinct (kind, op) branches, writes the new slice back
+        and, where an operation of the run asked for one, emits the
+        pre-op value. Targets/kinds/payloads are runtime DATA, so the
+        compile cache key is only (op count, window shape/dtype,
+        branch set, what is staged)."""
+        from jax import lax
+
+        dtype = self._data.dtype
+        block = self.shape
+
+        # Scalar-payload epochs (the common AMO pattern: many scalar
+        # accumulates/CAS on a large window) keep payloads as (n,)
+        # scalars — broadcast happens INSIDE the kernel, so host-side
+        # staging is n scalars, not n x slot bytes.
+        scalar_mode = all(
+            (p.data is None or jnp.ndim(p.data) == 0)
+            and (p.compare is None or jnp.ndim(p.compare) == 0)
+            for p in run
+        ) and block != ()
+        # no compare array where no operation compares, no read where
+        # no operation asked for one
+        has_cmp = any(p.compare is not None for p in run)
+        has_read = any(p.request is not None for p in run)
+
+        n_pad, branch_keys, branch_fns, codes_a = self._branches(run)
+
+        pay_shape = () if scalar_mode else block
+        zeros = jnp.zeros(pay_shape, dtype)  # shared by all pad slots
+
+        def pay(x):
+            if x is None:
+                return zeros
+            return jnp.broadcast_to(
+                jnp.asarray(self._origin(x)).astype(dtype), pay_shape)
+
+        targets_a = self._steps([p.target for p in run], n_pad)
+        zero_pad = [None] * (n_pad - len(run))
+        payloads = jnp.stack([pay(p.data) for p in run]
+                             + [pay(x) for x in zero_pad])
+        compares = (jnp.stack([pay(p.compare) for p in run]
+                              + [pay(x) for x in zero_pad])
+                    if has_cmp else jnp.zeros((n_pad,), dtype))
+        indices = self._steps([p.index or 0 for p in run], n_pad)
+        sig = (n_pad, block, str(dtype), tuple(branch_keys), scalar_mode,
+               has_cmp, has_read)
+
+        def build():
+            def close_epoch(data, codes, targets, payloads, compares,
+                            indices):
+                def step(data, xs):
+                    code, tgt, payv, cmpv, idx = xs
+                    cur = lax.dynamic_index_in_dim(
+                        data, tgt, 0, keepdims=False
+                    )
+                    new, read = lax.switch(
+                        code, branch_fns, cur, payv, cmpv, idx
+                    )
+                    data = lax.dynamic_update_index_in_dim(
+                        data, new, tgt, 0
+                    )
+                    return data, (read if has_read else None)
+
+                return lax.scan(
+                    step, data,
+                    (codes, targets, payloads, compares, indices)
+                )
+
+            return close_epoch
+
+        return sig, build, (codes_a, targets_a, payloads, compares,
+                            indices)
+
+    def _stage_ranged(self, run: List[_PendingOp]):
+        """Ranged operations of ONE count ``c``: a ``lax.scan`` whose
+        step i slices ``c`` elements of row ``targets[i]`` of the
+        flattened window from ``disps[i]``, dispatches through the same
+        branches as a slot step (on the block, not the slot) and writes
+        the block back. Payloads are (n, c), compares exist only where
+        an operation compares, reads only where one was asked for, and
+        a run of gets returns no window: nothing but the window itself
+        has the slot's size."""
+        from jax import lax
+
+        dtype = self._data.dtype
+        c = run[0].count
+        has_pay = any(p.data is not None for p in run)
+        has_cmp = any(p.compare is not None for p in run)
+        has_read = any(p.request is not None for p in run)
+        writes = any(p.kind != "get" for p in run)
+        n_pad, branch_keys, branch_fns, codes_a = self._branches(run)
+        zeros = jnp.zeros((c,), dtype)
+
+        def row(x):
+            if x is None:
+                return zeros
+            return jnp.broadcast_to(
+                jnp.asarray(self._origin(x)).astype(dtype).reshape(-1),
+                (c,))
+
+        pad = [None] * (n_pad - len(run))
+        targets_a = self._steps([p.target for p in run], n_pad)
+        disps_a = self._steps([p.disp for p in run], n_pad)
+        payloads = (jnp.stack([row(p.data) for p in run]
+                              + [row(x) for x in pad]) if has_pay else None)
+        compares = (jnp.stack([row(p.compare) for p in run]
+                              + [row(x) for x in pad]) if has_cmp else None)
+        sig = ("ranged", n_pad, c, self.shape, str(dtype),
+               tuple(branch_keys), has_pay, has_cmp, has_read, writes)
+
+        def build():
+            def close_ranged(data, codes, targets, disps, payloads,
+                             compares):
+                flat = data.reshape(data.shape[0], -1)
+
+                def apply(flat, xs):
+                    code, tgt, disp, payv, cmpv = xs
+                    cur = lax.dynamic_slice(flat, (tgt, disp), (1, c))[0]
+                    return lax.switch(code, branch_fns, cur, payv, cmpv,
+                                      0) + (tgt, disp)
+
+                xs = (codes, targets, disps, payloads, compares)
+                if not writes:
+                    # gets alone: the window is read, never carried
+                    _, reads = lax.scan(
+                        lambda _, xs: (None, apply(flat, xs)[1]), None, xs)
+                    return None, reads
+
+                def step(flat, xs):
+                    new, read, tgt, disp = apply(flat, xs)
+                    flat = lax.dynamic_update_slice(flat, new[None],
+                                                    (tgt, disp))
+                    return flat, (read if has_read else None)
+
+                flat, reads = lax.scan(step, flat, xs)
+                return flat.reshape(data.shape), reads
+
+            return close_ranged
+
+        return sig, build, (codes_a, targets_a, disps_a, payloads,
+                            compares), writes
 
 
 def win_create(comm, base, name: str = "") -> Window:

@@ -22,16 +22,22 @@ process (the target's *home*) at synchronization time:
   exclusive lock serialize without the target's application code ever
   being involved — the osc/rdma passive-target model.
 
-Serialization is ``np.savez``/``np.load(allow_pickle=False)`` over the
-wire's payload transports (shm handoff on one host, chunked DCN
-staging across hosts) with a ``DssBuffer`` envelope — no pickle, no
-eval. Only predefined reduction ops may cross a process boundary
-(MPI itself restricts MPI_Accumulate to predefined ops).
+A batch is a ``DssBuffer`` envelope that carries the per-op request
+records (JSON: kind, target, op name, index or displacement and count,
+read flag, each payload's dtype and shape) followed by the payloads
+themselves as raw frames over the wire's payload transports (shm rings
+on one host, chunked DCN staging across hosts): consecutive payloads
+share a frame while together they fit one wire segment
+(``wire_pipeline_segsize``), a larger one travels alone, uncopied. The
+reply carries the read values the same way, so a batch of k ranged
+operations of c elements ships and returns O(k·c) elements whatever the
+slot's size. No pickle, no eval. Only predefined reduction ops may
+cross a process boundary (MPI itself restricts MPI_Accumulate to
+predefined ops).
 """
 
 from __future__ import annotations
 
-import io
 import itertools
 import json
 import threading
@@ -43,15 +49,14 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 import jax
-import jax.numpy as jnp
 
 from .. import obs as _obs
 from ..mca import pvar
 from ..mca import var as mca_var
 from ..native import DssBuffer
+from ..obs import spans as _spans
 from ..obs import watchdog as _watchdog
 from ..ops.op import PREDEFINED_OPS
-from ..request.request import Status
 from ..utils import output
 from ..utils.errors import ErrorCode, MPIError
 from .window import (LOCK_EXCLUSIVE, LOCK_SHARED, Window, _EpochKind,
@@ -95,7 +100,7 @@ class OscTuning:
     registry."""
 
     __slots__ = ("gen", "request_timeout_ms", "abandon_timeout_ms",
-                 "pscw_timeout_s")
+                 "pscw_timeout_s", "segment")
 
     def __init__(self) -> None:
         self.gen = mca_var.VARS.generation
@@ -111,11 +116,25 @@ class OscTuning:
             mca_var.get("osc_abandon_timeout_ms", 10_000) or 10_000)
         self.pscw_timeout_s = float(
             mca_var.get("osc_pscw_timeout_s", 0) or 0)
+        # the wire's own fragment size: consecutive batch payloads
+        # share a frame while together they fit one (``_frames_of``)
+        self.segment = max(0, int(
+            mca_var.get("wire_pipeline_segsize", 1 << 20) or 0))
 
 
 _win_requests = pvar.counter(
     "osc_wire_requests",
     "cross-process window service requests (batch/lock/abandon)",
+)
+
+_wire_bytes = pvar.counter(
+    "osc_wire_bytes",
+    "bytes of RMA batch frames this process sent (request records and "
+    "payloads) plus read frames it received",
+)
+_wire_ops = pvar.counter(
+    "osc_wire_ops",
+    "RMA operations shipped to another process's window service",
 )
 
 #: live window services (one per runtime) for the flight recorder's
@@ -180,26 +199,6 @@ KIND_COMPLETE = 5  # one-way: src process completed its access epoch
 KIND_ERROR = 99   # home-side failure applying a request
 
 
-def _savez_bytes(arrays: Dict[str, np.ndarray]) -> bytes:
-    """Deterministic npz writer: ``np.savez`` stamps every zip member
-    with the wall-clock mtime, so two packs of identical ops differ in
-    the member headers. Plan-time frame templates (osc/plan) must
-    render byte-identical output to the interpreted pack, so the zip
-    is written here with a fixed DOS-epoch timestamp — ``np.load``
-    reads it unchanged (same .npy members, same STORED layout)."""
-    import zipfile
-
-    bio = io.BytesIO()
-    with zipfile.ZipFile(bio, "w", zipfile.ZIP_STORED) as zf:
-        for name, val in arrays.items():
-            zi = zipfile.ZipInfo(name + ".npy",
-                                 date_time=(1980, 1, 1, 0, 0, 0))
-            with zf.open(zi, "w", force_zip64=True) as fid:
-                np.lib.format.write_array(fid, np.asanyarray(val),
-                                          allow_pickle=False)
-    return bio.getvalue()
-
-
 def _batch_meta(todo: List[_PendingOp]) -> List[Dict]:
     """Per-op request records (the wire header metadata). Shared by
     the per-call pack below and osc/plan's frozen ``BatchTemplate`` so
@@ -220,57 +219,166 @@ def _batch_meta(todo: List[_PendingOp]) -> List[Dict]:
             "o": p.op.name if p.op is not None else "",
             "i": -1 if p.index is None else int(p.index),
             "r": p.request is not None,
+            # a ranged op: displacement and count (-1: not ranged)
+            "d": -1 if p.disp is None else int(p.disp),
+            "n": -1 if p.count is None else int(p.count),
+            "hd": p.data is not None,
+            "hc": p.compare is not None,
         })
     return meta
 
 
-def _pack_batch(todo: List[_PendingOp]) -> np.ndarray:
-    """Serialize a pending-op batch to one uint8 array (npz form)."""
-    meta = _batch_meta(todo)
-    arrays: Dict[str, np.ndarray] = {}
-    for i, p in enumerate(todo):
-        if p.data is not None:
-            arrays[f"d{i}"] = np.asarray(p.data)
-        if p.compare is not None:
-            arrays[f"c{i}"] = np.asarray(p.compare)
-    arrays["meta"] = np.frombuffer(
-        json.dumps(meta).encode(), dtype=np.uint8
-    ).copy()
-    return np.frombuffer(_savez_bytes(arrays), dtype=np.uint8).copy()
+def _payloads(todo: List[_PendingOp]) -> List:
+    """The batch's payloads in wire order: per op its data, then its
+    compare value."""
+    return [x for p in todo for x in (p.data, p.compare) if x is not None]
 
 
-def _unpack_batch(raw) -> List[_PendingOp]:
-    """Inverse of :func:`_pack_batch`; requests are fresh local ones
-    for ops that want a read back."""
+def _descs(arrays) -> List[List]:
+    return [[str(a.dtype), [int(d) for d in a.shape]] for a in arrays]
+
+
+def _frames_of(sizes: List[int], seg: int) -> List[int]:
+    """How many consecutive payloads each wire frame holds: payloads
+    share a frame while together they fit one wire segment; one that
+    does not fit travels alone (and uncopied)."""
+    out: List[int] = []
+    n = used = 0
+    for size in sizes:
+        if n and used + size > seg:
+            out.append(n)
+            n = used = 0
+        n += 1
+        used += size
+    if n:
+        out.append(n)
+    return out
+
+
+class Batch:
+    """One home's batch as it goes on the wire: the request records
+    (``meta``, JSON in ASCII: ops, payload descriptors, payloads per
+    frame), the payloads on the host in wire order, and how many of
+    them each frame holds."""
+
+    __slots__ = ("meta", "frames", "arrays")
+
+    def __init__(self, meta: str, frames: Tuple[int, ...],
+                 arrays: List[np.ndarray]) -> None:
+        self.meta = meta
+        self.frames = frames
+        self.arrays = arrays
+
+    @property
+    def nbytes(self) -> int:
+        return len(self.meta) + sum(int(a.nbytes) for a in self.arrays)
+
+
+def _batch_header(todo: List[_PendingOp], seg: int
+                  ) -> Tuple[str, Tuple[int, ...]]:
+    """(request records as JSON, payloads per frame): everything
+    of a batch but the payload bytes, from shapes alone — what a frozen
+    ``BatchTemplate`` keeps."""
+    parts = _payloads(todo)
+    frames = _frames_of([_spans.nbytes(x) for x in parts], seg)
+    meta = json.dumps({"ops": _batch_meta(todo), "pay": _descs(parts),
+                       "frames": frames}, separators=(",", ":"))
+    return meta, tuple(frames)
+
+
+def _fetch(parts: List) -> List[np.ndarray]:
+    """The payloads on the host: every device array's copy is started
+    before the first is waited for."""
+    for x in parts:
+        start = getattr(x, "copy_to_host_async", None)
+        if start is not None:
+            start()
+    return [np.asarray(x) for x in parts]
+
+
+def _pack_batch(todo: List[_PendingOp], seg: int,
+                header: Optional[Tuple] = None) -> Batch:
+    """A pending-op batch as it goes on the wire. ``header``: the
+    signature's frozen ``_batch_header`` (osc/plan), else composed
+    here; the payloads are fetched either way."""
+    with _obs.span(_spans.OSC_PACK) as sp:
+        meta, frames = header or _batch_header(todo, seg)
+        parts = _payloads(todo)
+        with _obs.span(_spans.OSC_D2H,
+                       bytes=sum(_spans.nbytes(x) for x in parts)):
+            arrays = _fetch(parts)
+        batch = Batch(meta, frames, arrays)
+        sp.set_metadata(bytes=batch.nbytes)
+    return batch
+
+
+def _unpack_batch(meta: str, arrays: List[np.ndarray]
+                  ) -> List[_PendingOp]:
+    """Inverse of :func:`_pack_batch`; payloads stay the host arrays
+    the wire delivered, requests are fresh local ones for ops that
+    want a read back."""
     from ..request.request import Request
 
-    z = np.load(io.BytesIO(np.asarray(raw, dtype=np.uint8).tobytes()),
-                allow_pickle=False)
-    meta = json.loads(bytes(z["meta"]).decode())
+    arrays = iter(arrays)
     todo = []
-    for i, m in enumerate(meta):
+    for m in json.loads(meta)["ops"]:
         todo.append(_PendingOp(
             m["k"], m["t"],
-            data=(jnp.asarray(z[f"d{i}"]) if f"d{i}" in z else None),
+            data=(next(arrays) if m["hd"] else None),
             op=(PREDEFINED_OPS[m["o"]] if m["o"] else None),
             request=(Request() if m["r"] else None),
-            compare=(jnp.asarray(z[f"c{i}"]) if f"c{i}" in z else None),
+            compare=(next(arrays) if m["hc"] else None),
             index=(None if m["i"] < 0 else m["i"]),
+            disp=(None if m["d"] < 0 else m["d"]),
+            count=(None if m["n"] < 0 else m["n"]),
         ))
     return todo
 
 
-def _pack_reads(values: List[np.ndarray]) -> np.ndarray:
-    return np.frombuffer(
-        _savez_bytes({f"r{i}": np.asarray(v)
-                      for i, v in enumerate(values)}),
-        dtype=np.uint8).copy()
+def _keep_host(arr, _device):
+    """``put=`` of a payload receive: the host array as it arrived."""
+    return arr
 
 
-def _unpack_reads(raw, n: int) -> List[np.ndarray]:
-    z = np.load(io.BytesIO(np.asarray(raw, dtype=np.uint8).tobytes()),
-                allow_pickle=False)
-    return [z[f"r{i}"] for i in range(n)]
+def _send_frames(router, dst_pidx: int, tag: int,
+                 arrays: List[np.ndarray], frames) -> None:
+    """The payloads as wire frames: one alone goes as it is, several
+    are joined byte for byte (together they fit one wire segment)."""
+    i = 0
+    for n in frames:
+        group = arrays[i:i + n]
+        i += n
+        router._send_payload(
+            dst_pidx, tag,
+            group[0] if n == 1 else np.concatenate(
+                [np.ascontiguousarray(a).reshape(-1).view(np.uint8)
+                 for a in group]))
+
+
+def _recv_frames(router, src_pidx: int, tag: int, descs, frames
+                 ) -> List[np.ndarray]:
+    """Inverse of :func:`_send_frames`: every frame is taken off the
+    wire (whatever it turns out to hold), then cut into the payloads
+    ``descs`` describes, as views."""
+    raw = [router._recv_payload(tag, src_pidx, put=_keep_host)
+           for _ in frames]
+    out: List[np.ndarray] = []
+    i = 0
+    for n, frame in zip(frames, raw):
+        buf = np.ascontiguousarray(frame).reshape(-1).view(np.uint8)
+        off = 0
+        for dtype, shape in descs[i:i + n]:
+            dt = np.dtype(dtype)
+            size = int(np.prod(shape, dtype=np.int64)) * dt.itemsize
+            out.append(buf[off:off + size].view(dt).reshape(shape))
+            off += size
+        if off != buf.size:
+            raise MPIError(
+                ErrorCode.ERR_TRUNCATE,
+                f"window frame of {buf.size} bytes for {off} bytes of "
+                "payload")
+        i += n
+    return out
 
 
 class _LockState:
@@ -408,36 +516,8 @@ class WinService:
         cid, seq, kind, arg1, arg2, token = env.unpack_int64(6)
         token = int(token)
         if kind == KIND_BATCH:
-            # payload must be consumed even if applying fails, and the
-            # origin must get SOME reply or it stalls for the full
-            # request timeout — failures reply KIND_ERROR (loud at the
-            # origin, service stays alive)
-            rec = _obs.enabled  # capture once: flag may flip mid-apply
-            t0 = time.perf_counter() if rec else 0.0
-            payload = self.router._recv_payload(WIRE_WIN_DATA, src_pidx)
-            try:
-                win = self._window(int(cid), int(seq))
-                todo = _unpack_batch(payload)
-                reads = win._apply_home_batch(todo)
-                if int(arg1) >= 0:
-                    self.release(win, int(arg1), src_pidx)
-            except Exception as e:
-                _log.verbose(1, f"win service: batch from process "
-                                f"{src_pidx} failed: {e}")
-                self._reply(src_pidx, int(cid), int(seq), KIND_ERROR, [],
-                            token)
-                return
-            if rec and _obs.enabled:
-                # consumer side of the origin's (origin pidx, token)
-                # flow: both values rode the request envelope
-                _obs.record("win_apply", "osc", t0,
-                            time.perf_counter() - t0,
-                            nbytes=int(getattr(payload, "nbytes", 0)),
-                            peer=src_pidx, comm_id=int(cid),
-                            flow=_obs.flow_id("win", src_pidx, token),
-                            flow_side="t")
-            self._reply(src_pidx, int(cid), int(seq), KIND_BATCH, reads,
-                        token)
+            self._handle_batch(src_pidx, env, len(raw), int(cid),
+                               int(seq), int(arg1), int(arg2), token)
         elif kind == KIND_LOCK:
             win = self._window(int(cid), int(seq))
             granted = self.acquire(win, int(arg1), src_pidx, int(arg2),
@@ -459,19 +539,73 @@ class WinService:
         else:
             _log.verbose(1, f"win service: unknown kind {kind}")
 
+    def _handle_batch(self, src_pidx: int, env, limit: int, cid: int,
+                      seq: int, release_target: int, n_frames: int,
+                      token: int) -> None:
+        """One batch, from its envelope to its reply sent. Its frames
+        must be consumed even if applying fails, and the origin must
+        get SOME reply or it stalls for the full request timeout —
+        failures reply KIND_ERROR (loud at the origin, service stays
+        alive)."""
+        rec = _obs.enabled  # capture once: flag may flip mid-apply
+        t0 = time.perf_counter() if rec else 0.0
+        with _obs.span(_spans.OSC_APPLY, origin=src_pidx) as sp:
+            nbytes = 0
+            try:
+                try:
+                    meta = env.unpack_string(limit)
+                    head = json.loads(meta)
+                    if len(head["frames"]) != n_frames:
+                        raise ValueError("frame count")
+                except Exception:
+                    # unreadable records: take the frames off the wire
+                    # all the same, each as whatever it holds
+                    for _ in range(n_frames):
+                        self.router._recv_payload(WIRE_WIN_DATA, src_pidx,
+                                                  put=_keep_host)
+                    raise
+                arrays = _recv_frames(self.router, src_pidx, WIRE_WIN_DATA,
+                                      head["pay"], head["frames"])
+                nbytes = len(meta) + sum(int(a.nbytes) for a in arrays)
+                win = self._window(cid, seq)
+                todo = _unpack_batch(meta, arrays)
+                sp.set_metadata(ops=len(todo), bytes=nbytes)
+                reads = win._apply_home_batch(todo)
+                if release_target >= 0:
+                    self.release(win, release_target, src_pidx)
+            except Exception as e:
+                _log.verbose(1, f"win service: batch from process "
+                                f"{src_pidx} failed: {e}")
+                self._reply(src_pidx, cid, seq, KIND_ERROR, [], token)
+                return
+            if rec and _obs.enabled:
+                # consumer side of the origin's (origin pidx, token)
+                # flow: both values rode the request envelope
+                _obs.record("win_apply", "osc", t0,
+                            time.perf_counter() - t0, nbytes=nbytes,
+                            peer=src_pidx, comm_id=cid,
+                            flow=_obs.flow_id("win", src_pidx, token),
+                            flow_side="t")
+            self._reply(src_pidx, cid, seq, KIND_BATCH, reads, token)
+
     def _reply(self, dst_pidx: int, cid: int, seq: int, kind: int,
                reads: List[np.ndarray], token: int = 0) -> None:
         env = DssBuffer()
         env.pack_string(_WIN_MAGIC)
         env.pack_int64([cid, seq, kind, len(reads), token])
+        frames: List[int] = []
+        if reads:
+            frames = _frames_of([int(v.nbytes) for v in reads],
+                                self.tuning().segment)
+            env.pack_string(json.dumps(
+                {"pay": _descs(reads), "frames": frames},
+                separators=(",", ":")))
         self.router._retry(
             lambda: self.ep.send(self.router._nid(dst_pidx),
                                  WIRE_WIN_REPLY, env.tobytes()),
             f"window reply to process {dst_pidx}",
         )
-        if reads:
-            self.router._send_payload(dst_pidx, WIRE_WIN_RDATA,
-                                      _pack_reads(reads))
+        _send_frames(self.router, dst_pidx, WIRE_WIN_RDATA, reads, frames)
 
     # -- origin-side request/reply -----------------------------------------
     def _send_lock(self, owner_pidx: int) -> threading.Lock:
@@ -508,13 +642,18 @@ class WinService:
         rcid, rseq, rkind, n_reads, rtoken = renv.unpack_int64(5)
         reads: List[np.ndarray] = []
         if int(n_reads) and int(rkind) != KIND_ERROR:
-            # the owner's service thread sends a reply's RDATA directly
-            # behind its envelope, so consuming it HERE (src-matched)
-            # keeps the per-owner payload stream aligned no matter
-            # which thread's reply this is
-            rdata = self.router._recv_payload(WIRE_WIN_RDATA,
-                                              src_nid - 1)
-            reads = _unpack_reads(rdata, int(n_reads))
+            # the owner's service thread sends a reply's read frames
+            # directly behind its envelope, so consuming them HERE
+            # (src-matched) keeps the per-owner payload stream aligned
+            # no matter which thread's reply this is
+            head = json.loads(renv.unpack_string(len(raw)))
+            with _obs.span(_spans.OSC_UNPACK) as sp:
+                reads = _recv_frames(self.router, src_nid - 1,
+                                     WIRE_WIN_RDATA, head["pay"],
+                                     head["frames"])
+                got = sum(int(v.nbytes) for v in reads)
+                sp.set_metadata(bytes=got)
+            _wire_bytes.add(got)
         with self._reply_guard:
             slot = self._reply_slots.get(int(rtoken))
             if slot is None:
@@ -529,13 +668,15 @@ class WinService:
 
     def request(self, win: "WireWindow", owner_pidx: int, kind: int,
                 arg1: int, arg2: int,
-                payload: Optional[np.ndarray] = None,
+                payload: Optional[Batch] = None,
                 timeout_ms: Optional[int] = None) -> List[np.ndarray]:
         """Send one request to ``owner_pidx`` and await its reply
         (lock grants may be deferred behind another holder, hence the
         generous default bound — ``osc_request_timeout_ms``, read off
-        the tuning snapshot, never the registry). Returns the read
-        arrays.
+        the tuning snapshot, never the registry). ``payload``: a
+        batch, whose request records ride the envelope and whose
+        frames follow it (``arg2`` then tells the home how many).
+        Returns the read arrays.
 
         Concurrency: the reply channel is demultiplexed by token, so
         any number of threads may have requests outstanding — while a
@@ -546,6 +687,7 @@ class WinService:
             timeout_ms = self.tuning().request_timeout_ms
         token = next(self._token)
         _win_requests.add()
+        nbytes = payload.nbytes if payload is not None else 0
         rec = _obs.enabled  # capture once: flag may flip mid-request
         t0 = time.perf_counter() if rec else 0.0
         wd_tok = None
@@ -561,52 +703,40 @@ class WinService:
         with self._reply_guard:
             self._reply_slots[token] = slot
         try:
-            env = DssBuffer()
-            env.pack_string(_WIN_MAGIC)
-            env.pack_int64([win.comm.cid, win.win_seq, kind, arg1, arg2,
-                            token])
-            with self._send_lock(owner_pidx):
-                self.router._retry(
-                    lambda: self.ep.send(self.router._nid(owner_pidx),
-                                         WIRE_WIN_SERVICE, env.tobytes()),
-                    f"window request to process {owner_pidx}",
-                )
+            with _obs.span(_spans.OSC_REQUEST, kind=kind, peer=owner_pidx,
+                           bytes=nbytes):
+                env = DssBuffer()
+                env.pack_string(_WIN_MAGIC)
                 if payload is not None:
-                    self.router._send_payload(owner_pidx, WIRE_WIN_DATA,
-                                              payload)
-            if rec and _obs.enabled:
-                # producer side: the home's win_apply span derives the
-                # same (origin pidx, token) id from the envelope
-                _obs.record(
-                    "win_request", "osc", t0, time.perf_counter() - t0,
-                    nbytes=int(getattr(payload, "nbytes", 0) or 0),
-                    peer=owner_pidx, comm_id=win.comm.cid,
-                    flow=_obs.flow_id("win", self.my_pidx, token),
-                    flow_side="s")
-            deadline = time.monotonic() + timeout_ms / 1000
-            while not slot["ev"].is_set():
-                # one thread at a time pumps the shared channel; the
-                # others park on their event (woken the instant the
-                # pump routes their reply) — whoever holds the pump
-                # routes EVERY arriving reply to its waiter
-                if self._pump_lock.acquire(blocking=False):
-                    try:
-                        if slot["ev"].is_set():
-                            break
-                        self._pump_replies(time.monotonic() + 0.2)
-                    finally:
-                        self._pump_lock.release()
-                else:
-                    slot["ev"].wait(timeout=0.02)
-                if slot["ev"].is_set():
-                    break
-                if time.monotonic() >= deadline:
-                    raise MPIError(
-                        ErrorCode.ERR_PENDING,
-                        f"window request (kind {kind}) to process "
-                        f"{owner_pidx} got no reply within "
-                        f"{timeout_ms / 1000:.0f}s",
+                    arg2 = len(payload.frames)
+                env.pack_int64([win.comm.cid, win.win_seq, kind, arg1,
+                                arg2, token])
+                if payload is not None:
+                    env.pack_string(payload.meta)
+                with self._send_lock(owner_pidx):
+                    self.router._retry(
+                        lambda: self.ep.send(
+                            self.router._nid(owner_pidx),
+                            WIRE_WIN_SERVICE, env.tobytes()),
+                        f"window request to process {owner_pidx}",
                     )
+                    if payload is not None:
+                        _send_frames(self.router, owner_pidx,
+                                     WIRE_WIN_DATA, payload.arrays,
+                                     payload.frames)
+                        _wire_bytes.add(nbytes)
+                if rec and _obs.enabled:
+                    # producer side: the home's win_apply span derives
+                    # the same (origin pidx, token) id from the envelope
+                    _obs.record(
+                        "win_request", "osc", t0,
+                        time.perf_counter() - t0, nbytes=nbytes,
+                        peer=owner_pidx, comm_id=win.comm.cid,
+                        flow=_obs.flow_id("win", self.my_pidx, token),
+                        flow_side="s")
+                with _obs.span(_spans.OSC_REPLY_WAIT, kind=kind,
+                               peer=owner_pidx):
+                    self._await_reply(slot, kind, owner_pidx, timeout_ms)
         finally:
             if wd_tok is not None:
                 _watchdog.disarm(wd_tok)
@@ -629,6 +759,33 @@ class WinService:
                 f"seq={win.win_seq}, kind={kind})",
             )
         return slot["reads"] or []
+
+    def _await_reply(self, slot: dict, kind: int, owner_pidx: int,
+                     timeout_ms: int) -> None:
+        """Until ``slot``'s reply is routed to it. One thread at a
+        time pumps the shared channel; the others park on their event
+        (woken the instant the pump routes their reply) — whoever
+        holds the pump routes EVERY arriving reply to its waiter."""
+        deadline = time.monotonic() + timeout_ms / 1000
+        while not slot["ev"].is_set():
+            if self._pump_lock.acquire(blocking=False):
+                try:
+                    if slot["ev"].is_set():
+                        break
+                    self._pump_replies(time.monotonic() + 0.2)
+                finally:
+                    self._pump_lock.release()
+            else:
+                slot["ev"].wait(timeout=0.02)
+            if slot["ev"].is_set():
+                break
+            if time.monotonic() >= deadline:
+                raise MPIError(
+                    ErrorCode.ERR_PENDING,
+                    f"window request (kind {kind}) to process "
+                    f"{owner_pidx} got no reply within "
+                    f"{timeout_ms / 1000:.0f}s",
+                )
 
     # -- PSCW notices (one-way; no reply awaited) --------------------------
     def notify(self, dst_pidx: int, win: "WireWindow", kind: int) -> None:
@@ -876,51 +1033,64 @@ class WireWindow(Window):
         return self._data
 
     # -- epoch close: split local / per-home batches -----------------------
+    def _sync_span(self):
+        """``ompi.osc.sync``: a call that closes or flushes an epoch of
+        this window, entry to return; ``_sync_stats`` fills in what it
+        took off the queue."""
+        return _obs.span(_spans.OSC_SYNC, cid=self.comm.cid,
+                         win=self.win_seq)
+
+    @staticmethod
+    def _sync_stats(sp, ops: List[_PendingOp]) -> None:
+        sp.set_metadata(ops=len(ops), bytes=sum(
+            _spans.nbytes(x) for x in _payloads(ops)))
+
     def _apply_pending(self, only_target: Optional[int] = None) -> None:
         from .window import _epoch_count
 
-        with self._op_lock:
-            if not self._pending:
-                return
-            _epoch_count.add()
-            todo = self._take_pending(only_target)
-            if not todo:
-                return
-            local: List[_PendingOp] = []
-            remote: Dict[int, List[_PendingOp]] = {}
-            for p in todo:
-                own = self.owner[p.target]
-                if own == self.my_pidx:
-                    local.append(p)
-                else:
-                    remote.setdefault(own, []).append(p)
-            if local:
-                remapped = [
-                    _PendingOp(p.kind, self._local_pos(p.target),
-                               data=p.data, op=p.op, request=p.request,
-                               compare=p.compare, index=p.index,
-                               status_rank=p.target)
-                    for p in local
-                ]
-                t0 = time.perf_counter()
-                from . import plan as _osc_plan
-
-                if not _osc_plan.close_epoch(self, remapped, t0):
-                    self._run_epoch_program(remapped, _t0=t0)
-        # ship OUTSIDE _op_lock: holding it while awaiting the peer's
-        # ack would deadlock two processes fencing into each other
-        # (each service thread needs the lock to apply the other's
-        # batch)
-        for own in sorted(remote):
-            self._ship_batch(own, remote[own], release_target=-1)
+        with self._sync_span() as sp:
+            with self._op_lock:
+                if not self._pending:
+                    return
+                _epoch_count.add()
+                todo = self._take_pending(only_target)
+                if not todo:
+                    return
+                self._sync_stats(sp, todo)
+                local: List[_PendingOp] = []
+                remote: Dict[int, List[_PendingOp]] = {}
+                for p in todo:
+                    own = self.owner[p.target]
+                    if own == self.my_pidx:
+                        local.append(p)
+                    else:
+                        remote.setdefault(own, []).append(p)
+                if local:
+                    remapped = [
+                        _PendingOp(p.kind, self._local_pos(p.target),
+                                   data=p.data, op=p.op,
+                                   request=p.request, compare=p.compare,
+                                   index=p.index, status_rank=p.target,
+                                   disp=p.disp, count=p.count)
+                        for p in local
+                    ]
+                    self._run_epoch_program(
+                        remapped, _t0=time.perf_counter())
+            # ship OUTSIDE _op_lock: holding it while awaiting the
+            # peer's ack would deadlock two processes fencing into each
+            # other (each service thread needs the lock to apply the
+            # other's batch)
+            for own in sorted(remote):
+                self._ship_batch(own, remote[own], release_target=-1)
 
     def _ship_batch(self, owner_pidx: int, ops: List[_PendingOp],
                     release_target: int) -> None:
         from . import plan as _osc_plan
 
         # repeated batches render through the signature's frozen
-        # frame template (meta composed once at freeze time); bytes
-        # are identical to _pack_batch either way
+        # frame template (request records composed once at freeze
+        # time); the frames are identical to _pack_batch either way
+        _wire_ops.add(len(ops))
         reads = self.service.request(
             self, owner_pidx, KIND_BATCH, release_target, 0,
             payload=_osc_plan.batch_payload(self, ops),
@@ -932,14 +1102,14 @@ class WireWindow(Window):
                 f"window batch reply carried {len(reads)} reads for "
                 f"{len(want)} read-requests",
             )
-        for p, v in zip(want, reads):
-            p.request.complete(value=jnp.asarray(v),
-                               status=Status(source=p.target))
+        self._complete_reads(ops, reads)
 
     def _apply_home_batch(self, todo: List[_PendingOp]
                           ) -> List[np.ndarray]:
         """Service-side: apply a peer's batch into the local slices and
-        return the read values in op order."""
+        return the read values in op order, as host arrays. A range
+        that leaves this home's slot is refused here too (the origin
+        checked it against its own)."""
         for p in todo:
             if self.owner[p.target] != self.my_pidx:
                 raise MPIError(
@@ -947,17 +1117,13 @@ class WireWindow(Window):
                     f"batch targets rank {p.target}, owned by process "
                     f"{self.owner[p.target]}, not {self.my_pidx}",
                 )
+            if p.disp is not None:
+                self._check_range(p.disp, p.count)
             p.target = self._local_pos(p.target)
-        t0 = time.perf_counter()
-        from . import plan as _osc_plan
-
         with self._op_lock:
             # incoming batches ride the same access-plan cache: a
             # peer's steady-state epoch replays one fused program here
-            if not _osc_plan.close_epoch(self, todo, t0):
-                self._run_epoch_program(todo, _t0=t0)
-        return [np.asarray(p.request.value) for p in todo
-                if p.request is not None]
+            return self._close(todo, time.perf_counter())
 
     # -- passive target over the home lock table ---------------------------
     def lock(self, target: int, lock_type: int = LOCK_EXCLUSIVE) -> None:
@@ -1002,11 +1168,13 @@ class WireWindow(Window):
             self._apply_pending(only_target=target)
             self.service.release(self, target, self.my_pidx)
         else:
-            with self._op_lock:
-                ops = self._take_pending(only_target=target)
-            remote = [p for p in ops if self.owner[p.target] != self.my_pidx]
-            assert len(remote) == len(ops)  # only_target => one owner
-            self._ship_batch(own, remote, release_target=target)
+            with self._sync_span() as sp:
+                with self._op_lock:
+                    ops = self._take_pending(only_target=target)
+                self._sync_stats(sp, ops)
+                # only_target => one owner: the batch (empty or not)
+                # carries the release
+                self._ship_batch(own, ops, release_target=target)
 
     def unlock(self, target: int) -> None:
         self._require(_EpochKind.LOCK)

@@ -173,6 +173,21 @@ def profiler_trace(logdir: str):
     - ``ompi.wire.p2p_pump`` — one p2p message off its lane, envelope
       to payload complete (the sender's ``seq``, ``bytes``), with
       ``ompi.pml.h2d`` (the arrival's ``device_put``, ``bytes``) inside
+    - ``ompi.osc.sync`` — a call that closes or flushes an epoch of a
+      window on a spanning communicator: ``flush``, ``unlock``,
+      ``fence``, ``complete`` (``cid``, ``win``, ``ops``, ``bytes``),
+      with ``ompi.osc.pack`` (one home's batch composed, ``bytes``; the
+      fetch of its device payloads is ``ompi.osc.d2h`` inside it),
+      ``ompi.osc.request`` (the request to the home, entry to return:
+      ``kind``, ``peer``, ``bytes``; from the payload sent to the reply
+      routed it is ``ompi.osc.reply_wait``, and the reply's read values
+      come off the wire under ``ompi.osc.unpack``) and ``ompi.osc.h2d``
+      (the read values placed on this process's device) inside it
+    - ``ompi.osc.apply`` — at a window's home, on the service thread: a
+      peer's batch from its envelope to its reply sent (``origin``,
+      ``ops``, ``bytes``)
+    - ``ompi.osc.program`` — the call of an epoch program, interpreted
+      or planned, wherever it runs (``ops``)
 
     ``(cid, seq)`` joins an exchange to its ``ompi.nbc.wait`` when the
     schedule ran on another thread; on one thread nesting is the link.

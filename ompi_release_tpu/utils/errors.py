@@ -38,6 +38,7 @@ class ErrorCode(enum.IntEnum):
     ERR_PENDING = 19
     ERR_WIN = 45
     ERR_RMA_SYNC = 50
+    ERR_RMA_RANGE = 68  # MPI_ERR_RMA_RANGE: (disp, count) leaves the slot
     ERR_RMA_SHARED = 71  # MPI_ERR_RMA_SHARED: shared-window constraint
     ERR_BASE = 46
     ERR_DISP = 52

@@ -343,14 +343,24 @@ def test_span_sites_exist_and_only_the_helper_annotates():
               "PML_RECV_WAIT": "p2p/pml.py",
               "WIRE_P2P_SEND": "runtime/wire.py",
               "WIRE_P2P_PUMP": "runtime/wire.py",
-              "PML_H2D": "runtime/wire.py"}
+              "PML_H2D": "runtime/wire.py",
+              # ISSUE 34: the one-sided layer (a second site of
+              # OSC_PROGRAM, the planned replay, is in osc/plan.py)
+              "OSC_SYNC": "osc/wire_win.py", "OSC_PACK": "osc/wire_win.py",
+              "OSC_D2H": "osc/wire_win.py",
+              "OSC_REQUEST": "osc/wire_win.py",
+              "OSC_REPLY_WAIT": "osc/wire_win.py",
+              "OSC_UNPACK": "osc/wire_win.py",
+              "OSC_APPLY": "osc/wire_win.py", "OSC_H2D": "osc/window.py",
+              "OSC_PROGRAM": "osc/window.py"}
     import importlib.util  # obs/spans.py by path: the package pulls jax
     spec = importlib.util.spec_from_file_location(
         "_spans_only", os.path.join(REPO, SPANS_MODULE))
     spans = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(spans)
-    # sixteen names since ISSUE 31, each with a site of its own
-    assert len(spans.NAMES) == 16
+    # sixteen names since ISSUE 31, nine more since ISSUE 34, each with
+    # a site of its own
+    assert len(spans.NAMES) == 25
     assert {getattr(spans, const) for const in wanted} == set(spans.NAMES)
     for const, rel in wanted.items():
         path = os.path.join(pkg, rel)

@@ -13,9 +13,10 @@ Layers:
 3. Lifecycle: a cvar write re-plans at the next close (generation
    witness), replay divergence drops the plan loudly and falls back
    interpreted, ``win.free()`` evicts every plan and template.
-4. Wire frames: the frozen ``BatchTemplate`` renders bytes IDENTICAL
-   to ``_pack_batch`` (pinned, round-tripped through
-   ``_unpack_batch``), and packing is time-deterministic.
+4. Wire frames: the frozen ``BatchTemplate`` renders a batch IDENTICAL
+   to ``_pack_batch``'s (pinned, round-tripped through
+   ``_unpack_batch``), packing is deterministic, and payloads share a
+   frame only while they fit one wire segment.
 5. Hot-path cvar caching: steady-state closes and request timeouts hit
    the MCA registry ZERO times (the ``OscTuning`` snapshot + the
    generation-cached plan conf), and same-NAMED user ops can neither
@@ -41,7 +42,7 @@ from ompi_release_tpu.ops.op import Op
 from ompi_release_tpu.osc import LOCK_EXCLUSIVE, win_allocate
 from ompi_release_tpu.osc import plan as osc_plan
 from ompi_release_tpu.osc.wire_win import (
-    OscTuning, _pack_batch, _savez_bytes, _unpack_batch,
+    OscTuning, _frames_of, _pack_batch, _unpack_batch,
 )
 from ompi_release_tpu.osc.window import _PendingOp
 from ompi_release_tpu.runtime.state import JobState
@@ -322,32 +323,43 @@ def _wire_todo():
     ]
 
 
+SEG = 1 << 20  # wire_pipeline_segsize's default
+
+
+def _same_batch(a, b):
+    assert a.meta == b.meta and a.frames == b.frames
+    assert len(a.arrays) == len(b.arrays)
+    for x, y in zip(a.arrays, b.arrays):
+        assert x.dtype == y.dtype and x.shape == y.shape
+        assert x.tobytes() == y.tobytes()  # BYTE-identical
+
+
 class TestFrameTemplates:
     def test_template_bytes_identical_to_pack_batch(self):
         todo = _wire_todo()
-        want = _pack_batch(todo)
-        tpl = osc_plan.BatchTemplate(mca_var.VARS.generation, todo)
-        got = tpl.render(todo)
-        assert got.tobytes() == want.tobytes()  # BYTE-identical
+        want = _pack_batch(todo, SEG)
+        tpl = osc_plan.BatchTemplate(mca_var.VARS.generation, todo, SEG)
+        _same_batch(tpl.render(todo), want)
 
     def test_pack_batch_is_time_deterministic(self):
-        # np.savez stamps member mtimes; _savez_bytes pins the DOS
-        # epoch so two packs of the same ops are identical bytes
+        # two packs of the same ops are the same request records and
+        # the same payload bytes: nothing of the clock is in a frame
         todo = _wire_todo()
-        a = _pack_batch(todo)
-        b = _pack_batch(todo)
-        assert a.tobytes() == b.tobytes()
+        _same_batch(_pack_batch(todo, SEG), _pack_batch(todo, SEG))
 
     def test_template_round_trips_through_unpack(self):
-        todo = _wire_todo()
-        tpl = osc_plan.BatchTemplate(mca_var.VARS.generation, todo)
-        back = _unpack_batch(tpl.render(todo))
+        todo = _wire_todo() + [
+            _PendingOp("put", 2, data=jnp.arange(3, dtype=jnp.float32),
+                       op=ops.REPLACE, disp=1, count=3)]
+        tpl = osc_plan.BatchTemplate(mca_var.VARS.generation, todo, SEG)
+        batch = tpl.render(todo)
+        back = _unpack_batch(batch.meta, batch.arrays)
         assert [(p.kind, p.target) for p in back] == \
                [(p.kind, p.target) for p in todo]
         for p, q in zip(back, todo):
             assert (p.op.name if p.op else "") == \
                    (q.op.name if q.op else "")
-            assert p.index == q.index
+            assert (p.index, p.disp, p.count) == (q.index, q.disp, q.count)
             assert (p.request is not None) == (q.request is not None)
             if q.data is not None:
                 np.testing.assert_array_equal(
@@ -356,15 +368,18 @@ class TestFrameTemplates:
                 np.testing.assert_array_equal(
                     np.asarray(p.compare), np.asarray(q.compare))
 
-    def test_savez_bytes_loads_like_savez(self):
-        arrays = {"a": np.arange(5, dtype=np.int32),
-                  "b": np.ones((2, 3), np.float64)}
-        import io
-
-        z = np.load(io.BytesIO(_savez_bytes(arrays)),
-                    allow_pickle=False)
-        np.testing.assert_array_equal(z["a"], arrays["a"])
-        np.testing.assert_array_equal(z["b"], arrays["b"])
+    def test_payloads_share_a_frame_while_they_fit_one_segment(self):
+        # consecutive payloads are joined while together they fit one
+        # wire segment; one that does not fit travels alone
+        assert _frames_of([4, 4, 4], 16) == [3]
+        assert _frames_of([8, 8, 8], 16) == [2, 1]
+        assert _frames_of([64, 4, 4, 64], 16) == [1, 2, 1]
+        assert _frames_of([16] * 4, 16) == [1, 1, 1, 1]
+        assert _frames_of([], 16) == [] and _frames_of([4, 4], 0) == [1, 1]
+        batch = _pack_batch(_wire_todo(), SEG)
+        assert sum(batch.frames) == len(batch.arrays) == 5
+        assert batch.nbytes == len(batch.meta) + sum(
+            a.nbytes for a in batch.arrays)
 
 
 # ---------------------------------------------------------------------------
@@ -447,7 +462,7 @@ class TestHotPathCvars:
                            data=jnp.ones((4,), jnp.float32),
                            op=clobber)]
         with pytest.raises(MPIError):
-            _pack_batch(todo)
+            _pack_batch(todo, SEG)
 
     def test_cache_stats_shape(self):
         st = osc_plan.cache_stats()
