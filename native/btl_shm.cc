@@ -43,6 +43,7 @@
 #include <cstring>
 #include <thread>
 
+#include "crc32.h"
 #include "nativeev.h"
 
 namespace {
@@ -184,6 +185,10 @@ inline uint64_t be64(const uint8_t* p) {
   return v;
 }
 
+inline void put_be64(uint8_t* p, uint64_t v) {
+  for (int i = 7; i >= 0; --i, v >>= 8) p[i] = static_cast<uint8_t>(v);
+}
+
 // Peek the SGC2 prefix out of a scatter-gather list (the event ring
 // wants xfer/idx and the producer only has the iovec). True iff the
 // payload starts with a full prefix.
@@ -228,6 +233,163 @@ ShmRing* map_ring(int fd, uint64_t total, bool creator) {
   r->cap = total - kHdrSize;
   r->creator = creator;
   return r;
+}
+
+// Producer core of shmring_writev and shmring_write_msg: append one
+// record whose payload is the concatenation of the scatter-gather
+// parts, waiting on a full ring until `dl`. Return codes as
+// shmring_writev's.
+int put_record(ShmRing* r, int32_t tag, const uint8_t** parts,
+               const int64_t* lens, int32_t nparts, const Deadline& dl) {
+  RingHdr* h = hdr(r);
+  uint64_t plen = 0;
+  for (int32_t i = 0; i < nparts; ++i)
+    plen += static_cast<uint64_t>(lens[i]);
+  uint64_t total = kRecHdr + plen;
+  if (total > r->cap) return -2;
+  // credit the open stall's time since the last credit to w_stall_ns
+  auto credit = [r, h] {
+    auto now = std::chrono::steady_clock::now();
+    bump_rlx(&h->w_stall_ns, ns_between(r->w_stall_mark, now));
+    r->w_stall_mark = now;
+  };
+  uint64_t w = h->widx;  // we are the only writer
+  for (;;) {
+    uint64_t used = w - load_acq(&h->ridx);
+    if (r->cap - used >= total) break;
+    if (!r->w_stall_open) {  // ring full: this record is one stall
+      r->w_stall_open = true;  // until it is written (see ShmRing)
+      r->w_stall_t0 = r->w_stall_mark = std::chrono::steady_clock::now();
+      bump_rlx(&h->w_stalls, 1);
+    }
+    if (pid_dead(h->consumer_pid)) {
+      credit();
+      r->w_stall_open = false;
+      return -3;
+    }
+    if (dl.expired()) {
+      credit();
+      return -1;  // the stall stays open: the caller retries
+    }
+    ring_nap();
+  }
+  uint64_t waited = 0;
+  if (r->w_stall_open) {
+    credit();
+    r->w_stall_open = false;
+    waited = ns_between(r->w_stall_t0, r->w_stall_mark);
+  }
+  uint8_t rec[kRecHdr];
+  uint32_t l32 = static_cast<uint32_t>(plen);
+  std::memcpy(rec, &l32, 4);
+  std::memcpy(rec + 4, &tag, 4);
+  ring_put(r, w, rec, kRecHdr);
+  uint64_t pos = w + kRecHdr;
+  for (int32_t i = 0; i < nparts; ++i) {
+    ring_put(r, pos, parts[i], static_cast<size_t>(lens[i]));
+    pos += static_cast<uint64_t>(lens[i]);
+  }
+  store_rel(&h->widx, w + total);
+  bump_rlx(&h->w_frames, 1);
+  bump_rlx(&h->w_bytes, plen);
+  max_rlx(&h->hwm, (w + total) - load_acq(&h->ridx));
+  uint64_t xfer, idx;
+  if (sg_peek(parts, lens, nparts, &xfer, &idx))
+    ompitpu::nativeev_emit(
+        tag, xfer,
+        static_cast<uint32_t>(plen - kSgPrefix),
+        static_cast<uint32_t>(idx), /*recv_side=*/false, waited);
+  return 0;
+}
+
+// Copy out of the ring and checksum what was copied, block by block,
+// so the CRC reads each byte while the copy has it in cache.
+uint32_t ring_get_crc(ShmRing* r, uint64_t pos, uint8_t* dst, size_t n,
+                      uint32_t crc) {
+  constexpr size_t kBlock = 32 * 1024;
+  while (n) {
+    size_t b = n < kBlock ? n : kBlock;
+    ring_get(r, pos, dst, b);
+    crc = ompitpu::crc32_update(crc, dst, b);
+    pos += b;
+    dst += b;
+    n -= b;
+  }
+  return crc;
+}
+
+// Consumer core of shmring_read_frag and shmring_read_msg: pop the
+// head record IF it is an SGC2 fragment of transfer `xfer` on `tag`,
+// copying its payload straight into the reassembly buffer, waiting on
+// an empty ring until `dl`. Return codes as shmring_read_frag's.
+// `crc` (may be null) is the transfer's running checksum: crc[0] the
+// CRC-32 of fragments 0 .. crc[1]-1; a fragment that arrives in that
+// order is checksummed inside its copy, any other sets crc[1] to -1
+// (the caller then checks the whole buffer at the end).
+int64_t take_frag(ShmRing* r, int32_t tag, int64_t xfer, int64_t nchunks,
+                  int64_t chunk, uint8_t* base, int64_t nbytes,
+                  const Deadline& dl, int64_t* crc) {
+  RingHdr* h = hdr(r);
+  StallTimer stall(&h->r_stalls, &h->r_stall_ns);
+  uint64_t rd = h->ridx;  // we are the only reader
+  for (;;) {
+    if (load_acq(&h->widx) != rd) break;
+    stall.arm();  // ring empty: this read is a stall until data lands
+    if (pid_dead(h->producer_pid)) {
+      stall.settle();
+      return -3;
+    }
+    if (dl.expired()) {
+      stall.settle();
+      return -1;
+    }
+    ring_nap();
+  }
+  uint64_t waited = stall.settle();
+  uint8_t rec[kRecHdr];
+  ring_get(r, rd, rec, kRecHdr);
+  uint32_t plen;
+  int32_t rtag;
+  std::memcpy(&plen, rec, 4);
+  std::memcpy(&rtag, rec + 4, 4);
+  if (rtag != tag) return -5;
+  uint64_t next = rd + kRecHdr + plen;
+  auto consume = [h, next, plen] {
+    store_rel(&h->ridx, next);
+    bump_rlx(&h->r_frames, 1);
+    bump_rlx(&h->r_bytes, plen);
+  };
+  uint8_t pre[kSgPrefix];
+  if (plen >= kSgPrefix) ring_get(r, rd + kRecHdr, pre, kSgPrefix);
+  if (plen < kSgPrefix || std::memcmp(pre, "SGC2", 4) != 0 ||
+      be64(pre + 4) != static_cast<uint64_t>(xfer)) {
+    consume();
+    return -4;
+  }
+  int64_t idx = static_cast<int64_t>(be64(pre + 12));
+  int64_t flen = static_cast<int64_t>(plen - kSgPrefix);
+  if (idx < 0 || idx >= nchunks || idx * chunk + flen > nbytes) {
+    consume();
+    return -2;
+  }
+  bool chained = crc && crc[1] == idx;
+  uint64_t from = rd + kRecHdr + kSgPrefix;
+  if (chained) {
+    crc[0] = ring_get_crc(r, from, base + idx * chunk,
+                          static_cast<size_t>(flen),
+                          static_cast<uint32_t>(crc[0]));
+    crc[1] = idx + 1;
+  } else {
+    if (flen)
+      ring_get(r, from, base + idx * chunk, static_cast<size_t>(flen));
+    if (crc) crc[1] = -1;
+  }
+  consume();
+  ompitpu::nativeev_emit(tag, static_cast<uint64_t>(xfer),
+                         static_cast<uint32_t>(flen),
+                         static_cast<uint32_t>(idx),
+                         /*recv_side=*/true, waited);
+  return idx;
 }
 
 }  // namespace
@@ -346,67 +508,44 @@ int64_t shmring_stat(void* vr, int32_t which) {
 int shmring_writev(void* vr, int32_t tag, const uint8_t** parts,
                    const int64_t* lens, int32_t nparts,
                    int timeout_ms) {
+  return put_record(static_cast<ShmRing*>(vr), tag, parts, lens, nparts,
+                    Deadline(timeout_ms));
+}
+
+// Producer side, a message's payload in one call: append the SGC2
+// fragment records `first` .. nchunks-1 of transfer `xfer` (fragment
+// i = "SGC2" + xfer + i + payload[i*chunk : (i+1)*chunk], the records
+// btl/components.FrameTemplate.sg_lists composes), all inside one
+// wait of `timeout_ms`. Returns how many fragments went in; *status
+// says why it stopped: 0 the last one is in, else shmring_writev's
+// code for the fragment it stopped at (-1: the ring stayed full for
+// the rest of the slice — the caller looks at its own inbound rings
+// and calls again from that fragment, its stall still open).
+int64_t shmring_write_msg(void* vr, int32_t tag, int64_t xfer,
+                          const uint8_t* payload, int64_t nbytes,
+                          int64_t chunk, int64_t first, int64_t nchunks,
+                          int timeout_ms, int32_t* status) {
   auto* r = static_cast<ShmRing*>(vr);
-  RingHdr* h = hdr(r);
-  uint64_t plen = 0;
-  for (int32_t i = 0; i < nparts; ++i)
-    plen += static_cast<uint64_t>(lens[i]);
-  uint64_t total = kRecHdr + plen;
-  if (total > r->cap) return -2;
   Deadline dl(timeout_ms);
-  // credit the open stall's time since the last credit to w_stall_ns
-  auto credit = [r, h] {
-    auto now = std::chrono::steady_clock::now();
-    bump_rlx(&h->w_stall_ns, ns_between(r->w_stall_mark, now));
-    r->w_stall_mark = now;
-  };
-  uint64_t w = h->widx;  // we are the only writer
-  for (;;) {
-    uint64_t used = w - load_acq(&h->ridx);
-    if (r->cap - used >= total) break;
-    if (!r->w_stall_open) {  // ring full: this record is one stall
-      r->w_stall_open = true;  // until it is written (see ShmRing)
-      r->w_stall_t0 = r->w_stall_mark = std::chrono::steady_clock::now();
-      bump_rlx(&h->w_stalls, 1);
+  uint8_t pre[kSgPrefix];
+  std::memcpy(pre, "SGC2", 4);
+  put_be64(pre + 4, static_cast<uint64_t>(xfer));
+  int64_t n = 0;
+  *status = 0;
+  for (int64_t idx = first; idx < nchunks; ++idx, ++n) {
+    put_be64(pre + 12, static_cast<uint64_t>(idx));
+    int64_t off = idx * chunk;
+    int64_t flen = nbytes - off < chunk ? nbytes - off : chunk;
+    if (flen < 0) flen = 0;
+    const uint8_t* parts[2] = {pre, payload + off};
+    const int64_t lens[2] = {static_cast<int64_t>(kSgPrefix), flen};
+    int rc = put_record(r, tag, parts, lens, 2, dl);
+    if (rc != 0) {
+      *status = rc;
+      break;
     }
-    if (pid_dead(h->consumer_pid)) {
-      credit();
-      r->w_stall_open = false;
-      return -3;
-    }
-    if (dl.expired()) {
-      credit();
-      return -1;  // the stall stays open: the caller retries
-    }
-    ring_nap();
   }
-  uint64_t waited = 0;
-  if (r->w_stall_open) {
-    credit();
-    r->w_stall_open = false;
-    waited = ns_between(r->w_stall_t0, r->w_stall_mark);
-  }
-  uint8_t rec[kRecHdr];
-  uint32_t l32 = static_cast<uint32_t>(plen);
-  std::memcpy(rec, &l32, 4);
-  std::memcpy(rec + 4, &tag, 4);
-  ring_put(r, w, rec, kRecHdr);
-  uint64_t pos = w + kRecHdr;
-  for (int32_t i = 0; i < nparts; ++i) {
-    ring_put(r, pos, parts[i], static_cast<size_t>(lens[i]));
-    pos += static_cast<uint64_t>(lens[i]);
-  }
-  store_rel(&h->widx, w + total);
-  bump_rlx(&h->w_frames, 1);
-  bump_rlx(&h->w_bytes, plen);
-  max_rlx(&h->hwm, (w + total) - load_acq(&h->ridx));
-  uint64_t xfer, idx;
-  if (sg_peek(parts, lens, nparts, &xfer, &idx))
-    ompitpu::nativeev_emit(
-        tag, xfer,
-        static_cast<uint32_t>(plen - kSgPrefix),
-        static_cast<uint32_t>(idx), /*recv_side=*/false, waited);
-  return 0;
+  return n;
 }
 
 // Consumer side, fragment fast path: pop the head record IF it is an
@@ -420,67 +559,38 @@ int shmring_writev(void* vr, int32_t tag, const uint8_t** parts,
 int64_t shmring_read_frag(void* vr, int32_t tag, int64_t xfer,
                           int64_t nchunks, int64_t chunk, uint8_t* base,
                           int64_t nbytes, int timeout_ms) {
+  return take_frag(static_cast<ShmRing*>(vr), tag, xfer, nchunks, chunk,
+                   base, nbytes, Deadline(timeout_ms), nullptr);
+}
+
+// Consumer side, a message's payload in one call: land up to `want`
+// fragments of (tag, xfer) at base + idx * chunk, all inside one wait
+// of `timeout_ms`; stale same-tag fragments are dropped on the way.
+// Returns how many landed; *status says why it stopped: 0 all `want`
+// are in, else shmring_read_frag's code for what the caller has to
+// handle (-1 nothing more came in this slice, -2, -3, -5). `crc` is
+// the transfer's running checksum across calls (see take_frag): the
+// caller starts it at {0, 0} and, once every fragment is in, holds
+// crc[0] against the header's CRC if crc[1] == nchunks.
+int64_t shmring_read_msg(void* vr, int32_t tag, int64_t xfer,
+                         int64_t nchunks, int64_t chunk, uint8_t* base,
+                         int64_t nbytes, int64_t want, int timeout_ms,
+                         int64_t* crc, int32_t* status) {
   auto* r = static_cast<ShmRing*>(vr);
-  RingHdr* h = hdr(r);
   Deadline dl(timeout_ms);
-  StallTimer stall(&h->r_stalls, &h->r_stall_ns);
-  uint64_t rd = h->ridx;  // we are the only reader
-  for (;;) {
-    if (load_acq(&h->widx) != rd) break;
-    stall.arm();  // ring empty: this read is a stall until data lands
-    if (pid_dead(h->producer_pid)) {
-      stall.settle();
-      return -3;
+  int64_t n = 0;
+  *status = 0;
+  while (n < want) {
+    int64_t rc = take_frag(r, tag, xfer, nchunks, chunk, base, nbytes,
+                           dl, crc);
+    if (rc >= 0) {
+      ++n;
+    } else if (rc != -4) {
+      *status = static_cast<int32_t>(rc);
+      break;
     }
-    if (dl.expired()) {
-      stall.settle();
-      return -1;
-    }
-    ring_nap();
   }
-  uint64_t waited = stall.settle();
-  uint8_t rec[kRecHdr];
-  ring_get(r, rd, rec, kRecHdr);
-  uint32_t plen;
-  int32_t rtag;
-  std::memcpy(&plen, rec, 4);
-  std::memcpy(&rtag, rec + 4, 4);
-  if (rtag != tag) return -5;
-  uint64_t next = rd + kRecHdr + plen;
-  if (plen < kSgPrefix) {
-    store_rel(&h->ridx, next);
-    bump_rlx(&h->r_frames, 1);
-    bump_rlx(&h->r_bytes, plen);
-    return -4;
-  }
-  uint8_t pre[kSgPrefix];
-  ring_get(r, rd + kRecHdr, pre, kSgPrefix);
-  if (std::memcmp(pre, "SGC2", 4) != 0 ||
-      be64(pre + 4) != static_cast<uint64_t>(xfer)) {
-    store_rel(&h->ridx, next);
-    bump_rlx(&h->r_frames, 1);
-    bump_rlx(&h->r_bytes, plen);
-    return -4;
-  }
-  int64_t idx = static_cast<int64_t>(be64(pre + 12));
-  int64_t flen = static_cast<int64_t>(plen - kSgPrefix);
-  if (idx < 0 || idx >= nchunks || idx * chunk + flen > nbytes) {
-    store_rel(&h->ridx, next);
-    bump_rlx(&h->r_frames, 1);
-    bump_rlx(&h->r_bytes, plen);
-    return -2;
-  }
-  if (flen)
-    ring_get(r, rd + kRecHdr + kSgPrefix, base + idx * chunk,
-             static_cast<size_t>(flen));
-  store_rel(&h->ridx, next);
-  bump_rlx(&h->r_frames, 1);
-  bump_rlx(&h->r_bytes, plen);
-  ompitpu::nativeev_emit(tag, static_cast<uint64_t>(xfer),
-                         static_cast<uint32_t>(flen),
-                         static_cast<uint32_t>(idx),
-                         /*recv_side=*/true, waited);
-  return idx;
+  return n;
 }
 
 // Consumer side, generic pop: copy the head record's payload into

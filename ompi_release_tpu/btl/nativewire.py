@@ -5,11 +5,14 @@ One component, two transports, selected per peer from the modex
 business cards exactly like :meth:`WireRouter._btl_for`:
 
 * **co-hosted peers** ride a shared-memory SPSC byte ring
-  (``native/btl_shm.cc``): the sender's ``writev`` gathers the
-  precomposed SGH2 fragment parts straight into the mapped ring, the
-  receiver's ``read_frag`` memcpys each fragment payload directly into
-  the preallocated reassembly buffer — zero Python-side copies on the
-  whole byte path.
+  (``native/btl_shm.cc``), a message's payload in ONE native call a
+  side: the sender's ``write_msg`` writes every SGC2 fragment record
+  of the message straight from the source buffer into the mapped
+  ring, the receiver's ``read_msg`` copies every fragment directly
+  into the reassembly buffer and checksums it inside that copy — no
+  Python-side copy and no Python call per fragment; a call hands back
+  early only for what Python has to handle (a ring that stayed full,
+  another tag's record at the head, an empty slice, a dead peer).
 * **cross-host peers** ride vectored socket IO over the existing OOB
   mesh (``native/btl_tcp.cc``): ``wire_sendv`` writev's the frame
   header plus scatter-gather parts in one syscall (byte-identical on
@@ -38,12 +41,12 @@ SPEAK (legacy SGH1, portable SGH2) because it subclasses
 from __future__ import annotations
 
 import atexit
+import ctypes
 import os
 import threading
 import time as _time
 import uuid
 import weakref
-import zlib
 from typing import Dict, Optional, Tuple
 
 import numpy as np
@@ -94,6 +97,19 @@ _native_bytes = _pvar.counter(
 _native_frames = _pvar.counter(
     "wire_native_frames",
     "SGC2 fragment frames moved by the nativewire datapath",
+)
+_native_msg_calls = _pvar.counter(
+    "wire_native_msg_calls",
+    "native calls made for message payloads on the shm-ring leg "
+    "(ring.write_msg at the sender, ring.read_msg at the receiver)",
+)
+_native_msgs = _pvar.counter(
+    "wire_native_msgs",
+    "messages whose payload crossed the shm-ring leg (sent plus "
+    "received); wire_native_msg_calls / wire_native_msgs is 1.0 when "
+    "every message crossed whole, above it where a call handed back "
+    "mid-message: a ring that stayed full, another tag's record at "
+    "the head, a slice with no more fragments",
 )
 _fallback_copies = _pvar.counter(
     "wire_native_fallback_copies",
@@ -467,6 +483,14 @@ def _host_array(data) -> Tuple[np.ndarray, bool]:
     return out, copied
 
 
+def _native_crc32(buf, prior: int = 0) -> int:
+    """``zlib.crc32``'s value by the library's routine
+    (``native/crc32.h``), which the ring's one-call read also chains."""
+    from ..native import crc32
+
+    return crc32(buf, prior)
+
+
 def _retry_send(fn, what: str):
     """The wire router's first-contact backoff, minus its FT lookups
     (this module has no router handle): a confirmed process failure
@@ -753,19 +777,22 @@ class NativeWireBtl(DcnBtl):
                 rlk.release()
         return moved
 
-    def _ring_put(self, ring, lk, oob_ep, peer_pidx: int, tag: int,
-                  parts) -> None:
+    def _ring_put_msg(self, ring, lk, oob_ep, peer_pidx: int, tag: int,
+                      xfer: int, u8, tpl, first: int) -> int:
+        """Fragments ``first`` .. of one message into the peer's ring;
+        returns how many went in (at least one): all that are left
+        unless a native call handed back on a full ring after some."""
         deadline = _time.monotonic() + _SEND_TIMEOUT_MS / 1000
         slice_ms = _FULL_RING_LOOK_MS
         tok = None
         if _watchdog.enabled:
-            # a full-ring wait blocks INSIDE ring.writev, in slices;
+            # a full-ring wait blocks INSIDE ring.write_msg, in slices;
             # the ring keeps the stall open across them, so however
-            # many it takes this send is ONE w_stalls count and its
-            # stall time runs to the write (native/btl_shm.cc). The
-            # zero-arg info resolves at dump time, so the postmortem
-            # names the ring, its consumer, and the LIVE occupancy at
-            # the moment the watchdog fired
+            # many it takes a blocked fragment is ONE w_stalls count
+            # and its stall time runs to the write (native/btl_shm.cc).
+            # The zero-arg info resolves at dump time, so the
+            # postmortem names the ring, its consumer, and the LIVE
+            # occupancy at the moment the watchdog fired
             tok = _watchdog.arm(
                 "nw_ring_put", peer=peer_pidx,
                 info=lambda r=ring, p=peer_pidx: _ring_wait_info(
@@ -775,9 +802,10 @@ class NativeWireBtl(DcnBtl):
                 while True:
                     left = max(1, int((deadline - _time.monotonic())
                                       * 1000))
-                    rc = ring.writev(tag, parts, min(left, slice_ms))
-                    if rc == 0:
-                        return
+                    n, rc = ring.write_msg(
+                        tag, xfer, u8, tpl.chunk, first, tpl.nchunks,
+                        min(left, slice_ms))
+                    _native_msg_calls.add()
                     if rc == -3:
                         raise MPIError(
                             ErrorCode.ERR_PROC_FAILED,
@@ -788,9 +816,16 @@ class NativeWireBtl(DcnBtl):
                     if rc == -2:
                         # frame can NEVER fit this ring: the vectored
                         # socket loopback carries it, still zero-copy
-                        oob_ep.sendv(peer_pidx + 1, tag, parts)
-                        return
-                    if _time.monotonic() >= deadline:
+                        i = first + n
+                        off = tpl.offsets[i]
+                        oob_ep.sendv(peer_pidx + 1, tag, [
+                            _CHUNK2_MAGIC + int(xfer).to_bytes(8, "big"),
+                            tpl.idx_tails[i],
+                            memoryview(u8)[off:off + tpl.chunk]])
+                        return n + 1
+                    if rc == 0:
+                        return n
+                    if not n and _time.monotonic() >= deadline:
                         raise MPIError(
                             ErrorCode.ERR_PENDING,
                             f"shm ring to process {peer_pidx} stayed "
@@ -813,29 +848,37 @@ class NativeWireBtl(DcnBtl):
                     # into a stash.)
                     slice_ms = (_FULL_RING_LOOK_MS
                                 if self._stash_inbound() else 2000)
+                    if n:
+                        return n  # the caller resumes from first + n
         finally:
             if tok is not None:
                 _watchdog.disarm(tok)
 
     def frame_stream(self, oob_ep, peer_pidx: int, tag: int, data,
                      tpl=None):
-        """Side-effecting generator, one wire frame per ``next()`` —
-        the native twin of the router's planned/staged frame streams,
-        so QoS striping and the in-flight window discipline apply to
-        native transfers unchanged. The header frame rides the
-        portable OOB send (sentinels, any-source peeks and flow ids
-        depend on seeing it there); fragments ride the ring or the
-        vectored socket as scatter-gather part lists."""
+        """Side-effecting generator — the native twin of the router's
+        planned/staged frame streams, so QoS striping and the
+        in-flight window discipline apply to native transfers. The
+        header frame rides the portable OOB send (sentinels,
+        any-source peeks and flow ids depend on seeing it there) and
+        is one ``next()``. To a co-hosted peer the payload then goes
+        into the ring in one native call (``ring.write_msg``: every
+        fragment record, written in C) and one ``next()`` — more only
+        when a call hands back mid-message on a full ring, one
+        ``next()`` per call that moved something; to a cross-host peer
+        each fragment is a vectored socket send and a ``next()`` of
+        its own. So a stream never yields more often than it has
+        frames. Returns the number of fragments sent."""
         _check_user_tag(tag)
         nid = peer_pidx + 1
         seg = self.pipeline_segsize()
         if not self.peer_capable(peer_pidx) or seg <= 0:
             # portable framing end-to-end (legacy SGH1 when seg==0)
-            _retry_send(
+            sent = _retry_send(
                 lambda: DcnBtl.send_staged(self, oob_ep, nid, tag, data),
                 f"staged transfer to process {peer_pidx}")
             yield
-            return
+            return sent
         rec = _obs.enabled  # capture once: flag may flip mid-send
         t0 = _time.perf_counter() if rec else 0.0
         _track_ep(oob_ep)  # tcp-leg counters fold from its C struct
@@ -852,10 +895,10 @@ class NativeWireBtl(DcnBtl):
             )
         if tpl is None:
             tpl = _template_for(arr.shape, arr.dtype, seg)
-        mv = memoryview(arr.reshape(-1).view(np.uint8)) if arr.size \
-            else memoryview(b"")
+        u8 = arr.reshape(-1).view(np.uint8) if arr.size \
+            else np.empty(0, np.uint8)
         xfer = next(_c._xfer_ids)
-        frames = tpl.sg_lists(mv, xfer, zlib.crc32(mv))
+        frames = tpl.sg_lists(memoryview(u8), xfer, _native_crc32(u8))
         header = b"".join(next(frames))
         ring = lk = None
         if self._same_host(peer_pidx):
@@ -866,30 +909,44 @@ class NativeWireBtl(DcnBtl):
         _retry_send(lambda: oob_ep.send(nid, tag, header),
                     f"native header to process {peer_pidx}")
         yield
-        for parts in frames:
-            plen = len(parts[-1])
-            if ring is not None:
-                self._ring_put(ring, lk, oob_ep, peer_pidx, tag, parts)
-            else:
+
+        def moved(n: int, plen: int) -> None:
+            _zero_copy_strict.add(plen)
+            _native_bytes.add(plen)
+            _native_frames.add(n)
+            self.staged_chunks_pvar.add(n)
+
+        if ring is not None:
+            done = 0
+            while done < tpl.nchunks:
+                n = self._ring_put_msg(ring, lk, oob_ep, peer_pidx, tag,
+                                       xfer, u8, tpl, done)
+                moved(n, min(tpl.nbytes, (done + n) * tpl.chunk)
+                      - done * tpl.chunk)
+                done += n
+                yield
+            _native_msgs.add()
+        else:
+            for parts in frames:
                 _retry_send(
                     lambda p=parts: oob_ep.sendv(nid, tag, p),
                     f"native fragment to process {peer_pidx}")
-            _zero_copy_strict.add(plen)
-            _native_bytes.add(plen)
-            _native_frames.add()
-            self.staged_chunks_pvar.add()
-            yield
+                moved(1, len(parts[-1]))
+                yield
         self.staged_bytes_pvar.add(tpl.nbytes)
         if rec and _obs.enabled:
             _obs.record("btl_nw_send", "btl", t0,
                         _time.perf_counter() - t0,
                         nbytes=int(tpl.nbytes), peer=peer_pidx)
+        return tpl.nchunks
 
     def send_staged(self, oob_ep, peer_nid: int, tag: int, data) -> int:
-        n = 0
-        for _ in self.frame_stream(oob_ep, peer_nid - 1, tag, data):
-            n += 1
-        return max(0, n - 1)  # header is not a chunk
+        frames = self.frame_stream(oob_ep, peer_nid - 1, tag, data)
+        while True:
+            try:
+                next(frames)
+            except StopIteration as end:
+                return int(end.value or 0)
 
     # -- receive side ------------------------------------------------------
     @staticmethod
@@ -909,8 +966,10 @@ class NativeWireBtl(DcnBtl):
         """Native reassembly: the header is popped/parsed exactly like
         the portable path (shared stash, shared resync discipline);
         SGH2 fragments from a capable co-hosted sender then come out
-        of the shm ring, from a capable cross-host sender out of the
-        native frame queue — both memcpy'd straight into the
+        of the shm ring — all of them in one ``ring.read_msg`` call,
+        which checksums them inside its copy, unless something Python
+        must handle turns up — from a capable cross-host sender out
+        of the native frame queue; both memcpy'd straight into the
         preallocated buffer. Everything else (legacy SGH1, a sender
         that never advertised the capability) resumes the portable
         reassembly with the already-popped header."""
@@ -955,8 +1014,15 @@ class NativeWireBtl(DcnBtl):
             raise MPIError(ErrorCode.ERR_TRUNCATE,
                            f"staged transfer {xfer}: malformed "
                            f"shape {shape}")
-        buf = bytearray(nbytes)
+        # every byte is overwritten, or the transfer fails its fragment
+        # count or its CRC: no zero-fill
+        buf = np.empty(nbytes, np.uint8)
         bmv = memoryview(buf)
+        # the running CRC-32 of the fragments placed in order so far and
+        # the next index of that order (-1 once one came out of it):
+        # ring.read_msg chains it inside its copy, place() below for
+        # frames that came through a stash
+        crc_run = (ctypes.c_int64 * 2)(0, 0)
         want = _CHUNK2_MAGIC + int(xfer).to_bytes(8, "big")
         _frags_inflight.set(int(nchunks))
         nchunks, chunk = int(nchunks), int(chunk)
@@ -980,12 +1046,17 @@ class NativeWireBtl(DcnBtl):
                     f"the {nbytes}-byte buffer",
                 )
             bmv[off:off + len(payload)] = payload
+            if crc_run[1] == idx:
+                crc_run[0] = _native_crc32(payload, crc_run[0])
+                crc_run[1] = idx + 1
+            else:
+                crc_run[1] = -1
             return True
 
         got = 0
         tok = None
         if ring_ent is not None and _watchdog.enabled:
-            # the empty-ring wait blocks inside ring.read_frag (C
+            # the empty-ring wait blocks inside ring.read_msg (C
             # slices of <=200ms): name the ring, its producer, and the
             # live occupancy in any stall postmortem
             tok = _watchdog.arm(
@@ -1012,12 +1083,16 @@ class NativeWireBtl(DcnBtl):
                 if ring_ent is not None:
                     ring, rlk, rstash = ring_ent
                     restash = None
+                    # both stashes are looked at before the call and
+                    # again only when it hands back
                     with rlk:
                         q = rstash.get(tag)
                         praw = q.pop(0) if q else None
                         if praw is None:
-                            rc = ring.read_frag(tag, xfer, nchunks,
-                                                chunk, buf, step)
+                            n, rc = ring.read_msg(
+                                tag, xfer, nchunks, chunk, buf,
+                                nchunks - got, step, crc_run)
+                            _native_msg_calls.add()
                             if rc == -5:
                                 restash = self._pop_other_locked(ring)
                     if praw is not None:
@@ -1025,17 +1100,15 @@ class NativeWireBtl(DcnBtl):
                             got += 1
                             self.staged_chunks_pvar.add()
                         continue
+                    got += n
+                    self.staged_chunks_pvar.add(n)
                     if restash is not None:
                         _rlen, rtag, raw2, _scratch = restash
                         with rlk:
                             rstash.setdefault(rtag, []).append(raw2)
                         _fallback_copies.add()  # the one restash copy
                         continue
-                    if rc >= 0:
-                        got += 1
-                        self.staged_chunks_pvar.add()
-                        continue
-                    if rc == -1:
+                    if rc == -1 and not n:
                         # nothing came in a whole slice. Its sender may
                         # be parked on a full ring to a process that is
                         # itself parked in a read like this one — with
@@ -1043,11 +1116,11 @@ class NativeWireBtl(DcnBtl):
                         # which reads from 2, which writes to 1, which
                         # reads from 0): a reader has to take what is
                         # queued for it on its OTHER inbound rings, as
-                        # a sender on a full ring does (_ring_put)
+                        # a sender on a full ring does (_ring_put_msg)
                         self._stash_inbound()
                         continue
-                    if rc in (-4, -5):
-                        continue  # stale / raced
+                    if rc in (0, -1, -5):
+                        continue  # all in / slice over / head raced
                     if rc == -3:
                         raise MPIError(
                             ErrorCode.ERR_PROC_FAILED,
@@ -1064,6 +1137,7 @@ class NativeWireBtl(DcnBtl):
                     rc = oob_ep.recv_frag(src, tag, xfer, nchunks,
                                           chunk, buf, step)
                     if rc >= 0:
+                        crc_run[1] = -1  # landed unchecksummed
                         got += 1
                         self.staged_chunks_pvar.add()
                         continue
@@ -1091,7 +1165,11 @@ class NativeWireBtl(DcnBtl):
         finally:
             if tok is not None:
                 _watchdog.disarm(tok)
-        if zlib.crc32(bmv) != int(crc):
+        if ring_ent is not None:
+            _native_msgs.add()
+        if crc_run[1] != nchunks:  # not all in order: one pass now
+            crc_run[0] = _native_crc32(buf)
+        if crc_run[0] != int(crc):
             raise MPIError(
                 ErrorCode.ERR_TRUNCATE,
                 f"staged transfer {xfer} failed its payload CRC — "
@@ -1099,7 +1177,7 @@ class NativeWireBtl(DcnBtl):
             )
         _zero_copy_strict.add(nbytes)
         _native_bytes.add(nbytes)
-        arr = np.frombuffer(buf, dtype=dtype).reshape(shape)
+        arr = buf.view(dtype).reshape(shape)
         self.staged_bytes_pvar.add(arr.nbytes)
         if rec and _obs.enabled:
             _obs.record("btl_nw_recv", "btl", t_obs,

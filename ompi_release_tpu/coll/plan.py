@@ -582,8 +582,10 @@ def freeze_wire_plan(comm, recorded: List[Tuple[Tuple, Tuple]],
                 tpls.append(tpl)
             peer_slots.append((p, tuple(tpls)))
             # frames a stream will emit: header + fragments for a
-            # templated message, one frame otherwise — exact, so the
-            # striper can drop a drained stream without gating it
+            # templated message, one frame otherwise — exact for the
+            # portable streams and never less than a native stream's
+            # steps, so the striper can drop a drained stream without
+            # gating it
             frame_counts.append(sum(
                 (int(t.nchunks) + 1) if t is not None else 1
                 for t in tpls))

@@ -6,5 +6,6 @@ pybind11 is not, so the C ABI + ctypes is the binding layer).
 
 from .bindings import (  # noqa: F401
     USER_TAG_BASE, DssBuffer, NativeEventRing, OobEndpoint, ShmRing,
-    load_library, telemetry_symbols_available, wire_symbols_available,
+    crc32, load_library, telemetry_symbols_available,
+    wire_symbols_available,
 )

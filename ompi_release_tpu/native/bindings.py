@@ -34,7 +34,7 @@ _lib_lock = threading.Lock()
 #: files; order is irrelevant, the comparison is by name)
 _STAMP_INPUTS = ("dss.cc", "oob.cc", "btl_tcp.cc", "btl_shm.cc",
                  "nativeev.cc", "planexec.cc", "oob_endpoint.h",
-                 "nativeev.h", "Makefile")
+                 "nativeev.h", "crc32.h", "Makefile")
 _STAMP_PATH = os.path.join(_NATIVE_DIR, "build", ".srcstamp")
 
 
@@ -236,6 +236,17 @@ def _declare(lib: ctypes.CDLL) -> None:
         lib.shmring_read_into.argtypes = [P, i32p, P, ctypes.c_int64,
                                           ctypes.c_int]
         lib.shmring_read_into.restype = ctypes.c_int64
+    if hasattr(lib, "shmring_write_msg"):
+        lib.shmring_write_msg.argtypes = [
+            P, ctypes.c_int32, ctypes.c_int64, P, ctypes.c_int64,
+            ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int, i32p]
+        lib.shmring_write_msg.restype = ctypes.c_int64
+        lib.shmring_read_msg.argtypes = [
+            P, ctypes.c_int32, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, P, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int, i64p, i32p]
+        lib.shmring_read_msg.restype = ctypes.c_int64
     if hasattr(lib, "shmring_stat"):
         lib.shmring_stat.argtypes = [P, ctypes.c_int32]
         lib.shmring_stat.restype = ctypes.c_int64
@@ -310,7 +321,9 @@ def wire_symbols_available() -> bool:
         lib = load_library()
     except Exception:
         return False
-    return hasattr(lib, "wire_sendv") and hasattr(lib, "shmring_create")
+    return (hasattr(lib, "wire_sendv")
+            and hasattr(lib, "shmring_write_msg")
+            and hasattr(lib, "planexec_crc32"))
 
 
 def telemetry_symbols_available() -> bool:
@@ -375,6 +388,20 @@ def _sg_arrays(parts):
             lens[i] = a.nbytes
             keep.append(a)
     return ptrs, lens, keep
+
+
+def crc32(data, prior: int = 0) -> int:
+    """``zlib.crc32(data, prior)`` by the library's own routine
+    (``native/crc32.h``: carry-less multiply where the CPU has it,
+    slicing tables elsewhere) over ``data``'s buffer in place, the GIL
+    released — the nativewire sender's checksum and the verification
+    of whatever the ring's one-call read did not checksum in its copy."""
+    import numpy as _np
+
+    a = _np.frombuffer(data, dtype=_np.uint8)  # zero-copy view
+    return int(load_library().planexec_crc32(
+        prior, ctypes.cast(a.ctypes.data, ctypes.POINTER(ctypes.c_uint8)),
+        a.nbytes, 0))
 
 
 def _wbuf_ptr(buf):
@@ -454,31 +481,43 @@ class DssBuffer:
                            f"dss buffer exhausted unpacking {what}")
         return n
 
+    def _room(self, bound: int, extra: int = 0) -> int:
+        """Scratch elements for the next item: what it holds (plus
+        ``extra``), not the caller's bound — a ctypes array is
+        zero-filled, and the default bound of ``unpack_int64`` is 8 MiB
+        of it per call (a receiver parses two per staged header)."""
+        nxt = self.peek()
+        return max(1, min(bound, (nxt[1] if nxt else 0) + extra))
+
     def unpack_int64(self, max_count: int = 1_048_576) -> List[int]:
-        arr = (ctypes.c_int64 * max_count)()
+        room = self._room(max_count)
+        arr = (ctypes.c_int64 * room)()
         n = self._check(
-            self._lib.dss_unpack_int64(self._h, arr, max_count), "int64"
+            self._lib.dss_unpack_int64(self._h, arr, room), "int64"
         )
         return list(arr[:n])
 
     def unpack_double(self, max_count: int = 1_048_576) -> List[float]:
-        arr = (ctypes.c_double * max_count)()
+        room = self._room(max_count)
+        arr = (ctypes.c_double * room)()
         n = self._check(
-            self._lib.dss_unpack_double(self._h, arr, max_count), "double"
+            self._lib.dss_unpack_double(self._h, arr, room), "double"
         )
         return list(arr[:n])
 
     def unpack_string(self, max_len: int = 1 << 20) -> str:
-        buf = ctypes.create_string_buffer(max_len)
+        room = self._room(max_len, extra=1)  # the terminating NUL
+        buf = ctypes.create_string_buffer(room)
         self._check(
-            self._lib.dss_unpack_string(self._h, buf, max_len), "string"
+            self._lib.dss_unpack_string(self._h, buf, room), "string"
         )
         return buf.value.decode()
 
     def unpack_bytes(self, max_len: int = 1 << 26) -> bytes:
-        arr = (ctypes.c_uint8 * max_len)()
+        room = self._room(max_len)
+        arr = (ctypes.c_uint8 * room)()
         n = self._check(
-            self._lib.dss_unpack_bytes(self._h, arr, max_len), "bytes"
+            self._lib.dss_unpack_bytes(self._h, arr, room), "bytes"
         )
         return bytes(arr[:n])
 
@@ -692,7 +731,9 @@ class ShmRing:
     btl component's job, not the binding's. Ring protocol status codes
     (native/btl_shm.cc): writev 0/-1 timeout/-2 never-fits/-3 dead;
     read_frag idx/-1/-2 consumed-bad/-3 dead/-4 stale-dropped/-5
-    other-tag-left; read_into len/-1/-2 too-small/-3 dead."""
+    other-tag-left; read_into len/-1/-2 too-small/-3 dead; write_msg
+    and read_msg hand back (fragments moved, 0 or the code of the
+    fragment they stopped at)."""
 
     def __init__(self, handle, name: str) -> None:
         self._lib = load_library()
@@ -758,6 +799,38 @@ class ShmRing:
                                          timeout_ms)
         del keep
         return int(rc)
+
+    def write_msg(self, tag: int, xfer: int, payload, chunk: int,
+                  first: int, nchunks: int, timeout_ms: int):
+        """Fragments ``first`` .. ``nchunks - 1`` of transfer ``xfer``
+        (``payload`` cut every ``chunk`` bytes, each behind its SGC2
+        prefix) in one call: (fragments written, 0 or writev's code for
+        the fragment it stopped at)."""
+        import numpy as _np
+
+        a = _np.frombuffer(payload, dtype=_np.uint8)  # zero-copy view
+        status = ctypes.c_int32()
+        n = self._lib.shmring_write_msg(
+            self._handle(), tag, xfer, ctypes.c_void_p(a.ctypes.data),
+            a.nbytes, chunk, first, nchunks, timeout_ms,
+            ctypes.byref(status))
+        return int(n), status.value
+
+    def read_msg(self, tag: int, xfer: int, nchunks: int, chunk: int,
+                 buf, want: int, timeout_ms: int, crc):
+        """Up to ``want`` fragments of (tag, xfer) into ``buf`` in one
+        call: (fragments landed, 0 or read_frag's code for what stopped
+        it). ``crc``: a ``(c_int64 * 2)`` the caller starts at (0, 0)
+        and passes to every call of one transfer — the CRC-32 of the
+        fragments landed in order so far, and the next index of that
+        order (-1 once a fragment came out of it)."""
+        base, nbytes, keep = _wbuf_ptr(buf)
+        status = ctypes.c_int32()
+        n = self._lib.shmring_read_msg(
+            self._handle(), tag, xfer, nchunks, chunk, base, nbytes,
+            want, timeout_ms, crc, ctypes.byref(status))
+        del keep
+        return int(n), status.value
 
     def read_into(self, buf, timeout_ms: int):
         """Generic pop of the head record: (status_or_len, tag)."""

@@ -908,10 +908,12 @@ class WireRouter:
         for k, a in enumerate(arrs):
             tpl = templates[k] if templates is not None else None
             if btl is self._nw and btl is not None:
-                # native datapath: the stream does its own sends (ring
-                # writev / vectored sockets) with its own retry + typed
-                # fault mapping; frames and yields stay 1:1 with the
-                # portable stream so striping/QoS see the same shape
+                # native datapath: the stream does its own sends (one
+                # ring.write_msg per message / vectored sockets) with
+                # its own retry + typed fault mapping; it yields once
+                # for the header and once per native call that moved
+                # payload — never more often than the portable stream
+                # has frames, so the striper's frame counts bound it
                 for _ in btl.frame_stream(self.ep, peer, tag, a,
                                           tpl=tpl):
                     yield
@@ -985,11 +987,14 @@ class WireRouter:
         bursts at the class weight ratio instead of FIFO-hogging the
         endpoint.
 
-        ``counts`` (frozen plans only): exact frames left per stream.
-        A drained stream is dropped WITHOUT passing the gate — a
-        solo-class short tail must not buy window it will never use —
-        and a final partial burst is gated at its real cost, not the
-        full depth."""
+        ``counts`` (frozen plans only): frames left per stream — exact
+        for the portable streams, an upper bound for a native stream
+        to a co-hosted peer (one step per message payload, not per
+        fragment; it ends on ``StopIteration``). A stream whose count
+        is spent is dropped WITHOUT passing the gate — a solo-class
+        short tail must not buy window it will never use — and a
+        final partial burst is gated at its real cost, not the full
+        depth."""
         if arbiter is not None:
             arbiter.enter(cls)
         try:

@@ -2,16 +2,21 @@
 coordinator (the oob_stress / orte system-test analogue, SURVEY §4.3 —
 real processes over localhost)."""
 
+import ctypes
 import json
+import os
 import subprocess
 import sys
 import textwrap
+import zlib
 
+import numpy as np
 import pytest
 
-from ompi_release_tpu.native import DssBuffer, OobEndpoint
+from ompi_release_tpu.native import DssBuffer, OobEndpoint, ShmRing, crc32
+from ompi_release_tpu.native import bindings as nb
 from ompi_release_tpu.runtime.coordinator import HnpCoordinator
-from ompi_release_tpu.utils.errors import MPIError
+from ompi_release_tpu.utils.errors import ErrorCode, MPIError
 
 
 class TestDss:
@@ -50,6 +55,248 @@ class TestDss:
         assert raw.unpack_string() == "again"
         raw.rewind()
         assert raw.unpack_string() == "again"
+
+    def test_unpack_scratch_is_the_items_size_not_the_bound(self):
+        """A ctypes scratch array is zero-filled: sized by the default
+        bound it was 8 MiB per ``unpack_int64()`` — two of them in
+        every staged header a receiver parses. The scratch is what the
+        next item holds; the bound still refuses a longer item and
+        leaves the cursor where it was."""
+        b = DssBuffer()
+        b.pack_int64([5, 6, 7]).pack_string("abc").pack_bytes(b"xy")
+        b.pack_double([1.5])
+        r = DssBuffer(b.tobytes())
+        assert r._room(1_048_576) == 3
+        with pytest.raises(MPIError) as ei:
+            r.unpack_int64(max_count=2)  # the item is longer: refused
+        assert ei.value.code == ErrorCode.ERR_TYPE
+        assert r.unpack_int64(max_count=3) == [5, 6, 7]
+        assert r._room(1 << 20, extra=1) == 4
+        with pytest.raises(MPIError):
+            r.unpack_string(max_len=3)  # no room for the NUL
+        assert r.unpack_string(max_len=4) == "abc"
+        assert r.unpack_bytes() == b"xy"
+        assert r.unpack_double() == [1.5]
+        assert r._room(8) == 1  # exhausted: one element, then the error
+        with pytest.raises(MPIError) as ei:
+            r.unpack_int64()
+        assert ei.value.code == ErrorCode.ERR_TRUNCATE
+
+
+_CRC_SIZES = [0, 1, 63, 64, 65, (1 << 20) - 1, 1 << 20, (1 << 20) + 1]
+
+
+class TestSharedCrc:
+    """``native/crc32.h`` is the one CRC of the library: the plan
+    executor's, the ring's one-call read and — through
+    ``native.crc32`` — the nativewire sender's. Its value is
+    ``zlib.crc32``'s (the header frame of a mixed fleet depends on
+    it), on the CPU's carry-less-multiply path and on the tables, from
+    any start and chained over any cut."""
+
+    @staticmethod
+    def _crc(path, buf, lo, hi, prior=0):
+        if path == "cpu":  # what the wire calls
+            return crc32(memoryview(buf)[lo:hi], prior)
+        ptr = ctypes.cast(buf.ctypes.data + lo,
+                          ctypes.POINTER(ctypes.c_uint8))
+        return int(nb.load_library().planexec_crc32(prior, ptr, hi - lo, 1))
+
+    @pytest.mark.parametrize("path", ["cpu", "tables"])
+    @pytest.mark.parametrize("n", _CRC_SIZES)
+    def test_equals_zlib_from_any_start(self, n, path):
+        buf = np.random.default_rng(n).integers(0, 256, n + 16,
+                                                dtype=np.uint8)
+        raw = buf.tobytes()
+        for off in (0, 1, 3, 7, 13):
+            assert self._crc(path, buf, off, off + n) == \
+                zlib.crc32(raw[off:off + n]), (n, off)
+
+    @pytest.mark.parametrize("path", ["cpu", "tables"])
+    @pytest.mark.parametrize(
+        "n,chunk", [(n, c) for n in _CRC_SIZES
+                    for c in (1, 61, 4096, 1 << 18) if n // c <= 5000])
+    def test_chained_over_fragment_cuts(self, n, chunk, path):
+        buf = np.random.default_rng(n + chunk).integers(
+            0, 256, n, dtype=np.uint8)
+        got = 0
+        for lo in range(0, n, chunk):
+            got = self._crc(path, buf, lo, min(n, lo + chunk), got)
+        assert got == zlib.crc32(buf.tobytes())
+
+
+@pytest.fixture
+def ring_pair():
+    """A producer and a consumer handle on one small ring."""
+    made = []
+
+    def make(capacity):
+        name = f"/onw-msgtest-{os.getpid()}-{len(made)}"
+        ShmRing.unlink(name)
+        tx = ShmRing.create(name, capacity, os.getpid())
+        rx = ShmRing.attach(name, os.getpid())
+        made.append((name, tx, rx))
+        return tx, rx
+
+    yield make
+    for name, tx, rx in made:
+        rx.close()
+        tx.close()
+        ShmRing.unlink(name)
+
+
+class TestRingMessageCalls:
+    """``shmring_write_msg`` / ``shmring_read_msg``: a message's
+    fragment records in one call a side, handing back only for what
+    the caller has to deal with."""
+
+    TAG, XFER = 41, 9001
+
+    @staticmethod
+    def _payload(n):
+        return np.random.default_rng(n).integers(0, 256, n, dtype=np.uint8)
+
+    def test_roomy_ring_one_call_a_side(self, ring_pair):
+        tx, rx = ring_pair(1 << 20)
+        x = self._payload(300_001)
+        chunk, nchunks = 1 << 16, 5
+        assert tx.write_msg(self.TAG, self.XFER, x, chunk, 0, nchunks,
+                            100) == (nchunks, 0)
+        assert tx.stats()["w_frames"] == nchunks
+        out = np.empty(x.nbytes, np.uint8)
+        crc = (ctypes.c_int64 * 2)(0, 0)
+        assert rx.read_msg(self.TAG, self.XFER, nchunks, chunk, out,
+                           nchunks, 100, crc) == (nchunks, 0)
+        np.testing.assert_array_equal(out, x)
+        # checksummed inside the copy, every fragment in order
+        assert (crc[0], crc[1]) == (zlib.crc32(x.tobytes()), nchunks)
+        assert rx.pending() == 0
+
+    def test_records_are_the_templates_fragments(self, ring_pair):
+        """What write_msg puts in the ring is what writev puts there
+        from ``FrameTemplate.sg_lists``: the same records, byte for
+        byte (a portable reader, the plan executor and ``read_frag``
+        all read them)."""
+        from ompi_release_tpu.btl import components as btl_comps
+
+        tx, rx = ring_pair(1 << 20)
+        x = self._payload(10_000)
+        tpl = btl_comps.plan_frame_template(x.shape, x.dtype, 4096)
+        frames = list(tpl.sg_lists(memoryview(x), self.XFER, 0))[1:]
+        assert tx.write_msg(self.TAG, self.XFER, x, tpl.chunk, 0,
+                            tpl.nchunks, 100) == (tpl.nchunks, 0)
+        tmp = bytearray(1 << 16)
+        for parts in frames:
+            n, tag = rx.read_into(tmp, 100)
+            assert tag == self.TAG
+            assert bytes(tmp[:n]) == b"".join(bytes(p) for p in parts)
+
+    def test_small_ring_hands_back_and_resumes(self, ring_pair):
+        """A ring smaller than the message: the writer hands back at
+        the first record that does not fit, after its slice, with the
+        count that went in; the reader hands back on an empty ring;
+        both resume where they stopped and the checksum chains across
+        the calls."""
+        tx, rx = ring_pair(64 * 1024)
+        x = self._payload(250_000)
+        chunk = 16 * 1024
+        nchunks = -(-x.nbytes // chunk)
+        out = np.empty(x.nbytes, np.uint8)
+        crc = (ctypes.c_int64 * 2)(0, 0)
+        sent = got = wcalls = rcalls = 0
+        while got < nchunks:
+            if sent < nchunks:
+                n, rc = tx.write_msg(self.TAG, self.XFER, x, chunk, sent,
+                                     nchunks, 5)
+                wcalls += 1
+                sent += n
+                assert rc == (0 if sent == nchunks else -1)
+                assert 0 < n < nchunks  # some, never all: 64 KiB ring
+            n, rc = rx.read_msg(self.TAG, self.XFER, nchunks, chunk, out,
+                                nchunks - got, 5, crc)
+            rcalls += 1
+            got += n
+            assert rc == (0 if got == nchunks else -1)
+        assert wcalls > 1 and rcalls > 1
+        np.testing.assert_array_equal(out, x)
+        assert (crc[0], crc[1]) == (zlib.crc32(x.tobytes()), nchunks)
+        # a blocked record is one stall, however the calls were cut
+        assert tx.stats()["w_stalls"] == wcalls - 1
+
+    def test_foreign_tag_at_the_head_is_left_for_the_caller(self,
+                                                            ring_pair):
+        tx, rx = ring_pair(1 << 20)
+        x = self._payload(40_000)
+        chunk, nchunks = 8192, 5
+        assert tx.write_msg(self.TAG, self.XFER, x, chunk, 0, 2, 100) \
+            == (2, 0)
+        assert tx.writev(self.TAG + 1, [b"someone else's"], 100) == 0
+        assert tx.write_msg(self.TAG, self.XFER, x, chunk, 2, nchunks,
+                            100) == (3, 0)
+        out = np.empty(x.nbytes, np.uint8)
+        crc = (ctypes.c_int64 * 2)(0, 0)
+        assert rx.read_msg(self.TAG, self.XFER, nchunks, chunk, out,
+                           nchunks, 100, crc) == (2, -5)
+        tmp = bytearray(64)
+        n, tag = rx.read_into(tmp, 100)  # the caller restashes it
+        assert (bytes(tmp[:n]), tag) == (b"someone else's", self.TAG + 1)
+        assert rx.read_msg(self.TAG, self.XFER, nchunks, chunk, out, 3,
+                           100, crc) == (3, 0)
+        np.testing.assert_array_equal(out, x)
+        assert (crc[0], crc[1]) == (zlib.crc32(x.tobytes()), nchunks)
+
+    def test_out_of_order_and_stale_fragments(self, ring_pair):
+        """Fragments out of index order land where they belong and
+        break the running checksum (the caller then checks the whole
+        buffer); a fragment of another transfer on the same tag is
+        dropped inside the call, as ``read_frag`` drops it."""
+        tx, rx = ring_pair(1 << 20)
+        x = self._payload(20_000)
+        chunk, nchunks = 8192, 3
+        pre = b"SGC2" + self.XFER.to_bytes(8, "big")
+        stale = b"SGC2" + (self.XFER - 1).to_bytes(8, "big")
+        mv = memoryview(x)
+        assert tx.writev(self.TAG, [stale, (0).to_bytes(8, "big"),
+                                    b"old"], 100) == 0
+        for idx in (1, 0, 2):
+            assert tx.writev(
+                self.TAG, [pre, idx.to_bytes(8, "big"),
+                           mv[idx * chunk:(idx + 1) * chunk]], 100) == 0
+        out = np.empty(x.nbytes, np.uint8)
+        crc = (ctypes.c_int64 * 2)(0, 0)
+        assert rx.read_msg(self.TAG, self.XFER, nchunks, chunk, out,
+                           nchunks, 100, crc) == (nchunks, 0)
+        np.testing.assert_array_equal(out, x)
+        assert crc[1] == -1
+        assert rx.stats()["r_frames"] == nchunks + 1
+
+    def test_empty_message_is_one_empty_fragment(self, ring_pair):
+        tx, rx = ring_pair(1 << 16)
+        x = np.empty(0, np.uint8)
+        assert tx.write_msg(self.TAG, self.XFER, x, 4096, 0, 1, 100) \
+            == (1, 0)
+        crc = (ctypes.c_int64 * 2)(0, 0)
+        assert rx.read_msg(self.TAG, self.XFER, 1, 4096, x, 1, 100,
+                           crc) == (1, 0)
+        assert (crc[0], crc[1]) == (0, 1)
+
+    def test_codes_the_caller_must_handle(self, ring_pair):
+        tx, rx = ring_pair(1 << 16)
+        x = self._payload(1 << 18)
+        # a fragment record that can never fit this ring: route it
+        assert tx.write_msg(self.TAG, self.XFER, x, 1 << 17, 0, 2, 5) \
+            == (0, -2)
+        # an overrun (consumed, as read_frag does) is malformed
+        assert tx.write_msg(self.TAG, self.XFER, x, 4096, 0, 2, 5) \
+            == (2, 0)
+        out = np.empty(4096, np.uint8)  # room for one fragment only
+        crc = (ctypes.c_int64 * 2)(0, 0)
+        assert rx.read_msg(self.TAG, self.XFER, 2, 4096, out, 2, 5,
+                           crc) == (1, -2)
+        assert rx.pending() == 0
+        # an empty slice
+        assert rx.read_msg(self.TAG, self.XFER, 2, 4096, out, 1, 5,
+                           crc) == (0, -1)
 
 
 class TestOob:

@@ -204,28 +204,54 @@ class TestSocketInterop:
             b.close()
 
     @pytest.mark.parametrize("seg", [1 << 14], indirect=True)
-    def test_crc_catches_corruption(self, seg):
+    @pytest.mark.parametrize("leg", ["socket", "ring"])
+    def test_crc_catches_corruption(self, seg, leg):
         """A corrupted fragment payload fails the transfer CRC with
-        the typed ERR_TRUNCATE — never silently wrong data."""
+        the typed ERR_TRUNCATE — never silently wrong data. On the
+        socket leg a portable sender's last frame is altered; on the
+        ring leg the native sender's ``write_msg`` has put the whole
+        payload into the ring and one byte of the ring's data area is
+        flipped before the receiver's ``read_msg`` takes it out (the
+        checksum chained inside that copy is what catches it)."""
         a, b = self._pair()
         try:
-            cards = _cards(["hostA", "hostB"])
+            hosts = ["hostA", "hostB"] if leg == "socket" \
+                else ["hostX", "hostX"]
+            cards = _cards(hosts)
             mod = nw.NativeWireBtl()
             mod.bind(cards, 0)
             x = np.arange(20_000, dtype=np.int32)
-            frames = list(btl_comps.DcnBtl().staged_frames(
-                x, segsize=seg))
-            bad = bytearray(frames[-1])
-            bad[-1] ^= 0xFF
-            frames[-1] = bytes(bad)
-            for fr in frames:
-                b.send(1, USER_TAG + 6, fr)
+            if leg == "socket":
+                frames = list(btl_comps.DcnBtl().staged_frames(
+                    x, segsize=seg))
+                bad = bytearray(frames[-1])
+                bad[-1] ^= 0xFF
+                frames[-1] = bytes(bad)
+                for fr in frames:
+                    b.send(1, USER_TAG + 6, fr)
+            else:
+                tx = nw.NativeWireBtl()
+                tx.bind(cards, 1)
+                tx.send_staged(b, 1, USER_TAG + 6, x)
+                ring, _lk = tx._tx_ring(
+                    0, nw._slot_of(USER_TAG + 6, tx._cap(0)[1]))
+                # 128-byte ring header, then the first record: 8 bytes
+                # of length and tag, the 20-byte SGC2 prefix, payload
+                with open("/dev/shm" + ring.name, "r+b") as f:
+                    f.seek(128 + 8 + 20 + 1000)
+                    byte = f.read(1)
+                    f.seek(-1, os.SEEK_CUR)
+                    f.write(bytes([byte[0] ^ 0xFF]))
             with pytest.raises(MPIError) as ei:
                 mod.recv_staged(a, USER_TAG + 6, timeout_ms=10_000)
             assert ei.value.code == ErrorCode.ERR_TRUNCATE
+            assert "failed its payload CRC" in str(ei.value)
         finally:
             a.close()
             b.close()
+            for m in (locals().get("tx"), locals().get("mod")):
+                if isinstance(m, nw.NativeWireBtl):
+                    m._shutdown_rings()
 
     @pytest.mark.parametrize("seg", [1 << 15], indirect=True)
     def test_shm_ring_loopback_same_process(self, seg):
@@ -275,9 +301,9 @@ class TestSocketInterop:
         still send past one ring of bytes (an all-pairs allgather's
         first, interpreted call; ISSUE 31, step 0). A sender on a full
         ring drains its inbound rings; so must a reader whose ring stays
-        empty for a slice: here process 0 is mid-transfer on the ring
-        from 1, which sends nothing for a while, and the ring from 2 is
-        full of another transfer's frames. They have to be in the
+        empty for a slice: here process 0 holds a header from 1 and waits
+        on the ring from 1, which sends nothing for a while, and the
+        ring from 2 is full of another transfer's frames. They have to be in the
         stash — the ring empty, its writer free to go on — before
         process 1 sends another byte."""
         a, b = self._pair()
@@ -303,8 +329,9 @@ class TestSocketInterop:
                     rx.recv_staged(a, tag, timeout_ms=30_000)),
                 daemon=True)
             th.start()
-            for _ in range(3):  # the header and two fragments
-                next(frames)
+            # the header alone: since PR 35 the next step of the stream
+            # would put the whole payload into the ring in one call
+            next(frames)
             deadline = time.monotonic() + 5
             while (ring2.stats()["r_frames"] < queued
                    and time.monotonic() < deadline):
@@ -323,6 +350,175 @@ class TestSocketInterop:
             b.close()
             for mod in (locals().get("rx"), locals().get("tx1"),
                         locals().get("tx2")):
+                if isinstance(mod, nw.NativeWireBtl):
+                    mod._shutdown_rings()
+
+    def _ring_fleet(self, ring_bytes=8 << 20):
+        cards = _cards(["hostX", "hostX"])
+        for i, card in enumerate(cards):
+            card[nw.CARD_KEY] = f"tok{i}-{os.getpid()}:4:{ring_bytes}"
+        tx = nw.NativeWireBtl()
+        tx.bind(cards, 1)
+        rx = nw.NativeWireBtl()
+        rx.bind(cards, 0)
+        return tx, rx
+
+    @staticmethod
+    def _msg_counters():
+        return nw._native_msg_calls.read(), nw._native_msgs.read()
+
+    @pytest.mark.parametrize("seg", [1 << 15], indirect=True)
+    def test_a_message_that_fits_is_one_native_call_a_side(self, seg):
+        """The whole payload is in the ring before the receiver looks:
+        one ``write_msg``, one ``read_msg``, whatever the fragment
+        count — ``wire_native_msg_calls`` : ``wire_native_msgs`` reads
+        1 : 1 — and the stream yields once for the header and once for
+        the payload."""
+        a, b = self._pair()
+        try:
+            tx, rx = self._ring_fleet()
+            x = np.arange(500_000, dtype=np.float32)  # 62 fragments
+            calls0, msgs0 = self._msg_counters()
+            frames0 = nw._native_frames.read()
+            fb0 = nw._fallback_copies.read()
+            steps = sum(1 for _ in tx.frame_stream(b, 0, USER_TAG + 8, x))
+            assert steps == 2
+            assert self._msg_counters() == (calls0 + 1, msgs0 + 1)
+            got = rx.recv_staged(a, USER_TAG + 8, timeout_ms=30_000)
+            np.testing.assert_array_equal(np.asarray(got), x)
+            assert self._msg_counters() == (calls0 + 2, msgs0 + 2)
+            assert nw._native_frames.read() - frames0 == 62
+            assert nw._fallback_copies.read() == fb0
+        finally:
+            a.close()
+            b.close()
+            for mod in (locals().get("tx"), locals().get("rx")):
+                if isinstance(mod, nw.NativeWireBtl):
+                    mod._shutdown_rings()
+
+    @pytest.mark.parametrize("seg", [1 << 14], indirect=True)
+    def test_a_ring_smaller_than_the_message_resumes(self, seg):
+        """400 KB through a 64 KiB ring: ``write_msg`` hands back at
+        the full ring, the sender does what it did per fragment (its
+        own inbound rings, a slice) and calls again from that
+        fragment; the reader's ``read_msg`` returns what has landed
+        when a slice ends. More calls than messages; the payload and
+        its checksum whole; the stream never yields more often than
+        the message has frames."""
+        a, b = self._pair()
+        try:
+            tx, rx = self._ring_fleet(ring_bytes=1 << 16)
+            x = np.arange(100_000, dtype=np.float32)  # 25 fragments
+            calls0, msgs0 = self._msg_counters()
+            err, steps = [], []
+
+            def _send():
+                try:
+                    steps.append(sum(
+                        1 for _ in tx.frame_stream(b, 0, USER_TAG + 8, x)))
+                except Exception as e:  # surfaced by the main thread
+                    err.append(e)
+
+            th = threading.Thread(target=_send, daemon=True)
+            th.start()
+            time.sleep(0.1)  # the sender is parked on the full ring
+            got = rx.recv_staged(a, USER_TAG + 8, timeout_ms=60_000)
+            th.join(timeout=60)
+            assert not err, err
+            np.testing.assert_array_equal(np.asarray(got), x)
+            calls, msgs = self._msg_counters()
+            assert msgs - msgs0 == 2
+            assert calls - calls0 > msgs - msgs0
+            assert 2 < steps[0] <= 1 + 25
+        finally:
+            a.close()
+            b.close()
+            for mod in (locals().get("tx"), locals().get("rx")):
+                if isinstance(mod, nw.NativeWireBtl):
+                    mod._shutdown_rings()
+
+    @pytest.mark.parametrize("seg", [1 << 14], indirect=True)
+    def test_foreign_tag_at_the_ring_head_is_restashed(self, seg):
+        """Another lane's record sits in the ring among this
+        message's fragments (two tags that hash to one slot):
+        ``read_msg`` hands back in front of it (-5), Python moves it to
+        the ring's cross-tag stash — the one counted copy — and the
+        call resumes; the other lane finds its record there."""
+        a, b = self._pair()
+        try:
+            tx, rx = self._ring_fleet()
+            tag = USER_TAG + 8
+            slot = nw._slot_of(tag, 4)
+            other = next(t for t in range(tag + 1, tag + 64)
+                         if nw._slot_of(t, 4) == slot)
+            ring, _lk = tx._tx_ring(0, slot)
+            x = np.arange(50_000, dtype=np.float32)  # 13 fragments
+            stream = tx.frame_stream(b, 0, tag, x)
+            next(stream)  # the header
+            # the other lane's record goes in first, then the payload
+            assert ring.writev(other, [b"another lane's frame"], 100) == 0
+            for _ in stream:
+                pass
+            calls0, msgs0 = self._msg_counters()
+            fb0 = nw._fallback_copies.read()
+            got = rx.recv_staged(a, tag, timeout_ms=30_000)
+            np.testing.assert_array_equal(np.asarray(got), x)
+            calls, msgs = self._msg_counters()
+            assert (calls - calls0, msgs - msgs0) == (2, 1)
+            assert nw._fallback_copies.read() - fb0 == 1
+            ent = rx._rx_ring(1, slot, time.monotonic() + 1)
+            assert ent[2][other] == [b"another lane's frame"]
+        finally:
+            a.close()
+            b.close()
+            for mod in (locals().get("tx"), locals().get("rx")):
+                if isinstance(mod, nw.NativeWireBtl):
+                    mod._shutdown_rings()
+
+    @pytest.mark.parametrize("seg", [1 << 14], indirect=True)
+    def test_fragments_partly_from_the_stash(self, seg):
+        """The first fragments of a message were taken off the ring
+        into the cross-tag stash (a sender of ours on a full ring
+        drained its inbound rings meanwhile), the rest are still in
+        the ring: the receiver places the stashed ones, lets
+        ``read_msg`` land the others, and the checksum — chained over
+        both, they are in order — holds. A flipped byte in a stashed
+        fragment fails it."""
+        a, b = self._pair()
+        try:
+            tx, rx = self._ring_fleet(ring_bytes=1 << 16)
+            tag = USER_TAG + 8
+            x = np.arange(40_000, dtype=np.float32)  # 10 fragments
+            for flip in (False, True):
+                stream = tx.frame_stream(b, 0, tag, x)
+                next(stream)  # the header
+                next(stream)  # what fits a 64 KiB ring: three fragments
+                fb0 = nw._fallback_copies.read()
+                assert rx._stash_inbound()
+                stashed = nw._fallback_copies.read() - fb0
+                assert 0 < stashed < 10
+                if flip:
+                    slot = nw._slot_of(tag, 4)
+                    q = rx._rx_ring(1, slot, time.monotonic() + 1)[2][tag]
+                    bad = bytearray(q[1])
+                    bad[-1] ^= 0xFF
+                    q[1] = bytes(bad)
+                th = threading.Thread(
+                    target=lambda: [None for _ in stream], daemon=True)
+                th.start()
+                if flip:
+                    with pytest.raises(MPIError) as ei:
+                        rx.recv_staged(a, tag, timeout_ms=30_000)
+                    assert ei.value.code == ErrorCode.ERR_TRUNCATE
+                else:
+                    got = rx.recv_staged(a, tag, timeout_ms=30_000)
+                    np.testing.assert_array_equal(np.asarray(got), x)
+                th.join(timeout=30)
+                assert not th.is_alive()
+        finally:
+            a.close()
+            b.close()
+            for mod in (locals().get("tx"), locals().get("rx")):
                 if isinstance(mod, nw.NativeWireBtl):
                     mod._shutdown_rings()
 
