@@ -5,7 +5,14 @@ Each PE waits until its symmetric flag holds the lap count its left
 neighbour put there, then decrements (PE 0) and puts onward — the
 put/wait_until pattern of ``examples/ring_oshmem_c.c``.
 
-Run:  python examples/ring_oshmem_tpu.py   (driver mode, virtual PEs)
+A flag is one word of the symmetric heap, so every operation names its
+address: ``index=0`` for the AMOs (``shmem_int_swap(&flag[0], ...)``),
+``offset=0`` for the put (``shmem_putmem(&flag[0], ...)``).
+
+Run:  python examples/ring_oshmem_tpu.py   (driver mode, virtual PEs:
+one controller plays every PE in turn, so ``pe=`` is explicit and
+``ctx.my_pe`` is None; under ``tpurun`` each process is one PE,
+``ctx.my_pe`` names it and ``wait_until`` defaults to it)
 """
 import os
 import sys
@@ -31,20 +38,20 @@ def main() -> int:
     ctx.quiet()
 
     passes = 0
-    ctx.put_elem(flag, np.int32(laps), 0, pe=0)  # seed at PE 0
+    ctx.atomic_set(flag, laps, 0, index=0)  # seed at PE 0
     token = laps
     pe = 0
     while True:
         ctx.wait_until(flag, "ge", 0, pe=pe)
-        token = int(np.asarray(ctx.get(flag, pe=pe))[0])
-        ctx.put_elem(flag, np.int32(-1), 0, pe=pe)  # consume
+        # take the token and leave the flag empty, in one AMO
+        token = int(ctx.atomic_swap(flag, -1, pe, index=0))
         passes += 1
         if pe == 0 and passes > 1:
             token -= 1
             print(f"PE 0: {token} laps to go")
         if token == 0 and pe == n - 1:
             break
-        ctx.put_elem(flag, np.int32(token), 0, pe=(pe + 1) % n)
+        ctx.put(flag, np.int32([token]), (pe + 1) % n, offset=0)
         ctx.quiet()
         pe = (pe + 1) % n
     ctx.barrier_all()

@@ -85,6 +85,18 @@ keyword stats of the event, never folded into the name:
                            (``origin``, ``ops``, ``bytes``)
 ``ompi.osc.program``       the call of an epoch program, interpreted or
                            planned, wherever it runs (``ops``)
+``ompi.shmem.quiet``       ``ShmemCtx.quiet`` (so ``fence`` and
+                           ``barrier_all`` too), entry to return
+                           (``allocs`` that had something to complete,
+                           ``ops`` taken off their bulk queues)
+``ompi.shmem.drain``       one allocation's bulk queue replayed into its
+                           window and flushed (``ops``, ``bytes`` of
+                           their payloads, ``cid``; nested in ``quiet``
+                           or in the blocking call that drained)
+``ompi.shmem.get``         a blocking ``ShmemCtx.get``, entry to return
+                           (``bytes`` asked for)
+``ompi.shmem.amo``         a fetching AMO, entry to return (``kind``:
+                           ``fetch_add``, ``swap`` or ``cswap``)
 
 ``seq`` is the posted schedule's ``ScheduledOp.seq`` (process-local): a
 schedule may run on another thread than its ``ompi.coll.call``
@@ -124,12 +136,17 @@ OSC_UNPACK = "ompi.osc.unpack"
 OSC_H2D = "ompi.osc.h2d"
 OSC_APPLY = "ompi.osc.apply"
 OSC_PROGRAM = "ompi.osc.program"
+SHMEM_QUIET = "ompi.shmem.quiet"
+SHMEM_DRAIN = "ompi.shmem.drain"
+SHMEM_GET = "ompi.shmem.get"
+SHMEM_AMO = "ompi.shmem.amo"
 
 NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
          PLAN_NATIVE_FIRE, PLAN_XCHG, HIER_D2H, HIER_H2D, WIRE_STASH,
          PML_SEND, PML_D2H, WIRE_P2P_SEND, PML_RECV_WAIT, WIRE_P2P_PUMP,
          PML_H2D, HIER_ASSEMBLE, OSC_SYNC, OSC_PACK, OSC_D2H, OSC_REQUEST,
-         OSC_REPLY_WAIT, OSC_UNPACK, OSC_H2D, OSC_APPLY, OSC_PROGRAM)
+         OSC_REPLY_WAIT, OSC_UNPACK, OSC_H2D, OSC_APPLY, OSC_PROGRAM,
+         SHMEM_QUIET, SHMEM_DRAIN, SHMEM_GET, SHMEM_AMO)
 
 #: ``jax.profiler.TraceAnnotation`` and the ``obs`` package, bound on
 #: the first span: importing ``obs`` must not import jax (``obs

@@ -8,17 +8,28 @@ the delegate-to-MPI ``scoll/mpi`` component). TPU-native recast:
 - The symmetric heap is per-PE HBM: a symmetric allocation is one
   device array with a leading PE axis (slice i in PE i's HBM) — the
   same "address" (python handle) is valid for every PE, which is the
-  whole symmetric-heap contract (``oshmem/mca/memheap``).
+  whole symmetric-heap contract (``oshmem/mca/memheap``). An address
+  inside it is a flat element ``offset`` (put/get: ``dest + offset``)
+  or ``index`` (AMOs: ``&x[i]``); left out, an operation acts on the
+  whole slot (AMOs elementwise: a documented extension).
+- Who the origin is: in driver mode one controller plays every PE, so
+  ``pe=`` is always explicit and ``my_pe`` is ``None``. Under
+  ``tpurun`` each process is the origin of its own calls: with one PE
+  per process ``my_pe`` is the caller's rank, ``wait_until``/``test``
+  default to it, and ``local(pe)`` refuses a PE of another process
+  (no load/store path: use ``get``).
 - put/get queue onto the underlying RMA window machinery (the spml →
   BTL path, here spml → osc) and complete at ``quiet``/``barrier_all``
-  — OpenSHMEM's own completion rule. Fetch AMOs and get are blocking
-  (they flush), put/add are posted.
+  — OpenSHMEM's own completion rule. Puts and non-fetching AMOs are
+  posted; ``get`` and the fetching AMOs are blocking: they drain the
+  allocation's queue, issue their one request and flush its target.
 - the **planned bulk path**: posted puts/AMOs between
   ``quiet()``/``fence()`` boundaries are batched
   per symmetric allocation as light host-side tuples — no per-call
   ``jnp.asarray``, no per-call window queueing — and drained as ONE
   window epoch, which the osc access-plan machinery (``osc/plan``)
-  closes as one fused device program per (allocation, signature).
+  closes as one fused device program per (allocation, signature), or,
+  for a PE in another process, ships as one batch to its home.
   Posted ops therefore follow ``shmem_put_nbi`` source-buffer rules:
   the source is reusable after ``quiet()``. Blocking calls (get,
   fetch AMOs, ``wait_until``, ``local``) drain first, so per-call
@@ -30,7 +41,7 @@ the delegate-to-MPI ``scoll/mpi`` component). TPU-native recast:
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -40,6 +51,7 @@ import jax.numpy as jnp
 from .. import obs as _obs
 from .. import ops as ops_mod
 from ..mca import pvar
+from ..obs import spans as _spans
 from ..osc.window import Window
 from ..utils import output
 from ..utils.errors import ErrorCode, MPIError
@@ -57,6 +69,24 @@ _bulk_flushes = pvar.counter(
     "shmem_bulk_flushes",
     "bulk-queue drains (one planned window epoch per allocation)",
 )
+_ops = pvar.counter(
+    "shmem_ops",
+    "SHMEM puts, gets and AMOs issued, posted or blocking",
+)
+_blocking_ops = pvar.counter(
+    "shmem_blocking_ops",
+    "blocking SHMEM operations: gets and fetching AMOs (one request "
+    "and one flush of its target each)",
+)
+_quiets = pvar.counter(
+    "shmem_quiets", "quiet() calls (fence and barrier_all included)"
+)
+
+_CMPS = {
+    "eq": np.equal, "ne": np.not_equal,
+    "gt": np.greater, "ge": np.greater_equal,
+    "lt": np.less, "le": np.less_equal,
+}
 
 
 class SymmetricArray:
@@ -76,6 +106,15 @@ class SymmetricArray:
     def dtype(self):
         return self._win.dtype
 
+    def _row(self, pe: int) -> Optional[int]:
+        """The row of this process's storage that holds PE ``pe``, or
+        None where ``pe`` lives in another controller process."""
+        comm = self._win.comm
+        if not getattr(comm, "spans_processes", False):
+            return pe
+        lr = list(comm.local_comm_ranks)
+        return lr.index(pe) if pe in lr else None
+
     def local(self, pe: int) -> jax.Array:
         """PE ``pe``'s local view (shmem_ptr analogue; driver mode sees
         every PE). On a unified multi-controller world only
@@ -84,24 +123,20 @@ class SymmetricArray:
         (``oshmem/shmem/c/shmem_ptr.c``); use :meth:`ShmemCtx.get`
         for remote PEs."""
         self._ctx._drain(self)
-        self._win.flush_all()
-        comm = self._win.comm
-        if getattr(comm, "spans_processes", False):
-            lr = list(comm.local_comm_ranks)
-            if pe not in lr:
-                raise MPIError(
-                    ErrorCode.ERR_RMA_SHARED,
-                    f"shmem_ptr: PE {pe} lives in another controller "
-                    "process (no load/store path); use get()",
-                )
-            return self._win.read()[lr.index(pe)]
-        return self._win.read()[pe]
+        row = self._row(pe)
+        if row is None:
+            raise MPIError(
+                ErrorCode.ERR_RMA_SHARED,
+                f"shmem_ptr: PE {pe} lives in another controller "
+                "process (no load/store path); use get()",
+            )
+        return self._win.read()[row]
 
     def free(self) -> None:
         self._ctx._drain(self)  # posted ops must land, not vanish
         self._win.unlock_all()
         self._win.free()
-        self._ctx._allocs.discard(self)
+        self._ctx._allocs.pop(self, None)
 
 
 class ShmemCtx:
@@ -109,17 +144,31 @@ class ShmemCtx:
 
     def __init__(self, comm) -> None:
         self.comm = comm
-        self._allocs: set = set()
+        # in allocation order: ``finalize`` frees collectively, so every
+        # process has to walk its allocations in the same order
+        self._allocs: Dict["SymmetricArray", None] = {}
         # planned bulk path: per-allocation queues of light
-        # (kind, pe, data, op, index) tuples — jnp.asarray and window
-        # queueing are deferred to the drain, where the whole batch
-        # closes as ONE planned window epoch
+        # (kind, pe, data, op, index, disp) tuples — jnp.asarray and
+        # window queueing are deferred to the drain, where the whole
+        # batch closes as ONE planned window epoch
         self._bulk: Dict["SymmetricArray", List[Tuple]] = {}
 
     # -- setup / query (shmem.h accessors) ---------------------------------
     @property
     def n_pes(self) -> int:
         return self.comm.size
+
+    @property
+    def my_pe(self) -> Optional[int]:
+        """shmem_my_pe: the caller's rank on the communicator when each
+        process is one PE (``tpurun``); None in driver mode, where one
+        controller plays every PE and ``pe=`` stays explicit."""
+        comm = self.comm
+        if getattr(comm, "spans_processes", False):
+            lr = comm.local_comm_ranks
+            if len(lr) == 1:
+                return int(lr[0])
+        return None
 
     def malloc(self, shape: Tuple[int, ...], dtype=jnp.float32
                ) -> SymmetricArray:
@@ -128,7 +177,7 @@ class ShmemCtx:
 
         win = win_allocate(self.comm, tuple(shape), dtype)
         arr = SymmetricArray(self, win)
-        self._allocs.add(arr)
+        self._allocs[arr] = None
         _heap_bytes.add(
             int(np.prod(shape)) * jnp.dtype(dtype).itemsize * self.n_pes
         )
@@ -136,14 +185,18 @@ class ShmemCtx:
 
     # -- the planned bulk path ---------------------------------------------
     def _post(self, sym: SymmetricArray, kind: str, pe: int, data,
-              op, index) -> None:
+              op, index, disp=None) -> None:
         """Defer one posted op into ``sym``'s bulk queue (nbi
         semantics: the source lands at the next drain). The tuple
         carries the frozen Op OBJECT — the drain replays it through
         the window queue, so osc/plan keys the fused program by the
-        object, never by an op name."""
-        self._bulk.setdefault(sym, []).append((kind, pe, data, op, index))
+        object, never by an op name — and where the op has one its
+        element ``index`` or the displacement ``disp`` of its range. A
+        host scalar stays one until the drain."""
+        self._bulk.setdefault(sym, []).append(
+            (kind, pe, data, op, index, disp))
         _bulk_ops.add()
+        _ops.add()
 
     def _drain(self, sym: SymmetricArray) -> None:
         """Replay ``sym``'s bulk queue as one window epoch and flush:
@@ -152,127 +205,169 @@ class ShmemCtx:
         q = self._bulk.pop(sym, None)
         if not q:
             return
-        rec = _obs.enabled
-        t0 = time.perf_counter() if rec else 0.0
         win = sym._win
-        for kind, pe, data, op, index in q:
-            if kind == "put":
-                win.put(jnp.asarray(data), pe, index=index)
-            else:  # acc
-                win.accumulate(jnp.asarray(data), pe, op=op, index=index)
-        win.flush_all()
+        with _obs.span(_spans.SHMEM_DRAIN,
+                       journal=("shmem_bulk_flush", "osc"), ops=len(q),
+                       bytes=sum(_spans.nbytes(t[2]) for t in q),
+                       cid=win.comm.cid):
+            for kind, pe, data, op, index, disp in q:
+                if kind == "put":
+                    win.put(data, pe, index=index, disp=disp)
+                else:  # acc
+                    win.accumulate(data, pe, op=op, index=index)
+            win.flush_all()
         _bulk_flushes.add()
-        if rec and _obs.enabled:
-            _obs.record(
-                "shmem_bulk_flush", "osc", t0,
-                time.perf_counter() - t0, nbytes=sum(
-                    int(getattr(d, "nbytes", 0) or 0)
-                    for _, _, d, _, _ in q),
-                comm_id=win.comm.cid)
+
+    @staticmethod
+    def _in_range(sym: SymmetricArray, offset, nelems) -> Tuple[int, int]:
+        """``nelems`` elements from flat ``offset`` lie inside the
+        allocation, or the window's own ``ERR_RMA_RANGE``: raised at
+        the call, before anything is queued or drained."""
+        offset, nelems = int(offset), int(nelems)
+        sym._win._check_range(offset, nelems)
+        return offset, nelems
 
     # -- data movement (spml put/get) --------------------------------------
-    def put(self, sym: SymmetricArray, data, pe: int) -> None:
-        """shmem_put: posted; completes at quiet/barrier_all."""
-        self._post(sym, "put", pe, data, None, None)
+    def put(self, sym: SymmetricArray, data, pe: int,
+            offset: Optional[int] = None) -> None:
+        """shmem_put: posted; completes at quiet/barrier_all. With
+        ``offset``, shmem_putmem at ``dest + offset``: ``data.size``
+        consecutive elements of the allocation (flattened in C order)
+        from that flat offset; without, the whole slot."""
+        if offset is not None:
+            if not hasattr(data, "size"):
+                data = np.asarray(data)
+            offset, _ = self._in_range(sym, offset, data.size)
+        self._post(sym, "put", pe, data, None, None, offset)
 
-    def get(self, sym: SymmetricArray, pe: int) -> jax.Array:
-        """shmem_get: blocking (flushes pending ops first)."""
-        self._drain(sym)
-        sym._win.flush_all()
-        req = sym._win.get(pe)
-        sym._win.flush_all()
-        return req.value
+    def get(self, sym: SymmetricArray, pe: int,
+            offset: Optional[int] = None,
+            nelems: Optional[int] = None) -> jax.Array:
+        """shmem_get: blocking (pending ops of the allocation land
+        first). With ``offset`` and ``nelems``, shmem_getmem at
+        ``src + offset``: that many consecutive elements, 1-D; without,
+        the whole slot."""
+        if offset is not None and nelems is not None:
+            offset, nelems = self._in_range(sym, offset, nelems)
+        win = sym._win
+        _ops.add()
+        _blocking_ops.add()
+        with _obs.span(_spans.SHMEM_GET, bytes=win.dtype.itemsize * (
+                win._slot_elems() if nelems is None else nelems)):
+            self._drain(sym)
+            req = win.get(pe, disp=offset, count=nelems)
+            win.flush(pe)
+            return req.value
 
     def put_elem(self, sym: SymmetricArray, value, index, pe: int) -> None:
         """Scalar put at a flat index (shmem_p): a true single-element
         posted put — O(1) staged bytes, no read-modify-write of the
         whole slot."""
-        self._post(sym, "put", pe, value, None, int(index))
+        self._post(sym, "put", pe, value, None, self._at(sym, index))
 
     # -- atomics (oshmem/mca/atomic) ---------------------------------------
-    def atomic_add(self, sym: SymmetricArray, value, pe: int) -> None:
-        self._post(sym, "acc", pe, value, ops_mod.SUM, None)
+    # ``index`` given: ONE element at that flat index of the allocation,
+    # as OpenSHMEM's AMOs act (``shmem_int_fadd(&x[i], v, pe)``), and
+    # ``value`` a scalar; left out: the whole slot, elementwise.
+    def _at(self, sym: SymmetricArray, index) -> Optional[int]:
+        """The element index checked like a range of one, at the call."""
+        return None if index is None else self._in_range(sym, index, 1)[0]
 
-    def atomic_fetch_add(self, sym: SymmetricArray, value, pe: int
-                         ) -> jax.Array:
-        self._drain(sym)  # fetch observes earlier posted ops
-        req = sym._win.fetch_and_op(jnp.asarray(value), pe, op=ops_mod.SUM)
-        sym._win.flush(pe)
-        return req.value
+    def atomic_add(self, sym: SymmetricArray, value, pe: int,
+                   index: Optional[int] = None) -> None:
+        self._post(sym, "acc", pe, value, ops_mod.SUM, self._at(sym, index))
 
-    def atomic_swap(self, sym: SymmetricArray, value, pe: int) -> jax.Array:
-        self._drain(sym)
-        req = sym._win.fetch_and_op(jnp.asarray(value), pe,
-                                    op=ops_mod.REPLACE)
-        sym._win.flush(pe)
-        return req.value
-
-    def atomic_compare_swap(self, sym: SymmetricArray, cond, value, pe: int
-                            ) -> jax.Array:
-        self._drain(sym)
-        req = sym._win.compare_and_swap(jnp.asarray(value),
-                                        jnp.asarray(cond), pe)
-        sym._win.flush(pe)
-        return req.value
-
-    def atomic_inc(self, sym: SymmetricArray, pe: int) -> None:
+    def atomic_inc(self, sym: SymmetricArray, pe: int,
+                   index: Optional[int] = None) -> None:
         """shmem_inc: add 1 (the counter idiom)."""
-        self.atomic_add(sym, jnp.ones(sym.shape, sym.dtype), pe)
+        self.atomic_add(sym, 1, pe, index)
 
-    def atomic_fetch_inc(self, sym: SymmetricArray, pe: int) -> jax.Array:
-        return self.atomic_fetch_add(
-            sym, jnp.ones(sym.shape, sym.dtype), pe
-        )
-
-    def atomic_set(self, sym: SymmetricArray, value, pe: int) -> None:
+    def atomic_set(self, sym: SymmetricArray, value, pe: int,
+                   index: Optional[int] = None) -> None:
         """shmem_atomic_set: unconditional replace (no fetch)."""
-        self._post(sym, "acc", pe, value, ops_mod.REPLACE, None)
+        self._post(sym, "acc", pe, value, ops_mod.REPLACE,
+                   self._at(sym, index))
 
-    def atomic_fetch(self, sym: SymmetricArray, pe: int) -> jax.Array:
+    def _fetching(self, sym: SymmetricArray, kind: str, pe: int, index,
+                  issue) -> jax.Array:
+        """A fetching AMO: earlier posted ops of the allocation land,
+        then ``issue(window, index)`` queues the one request and its
+        target is flushed once; the value from before it comes back."""
+        index = self._at(sym, index)
+        _ops.add()
+        _blocking_ops.add()
+        with _obs.span(_spans.SHMEM_AMO, kind=kind):
+            self._drain(sym)
+            req = issue(sym._win, index)
+            sym._win.flush(pe)
+            return req.value
+
+    def atomic_fetch_add(self, sym: SymmetricArray, value, pe: int,
+                         index: Optional[int] = None) -> jax.Array:
+        return self._fetching(
+            sym, "fetch_add", pe, index, lambda win, i: win.fetch_and_op(
+                value, pe, op=ops_mod.SUM, index=i))
+
+    def atomic_fetch_inc(self, sym: SymmetricArray, pe: int,
+                         index: Optional[int] = None) -> jax.Array:
+        return self.atomic_fetch_add(sym, 1, pe, index)
+
+    def atomic_fetch(self, sym: SymmetricArray, pe: int,
+                     index: Optional[int] = None) -> jax.Array:
         """shmem_atomic_fetch: an atomic read = fetch_add(0)."""
-        return self.atomic_fetch_add(
-            sym, jnp.zeros(sym.shape, sym.dtype), pe
-        )
+        return self.atomic_fetch_add(sym, 0, pe, index)
+
+    def atomic_swap(self, sym: SymmetricArray, value, pe: int,
+                    index: Optional[int] = None) -> jax.Array:
+        return self._fetching(
+            sym, "swap", pe, index, lambda win, i: win.fetch_and_op(
+                value, pe, op=ops_mod.REPLACE, index=i))
+
+    def atomic_compare_swap(self, sym: SymmetricArray, cond, value,
+                            pe: int, index: Optional[int] = None
+                            ) -> jax.Array:
+        return self._fetching(
+            sym, "cswap", pe, index, lambda win, i: win.compare_and_swap(
+                value, cond, pe, index=i))
 
     # -- point-to-point synchronization (shmem_wait_until) -----------------
     def wait_until(self, sym: SymmetricArray, cmp: str, value, *,
-                   pe: int, timeout_s: float = 30.0,
+                   pe: Optional[int] = None, timeout_s: float = 30.0,
                    poll_s: float = 0.001) -> jax.Array:
         """Block until pe's symmetric variable satisfies the
         comparison — the SHMEM p2p synchronization primitive
-        (``shmem_wait_until``; cmp in eq/ne/gt/ge/lt/le). ``pe`` is
-        explicit because one controller plays every PE in driver mode
-        (in a per-process deployment it would default to the caller's
-        own PE). Progress comes from other ranks' posted puts/AMOs
-        being flushed (the poll flushes so posted ops land)."""
-        import time as _time
-
-        import numpy as _np
-
-        cmps = {
-            "eq": _np.equal, "ne": _np.not_equal,
-            "gt": _np.greater, "ge": _np.greater_equal,
-            "lt": _np.less, "le": _np.less_equal,
-        }
-        if cmp not in cmps:
+        (``shmem_wait_until``; cmp in eq/ne/gt/ge/lt/le). ``pe``
+        defaults to the caller's own PE (``my_pe``); one controller
+        that plays several PEs names it. A PE this process owns is
+        polled in its local slot (``local(pe)``: no RMA request; in
+        driver mode the poll drains, so posted ops land); a PE of
+        another process through ``get``."""
+        if cmp not in _CMPS:
             raise MPIError(ErrorCode.ERR_ARG,
-                           f"wait_until cmp must be one of {list(cmps)}")
-        target_pe = pe
-        deadline = _time.monotonic() + timeout_s
+                           f"wait_until cmp must be one of {list(_CMPS)}")
+        if pe is None:
+            pe = self.my_pe
+        if pe is None:
+            raise MPIError(
+                ErrorCode.ERR_ARG,
+                "wait_until: pe= is required where one process plays "
+                "several PEs (my_pe is None)")
+        owned = sym._row(pe) is not None
+        deadline = time.monotonic() + timeout_s
         while True:
-            cur = _np.asarray(self.get(sym, target_pe))
-            if bool(_np.all(cmps[cmp](cur, value))):
+            cur = np.asarray(sym.local(pe) if owned else self.get(sym, pe))
+            if bool(np.all(_CMPS[cmp](cur, value))):
                 return jnp.asarray(cur)
-            if _time.monotonic() > deadline:
+            if time.monotonic() > deadline:
                 raise MPIError(
                     ErrorCode.ERR_PENDING,
                     f"wait_until({cmp}, {value}) timed out; last "
                     f"value {cur!r}",
                 )
-            _time.sleep(poll_s)
+            time.sleep(poll_s)
 
     def test(self, sym: SymmetricArray, cmp: str, value, *,
-             pe: int) -> bool:
+             pe: Optional[int] = None) -> bool:
         """Nonblocking wait_until (shmem_test)."""
         try:
             self.wait_until(sym, cmp, value, pe=pe, timeout_s=0.0)
@@ -286,14 +381,25 @@ class ShmemCtx:
     def quiet(self) -> None:
         """Complete all outstanding puts/AMOs (shmem_quiet): drain
         every allocation's bulk queue (one planned epoch each) and
-        flush anything queued outside the bulk path."""
-        for a in list(self._allocs):
-            self._drain(a)
-            a._win.flush_all()
+        flush anything queued outside the bulk path. An allocation
+        with nothing queued and nothing pending costs nothing."""
+        _quiets.add()
+        with _obs.span(_spans.SHMEM_QUIET) as sp:
+            allocs = ops = 0
+            for a in list(self._allocs):
+                q = self._bulk.get(a)
+                if q:
+                    allocs += 1
+                    ops += len(q)
+                    self._drain(a)
+                elif a._win._pending:
+                    allocs += 1
+                    a._win.flush_all()
+            sp.set_metadata(allocs=allocs, ops=ops)
 
     def fence(self) -> None:
-        """Ordering only; driver mode applies in submission order, so
-        fence == quiet here (stronger is allowed)."""
+        """Ordering only; operations of one origin apply in the order
+        issued, so fence == quiet here (stronger is allowed)."""
         self.quiet()
 
     def barrier_all(self) -> None:
@@ -353,10 +459,8 @@ class ShmemCtx:
         """Acquire: spin CAS(0 -> pe+1) on the home PE with backoff.
         Deadlock-by-self (re-acquiring a held lock) raises instead of
         hanging — driver mode can detect it, so it does."""
-        import time as _time
-
         me = int(pe) + 1
-        deadline = _time.monotonic() + timeout_s
+        deadline = time.monotonic() + timeout_s
         delay = 0.0005
         while True:
             old = int(np.asarray(
@@ -370,13 +474,13 @@ class ShmemCtx:
                     f"PE {pe} already holds this lock (shmem locks are "
                     "not recursive)",
                 )
-            if _time.monotonic() > deadline:
+            if time.monotonic() > deadline:
                 raise MPIError(
                     ErrorCode.ERR_PENDING,
                     f"set_lock: PE {old - 1} held the lock for "
                     f">{timeout_s}s",
                 )
-            _time.sleep(delay)
+            time.sleep(delay)
             delay = min(delay * 2, 0.01)
 
     def test_lock(self, lock: SymmetricArray, *, pe: int) -> bool:

@@ -188,6 +188,15 @@ def profiler_trace(logdir: str):
       ``ops``, ``bytes``)
     - ``ompi.osc.program`` — the call of an epoch program, interpreted
       or planned, wherever it runs (``ops``)
+    - ``ompi.shmem.quiet`` — ``ShmemCtx.quiet`` (``fence`` and
+      ``barrier_all`` too), entry to return (``allocs``, ``ops``), with
+      one ``ompi.shmem.drain`` per allocation that had posted puts or
+      AMOs inside it: its bulk queue replayed into its window and
+      flushed (``ops``, ``bytes``; the window's ``ompi.osc.sync`` nests
+      in it where the communicator spans processes)
+    - ``ompi.shmem.get`` — a blocking ``ShmemCtx.get``, entry to return
+      (``bytes``); ``ompi.shmem.amo`` — a fetching AMO, entry to return
+      (``kind``)
 
     ``(cid, seq)`` joins an exchange to its ``ompi.nbc.wait`` when the
     schedule ran on another thread; on one thread nesting is the link.

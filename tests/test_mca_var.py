@@ -205,4 +205,4 @@ def test_census_of_options_and_counters():
     got = json.loads(r.stdout.split("CENSUS ", 1)[1].splitlines()[0])
     assert got["cvars"] == 104
     assert len(got["on_off"]) == 17, got["on_off"]
-    assert got["pvars"] == 114  # PR 35: wire_native_msg_calls, wire_native_msgs
+    assert got["pvars"] == 117  # PR 36: shmem_ops, shmem_blocking_ops, shmem_quiets
