@@ -14,6 +14,12 @@ movement) recast for a single-controller device mesh:
   epoch batch is the osc/rdma "aggregate and issue at sync" strategy.
 - get/get_accumulate/fetch_and_op/compare_and_swap return Requests
   whose values materialize at epoch close.
+- A payload waits in the queue where the caller had it: a
+  ``jax.Array`` is queued as the object that was passed, anything on
+  the host (a Python scalar, a numpy scalar or array, a list) as a
+  host snapshot of the shape and dtype ``jnp.asarray`` gives it —
+  it reaches a device as an argument of the epoch program, or never,
+  when its target's home is another process (``osc/wire_win``).
 
 Epoch rules enforced (``ompi/win/win.c`` access-epoch checks): RMA
 outside any epoch raises; fence/lock/PSCW cannot be mixed.
@@ -30,6 +36,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs as _obs
@@ -44,6 +51,11 @@ _log = output.stream("osc")
 
 _epoch_count = pvar.counter("osc_epochs", "RMA epochs closed")
 _rma_ops = pvar.counter("osc_rma_ops", "RMA operations issued")
+_host_payloads = pvar.counter(
+    "osc_host_payloads",
+    "RMA payloads (data and compare values) queued from host memory: "
+    "they never touched a device at the origin",
+)
 _epoch_programs = pvar.counter(
     "osc_epoch_programs", "distinct compiled epoch-close programs"
 )
@@ -102,6 +114,27 @@ class _PendingOp:
         # the COMM rank to report in the request's Status when target
         # has been remapped to a storage row (spanning windows)
         self.status_rank = status_rank
+
+
+def _payload(x):
+    """An origin payload as it waits in the queue. A ``jax.Array`` is
+    the caller's own object: no copy, no fetch, no launch. Anything
+    else is on the host and stays there, as a numpy array of the shape
+    and dtype ``jnp.asarray`` gives it (jax's dtype rules: a Python
+    int is int32 and one outside it raises, int64 becomes int32 with
+    x64 off) — never a bare Python scalar, which an epoch signature
+    would key by VALUE (``coll/plan.arg_desc``). It is a snapshot
+    taken at the call: a caller may rewrite their buffer before the
+    epoch closes."""
+    if isinstance(x, jax.Array):
+        return x
+    # the leaves and the dtype as ``jnp.array`` finds them
+    leaves = jax.tree_util.tree_leaves(
+        x, is_leaf=lambda v: not isinstance(v, (list, tuple)))
+    if any(v is None or isinstance(v, jax.Array) for v in leaves):
+        # a None (it raises) or a list of device arrays (joined there)
+        return jnp.asarray(x)
+    return np.array(x, dtype=jax.dtypes.result_type(*(leaves or [float])))
 
 
 # predefined window attributes (mpi.h MPI_WIN_BASE..MPI_WIN_MODEL)
@@ -444,6 +477,9 @@ class Window:
                     f"slot of {slot_elems} elements",
                 )
         _rma_ops.add()
+        host = sum(isinstance(x, np.ndarray) for x in (op.data, op.compare))
+        if host:
+            _host_payloads.add(host)
         with self._op_lock:
             self._pending.append(op)
         return op.request
@@ -502,8 +538,11 @@ class Window:
         """Put a whole slot, or (``index`` given) a single element at a
         flat offset within the slot, or (``disp`` given) ``data.size``
         consecutive elements from that flat offset (MPI target_disp
-        addressing)."""
-        self._op("put", target, jnp.asarray(data), REPLACE, index=index,
+        addressing). Until the epoch closes ``data`` waits where it
+        was: a ``jax.Array`` as the object passed, a host value as a
+        host snapshot taken now (:func:`_payload`) — so is every
+        payload and compare value of the calls below."""
+        self._op("put", target, _payload(data), REPLACE, index=index,
                  disp=disp)
 
     def get(self, target: int, disp: Optional[int] = None,
@@ -518,14 +557,14 @@ class Window:
     def accumulate(self, data, target: int, op: Op = SUM,
                    index: Optional[int] = None,
                    disp: Optional[int] = None) -> None:
-        self._op("acc", target, jnp.asarray(data), op, index=index,
+        self._op("acc", target, _payload(data), op, index=index,
                  disp=disp)
 
     def get_accumulate(self, data, target: int, op: Op = SUM,
                        index: Optional[int] = None,
                        disp: Optional[int] = None) -> Request:
         req = self._rma_request(target)
-        self._op("get_acc", target, jnp.asarray(data), op, req,
+        self._op("get_acc", target, _payload(data), op, req,
                  index=index, disp=disp)
         return req
 
@@ -549,7 +588,7 @@ class Window:
     def rput(self, data, target: int, index: Optional[int] = None,
              disp: Optional[int] = None) -> Request:
         req = self._rma_request(target)
-        self._op("put", target, jnp.asarray(data), REPLACE, req,
+        self._op("put", target, _payload(data), REPLACE, req,
                  index=index, disp=disp)
         return req
 
@@ -557,7 +596,7 @@ class Window:
                     index: Optional[int] = None,
                     disp: Optional[int] = None) -> Request:
         req = self._rma_request(target)
-        self._op("acc", target, jnp.asarray(data), op, req, index=index,
+        self._op("acc", target, _payload(data), op, req, index=index,
                  disp=disp)
         return req
 
@@ -580,8 +619,8 @@ class Window:
         offset; with neither, an elementwise CAS over the whole slot
         (a documented whole-block extension)."""
         req = self._rma_request(target)
-        self._op("cas", target, jnp.asarray(value), None, req,
-                 compare=jnp.asarray(compare), index=index, disp=disp)
+        self._op("cas", target, _payload(value), None, req,
+                 compare=_payload(compare), index=index, disp=disp)
         return req
 
     # -- application -------------------------------------------------------

@@ -288,11 +288,11 @@ def _batch_header(todo: List[_PendingOp], seg: int
 
 def _fetch(parts: List) -> List[np.ndarray]:
     """The payloads on the host: every device array's copy is started
-    before the first is waited for."""
+    before the first is waited for; a payload queued from the host
+    (``window._payload``) is there already and goes as it is."""
     for x in parts:
-        start = getattr(x, "copy_to_host_async", None)
-        if start is not None:
-            start()
+        if isinstance(x, jax.Array):
+            x.copy_to_host_async()
     return [np.asarray(x) for x in parts]
 
 
@@ -300,12 +300,14 @@ def _pack_batch(todo: List[_PendingOp], seg: int,
                 header: Optional[Tuple] = None) -> Batch:
     """A pending-op batch as it goes on the wire. ``header``: the
     signature's frozen ``_batch_header`` (osc/plan), else composed
-    here; the payloads are fetched either way."""
+    here; the payloads are fetched either way (``ompi.osc.d2h``; its
+    ``bytes`` are what a device held: a host payload is not fetched)."""
     with _obs.span(_spans.OSC_PACK) as sp:
         meta, frames = header or _batch_header(todo, seg)
         parts = _payloads(todo)
-        with _obs.span(_spans.OSC_D2H,
-                       bytes=sum(_spans.nbytes(x) for x in parts)):
+        with _obs.span(_spans.OSC_D2H, bytes=sum(
+                _spans.nbytes(x) for x in parts
+                if isinstance(x, jax.Array))):
             arrays = _fetch(parts)
         batch = Batch(meta, frames, arrays)
         sp.set_metadata(bytes=batch.nbytes)

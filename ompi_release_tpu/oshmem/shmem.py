@@ -26,8 +26,11 @@ the delegate-to-MPI ``scoll/mpi`` component). TPU-native recast:
 - the **planned bulk path**: posted puts/AMOs between
   ``quiet()``/``fence()`` boundaries are batched
   per symmetric allocation as light host-side tuples — no per-call
-  ``jnp.asarray``, no per-call window queueing — and drained as ONE
-  window epoch, which the osc access-plan machinery (``osc/plan``)
+  window queueing — and drained as ONE window epoch: the drain hands
+  each source to the window as it is (a device array stays the
+  caller's object, an AMO's host operand stays on the host until the
+  batch frame or the epoch program takes it: ``osc/window._payload``),
+  which the osc access-plan machinery (``osc/plan``)
   closes as one fused device program per (allocation, signature), or,
   for a PE in another process, ships as one batch to its home.
   Posted ops therefore follow ``shmem_put_nbi`` source-buffer rules:
@@ -148,9 +151,9 @@ class ShmemCtx:
         # process has to walk its allocations in the same order
         self._allocs: Dict["SymmetricArray", None] = {}
         # planned bulk path: per-allocation queues of light
-        # (kind, pe, data, op, index, disp) tuples — jnp.asarray and
-        # window queueing are deferred to the drain, where the whole
-        # batch closes as ONE planned window epoch
+        # (kind, pe, data, op, index, disp) tuples — window queueing
+        # is deferred to the drain, where the whole batch closes as
+        # ONE planned window epoch
         self._bulk: Dict["SymmetricArray", List[Tuple]] = {}
 
     # -- setup / query (shmem.h accessors) ---------------------------------
@@ -192,7 +195,8 @@ class ShmemCtx:
         the window queue, so osc/plan keys the fused program by the
         object, never by an op name — and where the op has one its
         element ``index`` or the displacement ``disp`` of its range. A
-        host scalar stays one until the drain."""
+        host scalar stays one until the drain, and on the host after
+        it: the window queues it as a 0-d host array."""
         self._bulk.setdefault(sym, []).append(
             (kind, pe, data, op, index, disp))
         _bulk_ops.add()

@@ -52,7 +52,7 @@ APP = textwrap.dedent("""
     pieces = [jax.device_put(np.arange(PIECE, dtype=np.float32) + i)
               for i in range(WINDOW)]
     COUNTERS = ("osc_wire_bytes", "osc_wire_ops", "osc_rma_ops",
-                "osc_wire_requests")
+                "osc_wire_requests", "osc_host_payloads")
 
     def counters():
         return {k: pvar.PVARS.lookup(k).read() for k in COUNTERS}
@@ -187,7 +187,11 @@ def test_counters_tick_by_what_the_wire_audit_expects(ranks):
     assert delta["osc_wire_requests"] == 2  # one batch a flush
     # the home issued nothing and shipped nothing
     assert ranks[1]["delta"] == {"osc_wire_bytes": 0, "osc_wire_ops": 0,
-                                 "osc_rma_ops": 0, "osc_wire_requests": 0}
+                                 "osc_rma_ops": 0, "osc_wire_requests": 0,
+                                 "osc_host_payloads": 0}
+    # every block was a device array: queued as passed, fetched for the
+    # frame (``ompi.osc.d2h`` reports all of it, above)
+    assert delta["osc_host_payloads"] == 0
 
 
 def test_no_session_writes_nothing_and_delivers_the_same_bits(ranks):

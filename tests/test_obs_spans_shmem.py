@@ -10,7 +10,9 @@ sync inside each blocking call (it flushes its target once). ``shmem_ops``,
 ``shmem_blocking_ops`` and ``shmem_quiets`` tick by what was issued and the
 wire by one batch per quiet and one per blocking call. With no session open
 the same calls write nothing and deliver the same bits; with ``obs`` enabled
-the drain still journals as ``shmem_bulk_flush``.
+the drain still journals as ``shmem_bulk_flush``. The AMOs' operands are
+host scalars and stay on the host (ISSUE 37): ``osc_host_payloads`` counts
+them and ``ompi.osc.d2h`` reports the puts' device blocks alone.
 """
 
 import json
@@ -53,7 +55,8 @@ APP = textwrap.dedent("""
     pieces = [jax.device_put(np.arange(PIECE, dtype=np.int32) + 100 * i)
               for i in range(PUTS)]
     COUNTERS = ("shmem_ops", "shmem_blocking_ops", "shmem_quiets",
-                "shmem_bulk_ops", "shmem_bulk_flushes", "osc_wire_requests")
+                "shmem_bulk_ops", "shmem_bulk_flushes", "osc_wire_requests",
+                "osc_host_payloads")
 
     def counters():
         return {k: pvar.PVARS.lookup(k).read() for k in COUNTERS}
@@ -132,7 +135,7 @@ def test_a_quiet_holds_the_drain_and_the_drain_the_windows_sync(ranks):
         {"allocs": 1, "ops": PUTS + ADDS}, {"allocs": 0, "ops": 0}]
     (drain,) = drains
     assert T.inside(drain, quiets[0])
-    # the AMOs' scalars are host scalars: no bytes until they are staged
+    # the AMOs' operands are Python ints in the queue: no bytes there
     assert drain["stats"]["ops"] == PUTS + ADDS
     assert drain["stats"]["bytes"] == 4 * PIECE * PUTS
     syncs = [s for s in T.named(events, spans.OSC_SYNC)
@@ -162,16 +165,34 @@ def test_a_blocking_call_is_one_span_with_one_sync_inside(ranks):
                 if ".shmem." in e["name"] and e["name"] != spans.SHMEM_QUIET]
 
 
+def test_the_fetch_counts_what_a_device_held(ranks):
+    """``ompi.osc.d2h``'s ``bytes``: the puts' device blocks, not the
+    AMOs' host operands beside them in the quiet's batch; a blocking
+    call's batch holds host operands alone, or nothing."""
+    events = ranks[0]["events"]
+    syncs, fetches = (T.named(events, spans.OSC_SYNC),
+                      T.named(events, spans.OSC_D2H))
+    assert len(fetches) == len(syncs) == 1 + 5
+    assert all(T.inside(f, s) for f, s in zip(fetches, syncs))
+    assert [f["stats"]["bytes"] for f in fetches] == [
+        4 * PIECE * PUTS, 0, 0, 0, 0, 0]
+    # the sync still counts every payload it took off the queue
+    assert [s["stats"]["bytes"] for s in syncs] == [
+        4 * PIECE * PUTS + 4 * ADDS, 0, 0, 4, 4, 8]
+
+
 def test_counters_tick_by_what_was_issued(ranks):
     assert ranks[0]["delta"] == {
         "shmem_ops": PUTS + ADDS + 5, "shmem_blocking_ops": 5,
         "shmem_quiets": 2, "shmem_bulk_ops": PUTS + ADDS,
         "shmem_bulk_flushes": 1,
-        "osc_wire_requests": 1 + 5}  # one batch a quiet, one a blocking call
+        "osc_wire_requests": 1 + 5,  # one batch a quiet, one a blocking call
+        # the AMOs' operands and the one compare value; no put's block
+        "osc_host_payloads": ADDS + 3 + 1}
     assert ranks[1]["delta"] == {
         "shmem_ops": 0, "shmem_blocking_ops": 0, "shmem_quiets": 1,
         "shmem_bulk_ops": 0, "shmem_bulk_flushes": 0,
-        "osc_wire_requests": 0}
+        "osc_wire_requests": 0, "osc_host_payloads": 0}
 
 
 def test_no_session_writes_nothing_and_delivers_the_same_bits(ranks):

@@ -94,6 +94,35 @@ APP = textwrap.dedent("""
         ctx.put(flag, np.int32([7]), 1, offset=0)
         ctx.quiet()
     ctx.barrier_all()
+
+    # host operands stay on the host (ISSUE 37): a drain of one put and 64
+    # one-word AMOs, other operands each time, then 64 puts of device words
+    AMOS = 64
+    table = ctx.malloc((AMOS,), np.int32)
+    host_payloads = pvar.PVARS.lookup("osc_host_payloads")
+    plan_hits = pvar.PVARS.lookup("osc_plan_cache_hits")
+    base = jax.device_put(np.arange(AMOS, dtype=np.int32))
+    words = [jax.device_put(np.int32([1000 + j])) for j in range(AMOS)]
+    doc["drains"] = []
+    if me == 0:
+        for rep in range(4):
+            before = host_payloads.read(), plan_hits.read()
+            if rep < 3:
+                ctx.put(table, base, 1, offset=0)
+                for j in range(AMOS):
+                    ctx.atomic_add(table, 100 * rep + j, 1, index=j)
+            else:
+                for j, w in enumerate(words):
+                    ctx.put(table, w, 1, offset=j)
+            ctx.quiet()
+            hits = plan_hits.read()
+            doc["drains"].append(
+                [host_payloads.read() - before[0],
+                 hits["count"] - before[1]["count"],
+                 hits["sum"] - before[1]["sum"]])
+            doc.setdefault("table_seen", []).append(
+                hexed(ctx.get(table, 1)))
+    ctx.barrier_all()
     with open(os.path.join(out_dir, "rank%%d.json" %% me), "w") as f:
         json.dump(doc, f)
     shmem.shmem_finalize()
@@ -159,3 +188,18 @@ def test_wait_until_on_the_own_pe_makes_no_wire_request(ranks):
     assert waiter["test_before"] is False and waiter["test_after"] is True
     assert waiter["waited_for"] == 7
     assert waiter["requests_while_waiting"] == 0
+
+
+def test_a_drain_keeps_host_operands_on_the_host_and_its_plan(ranks):
+    """Per drain of PE 0: [host payloads queued, batches through the
+    template cache, of them replays]. 64 host operands a drain and none
+    from the device puts; other VALUES each time replay the one frozen
+    template (the first drain freezes it), and the home saw them."""
+    assert ranks[0]["drains"] == [
+        [64, 1, 0], [64, 1, 1], [64, 1, 1], [0, 1, 0]]
+    assert ranks[1]["drains"] == []
+    seen = [unhexed(v) for v in ranks[0]["table_seen"]]
+    for rep in range(3):
+        np.testing.assert_array_equal(
+            seen[rep], np.arange(64) + 100 * rep + np.arange(64))
+    np.testing.assert_array_equal(seen[3], 1000 + np.arange(64))
