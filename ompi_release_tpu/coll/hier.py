@@ -609,9 +609,14 @@ class _HierModule:
 
     @staticmethod
     def _fold(parts: list, op: Op):
-        acc = parts[0]
-        for nxt in parts[1:]:
-            acc = op(acc, nxt)
+        """``parts`` folded left to right: ``ompi.hier.fold`` (``bytes``
+        of the result; on device arrays until the last op is launched)."""
+        first = parts[0]
+        with _obs.span(_spans.HIER_FOLD, bytes=sum(map(
+                _spans.nbytes, first if op.is_pair_op else (first,)))):
+            acc = first
+            for nxt in parts[1:]:
+                acc = op(acc, nxt)
         return acc
 
     def _fold_flats(self, procs: List[int], flats: Dict[int, object],

@@ -65,6 +65,7 @@ import numpy as np
 from .. import obs as _obs
 from ..mca import pvar
 from ..mca import var as mca_var
+from ..obs import spans as _spans
 from ..utils import output
 from ..utils.errors import ErrorCode, MPIError
 
@@ -361,15 +362,36 @@ def allgather_ring(x, procs: List[int], me: int,
 # ---------------------------------------------------------------------------
 
 def _pad_chunks(mine, P: int, identity) -> tuple:
-    flat = _flat(mine)
-    L = flat.shape[0]
-    per = max(1, -(-L // P))
-    if per * P != L:
-        flat = np.concatenate(
-            [flat, np.full(per * P - L, identity, flat.dtype)])
-    elif not flat.flags.writeable:  # jax-backed views are read-only;
-        flat = flat.copy()          # rabenseifner accumulates in place
+    """(``mine`` flat, writable and padded with ``identity`` to
+    ``per * P`` elements, its own length, ``per``): ``ompi.hier.pad``,
+    whose ``bytes`` are what it copied (0: flat as it came)."""
+    with _obs.span(_spans.HIER_PAD) as sp:
+        flat = _flat(mine)
+        L = flat.shape[0]
+        per = max(1, -(-L // P))
+        copied = 0
+        if per * P != L:
+            flat = np.concatenate(
+                [flat, np.full(per * P - L, identity, flat.dtype)])
+            copied = flat.nbytes
+        elif not flat.flags.writeable:  # jax-backed views are read-only;
+            flat = flat.copy()          # rabenseifner accumulates in place
+            copied = flat.nbytes
+        sp.set_metadata(bytes=copied)
     return flat, L, per
+
+
+def fold(op: Callable, left, right, into=None) -> np.ndarray:
+    """One fold of an arrival into a chunk of the partial, operands in
+    the caller's fixed order, the result on the host and, where the
+    schedule accumulates in place, written back ``into`` its chunk:
+    ``ompi.hier.fold`` (``bytes`` of the result)."""
+    with _obs.span(_spans.HIER_FOLD, bytes=_spans.nbytes(right)):
+        out = np.asarray(op(left, right))
+        if into is None:
+            return out
+        into[:] = out
+        return into
 
 
 def allreduce_ring(x, procs: List[int], me: int, mine,
@@ -389,7 +411,7 @@ def allreduce_ring(x, procs: List[int], me: int, mine,
         got = _round(x, {nxt: [chunks[cs]]}, {prv: 1})[prv][0]
         # operand order is fixed: the travelling accumulator (earlier
         # ring positions) on the left, my partial on the right
-        chunks[cr] = np.asarray(op(_flat(got), chunks[cr]))
+        chunks[cr] = fold(op, _flat(got), chunks[cr])
     for s in range(P - 1):  # allgather of the reduced chunks
         cs = (mi + 1 - s) % P
         cr = (mi - s) % P
@@ -425,8 +447,8 @@ def allreduce_rabenseifner(x, procs: List[int], me: int, mine,
                            {partner: 1})[partner][0])
         seg = flat[keep[0] * per:keep[1] * per]
         # fixed operand order: the lower-position accumulator left
-        merged = op(got, seg) if mi & d else op(seg, got)
-        flat[keep[0] * per:keep[1] * per] = np.asarray(merged)
+        left, right = (got, seg) if mi & d else (seg, got)
+        fold(op, left, right, into=seg)
         lo, hi = keep
         d //= 2
     d = 1

@@ -1109,17 +1109,25 @@ class NativeXchg:
 
     def _arrivals(self, r: int) -> Dict[int, list]:
         """Round ``r``'s arrivals, per source in message order, with
-        the frozen plan's shapes and dtypes."""
+        the frozen plan's shapes and dtypes: ``ompi.plan.arrivals``,
+        whose ``bytes`` are what it copied out of the slab (0 where the
+        schedule takes views)."""
         npl = self.np
         pool = self._pool
         got: Dict[int, list] = {}
-        for src, lst in npl.pool_rounds[r]:
-            arrs = []
-            for _pool_idx, off, shape, dt, nb in lst:
-                a = pool[off:off + nb].view(dt).reshape(shape)
-                if not self.views:
-                    a = a.copy()
-                    _pool_copy_bytes.add(nb)
-                arrs.append(a)
-            got[src] = arrs
+        copied = 0
+        with _obs.span(_spans.PLAN_ARRIVALS, cid=npl.cid,
+                       seq=self.seq) as sp:
+            for src, lst in npl.pool_rounds[r]:
+                arrs = []
+                for _pool_idx, off, shape, dt, nb in lst:
+                    a = pool[off:off + nb].view(dt).reshape(shape)
+                    if not self.views:
+                        a = a.copy()
+                        copied += nb
+                    arrs.append(a)
+                got[src] = arrs
+            sp.set_metadata(bytes=copied)
+        if copied:
+            _pool_copy_bytes.add(copied)
         return got

@@ -52,7 +52,7 @@ import numpy as np
 from .. import obs as _obs
 from ..mca import pvar
 from . import hier_schedules as _hs
-from .hier_schedules import _concat, _flat, _round
+from .hier_schedules import _concat, _flat, _pad_chunks, _round
 
 #: topology-aware schedule executions (one bump per completed run) —
 #: the auditable "the topo family actually engaged" counter
@@ -148,19 +148,6 @@ def flat_ring_inter_bytes_total(n_elems: int, itemsize: int,
 # shared ring fragments
 # ---------------------------------------------------------------------------
 
-def _pad_flat(mine, slots: int, identity) -> Tuple[np.ndarray, int, int]:
-    """(flat padded to per*slots elements, original length, per)."""
-    flat = _flat(mine)
-    L = flat.shape[0]
-    per = max(1, -(-L // slots))
-    if per * slots != L:
-        flat = np.concatenate(
-            [flat, np.full(per * slots - L, identity, flat.dtype)])
-    elif not flat.flags.writeable:
-        flat = flat.copy()
-    return flat, L, per
-
-
 def _ring_reduce_scatter(x, ring: List[int], mi: int,
                          chunks: List[np.ndarray], op: Callable) -> int:
     """In-place ring reduce-scatter over ``ring``: P-1 rounds, chunk
@@ -173,7 +160,7 @@ def _ring_reduce_scatter(x, ring: List[int], mi: int,
         cs = (mi - s) % P
         cr = (mi - s - 1) % P
         got = _round(x, {nxt: [chunks[cs]]}, {prv: 1})[prv][0]
-        chunks[cr] = np.asarray(op(_flat(got), chunks[cr]))
+        chunks[cr] = _hs.fold(op, _flat(got), chunks[cr])
     return (mi + 1) % P
 
 
@@ -227,7 +214,7 @@ def allreduce_multiring(x, procs: List[int], me: int, mine,
     rec = _obs.enabled
     t0 = _time.perf_counter() if rec else 0.0
     mi = procs.index(me)
-    flat, L, per = _pad_flat(mine, k * P, identity)
+    flat, L, per = _pad_chunks(mine, k * P, identity)
     # chunks[j][c]: stripe j's chunk at ring position c
     chunks = [[flat[(j * P + c) * per:(j * P + c + 1) * per].copy()
                for c in range(P)] for j in range(k)]
@@ -244,7 +231,7 @@ def allreduce_multiring(x, procs: List[int], me: int, mine,
         for j in range(k):
             cr = (pos[j] - s_ - 1) % P
             g = _flat(got[prv[j]][0])
-            chunks[j][cr] = np.asarray(op(g, chunks[j][cr]))
+            chunks[j][cr] = _hs.fold(op, g, chunks[j][cr])
     for s_ in range(P - 1):  # allgather of the reduced chunks
         sends = {nxt[j]: [chunks[j][(pos[j] + 1 - s_) % P]]
                  for j in range(k)}
@@ -283,7 +270,7 @@ def allreduce_torus2d(x, procs: List[int], me: int, mine,
     gi, gj = _coords(grid, me)
     group = groups[gj]
     column = [groups[j][gi] for j in range(d1)]
-    flat, L, per0 = _pad_flat(mine, d0, identity)
+    flat, L, per0 = _pad_chunks(mine, d0, identity)
     chunks = [flat[c * per0:(c + 1) * per0].copy() for c in range(d0)]
     own = _ring_reduce_scatter(x, group, gi, chunks, op)   # shm
     part = _hs.allreduce_ring(x, column, me, chunks[own],  # DCN

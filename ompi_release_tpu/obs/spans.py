@@ -34,6 +34,17 @@ keyword stats of the event, never folded into the name:
                            (``cid``, ``seq``, ``bytes`` sent)
 ``ompi.hier.d2h``          a device buffer fetched to the host (``bytes``)
 ``ompi.hier.h2d``          a host result placed on the device (``bytes``)
+``ompi.plan.arrivals``     one round's arrivals of a native fire handed to
+                           the schedule body: views of the executor's
+                           slab, or copies out of it where the schedule
+                           folds (``cid``, ``seq``, ``bytes`` copied)
+``ompi.hier.pad``          a reduction's partial made flat and divisible
+                           before its first round: identity padding, or
+                           the copy of a read-only partial (``bytes``
+                           copied)
+``ompi.hier.fold``         one fold of arrivals into the partial between
+                           two exchanges: the op and the write-back
+                           (``bytes`` of the result written)
 ``ompi.hier.assemble``     the result of a bcast, allgather, gather or
                            alltoall built in one pass from the rank's
                            own buffer and the arrivals, until nothing
@@ -74,7 +85,12 @@ keyword stats of the event, never folded into the name:
                            (``kind``, ``peer``, ``bytes`` sent)
 ``ompi.osc.reply_wait``    inside it, from the payload sent to the reply
                            routed to its slot: both wire legs and the
-                           home's turn (``kind``, ``peer``)
+                           home's turn (``kind``, ``peer``; for a batch
+                           also ``token`` and, from the stamps the home
+                           sent back on its reply, ``turn_us``, of it
+                           ``recv_us`` and ``program_us``, and, between
+                           processes of one host, ``out_us`` and
+                           ``back_us``)
 ``ompi.osc.unpack``        the read values of a reply off the wire
                            (``bytes``; runs in whichever thread pumps
                            the reply channel)
@@ -82,7 +98,8 @@ keyword stats of the event, never folded into the name:
                            until it returns (``bytes``)
 ``ompi.osc.apply``         at the home, on the service thread: a batch
                            from its envelope to its reply sent
-                           (``origin``, ``ops``, ``bytes``)
+                           (``origin``, ``token``: the request's, as on
+                           its ``reply_wait``; ``ops``, ``bytes``)
 ``ompi.osc.program``       the call of an epoch program, interpreted or
                            planned, wherever it runs (``ops``)
 ``ompi.shmem.quiet``       ``ShmemCtx.quiet`` (so ``fence`` and
@@ -120,6 +137,9 @@ PLAN_XCHG = "ompi.plan.xchg"
 HIER_D2H = "ompi.hier.d2h"
 HIER_H2D = "ompi.hier.h2d"
 HIER_ASSEMBLE = "ompi.hier.assemble"
+PLAN_ARRIVALS = "ompi.plan.arrivals"
+HIER_PAD = "ompi.hier.pad"
+HIER_FOLD = "ompi.hier.fold"
 WIRE_STASH = "ompi.wire.stash"
 PML_SEND = "ompi.pml.send"
 PML_D2H = "ompi.pml.d2h"
@@ -146,7 +166,8 @@ NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
          PML_SEND, PML_D2H, WIRE_P2P_SEND, PML_RECV_WAIT, WIRE_P2P_PUMP,
          PML_H2D, HIER_ASSEMBLE, OSC_SYNC, OSC_PACK, OSC_D2H, OSC_REQUEST,
          OSC_REPLY_WAIT, OSC_UNPACK, OSC_H2D, OSC_APPLY, OSC_PROGRAM,
-         SHMEM_QUIET, SHMEM_DRAIN, SHMEM_GET, SHMEM_AMO)
+         SHMEM_QUIET, SHMEM_DRAIN, SHMEM_GET, SHMEM_AMO, PLAN_ARRIVALS,
+         HIER_PAD, HIER_FOLD)
 
 #: ``jax.profiler.TraceAnnotation`` and the ``obs`` package, bound on
 #: the first span: importing ``obs`` must not import jax (``obs

@@ -137,6 +137,51 @@ _wire_ops = pvar.counter(
     "RMA operations shipped to another process's window service",
 )
 
+#: The home's turn of a batch, timed at the home and sent back on its
+#: reply (``WinService._reply``'s ``turn``); the origin sums
+#: them over the replies it was routed. ``out`` and ``back`` compare a
+#: stamp of each process and tick only where the two share a clock
+#: (:func:`_same_host`); across hosts ``reply_wait - turn`` is their sum.
+_home_turn = pvar.timer(
+    "osc_home_turn_seconds",
+    "seconds the homes of this process's RMA batches spent on them, "
+    "request envelope taken to reply composed (stamped at the home)",
+)
+_home_recv = pvar.timer(
+    "osc_home_recv_seconds",
+    "of those, from the envelope taken to the batch's payload frames "
+    "off the wire and its request records parsed",
+)
+_home_program = pvar.timer(
+    "osc_home_program_seconds",
+    "of those, applying the batch: range checks, the window's lock, "
+    "the epoch program, the read values on the host",
+)
+_home_out = pvar.timer(
+    "osc_home_out_seconds",
+    "seconds from a batch's request envelope sent to the home's "
+    "service thread holding it (wire leg, wake-up, queue behind an "
+    "earlier batch); ticks only between processes of one host",
+)
+_home_back = pvar.timer(
+    "osc_home_back_seconds",
+    "seconds from the home composing a batch's reply to the reply "
+    "and its read frames routed at the origin; ticks only between "
+    "processes of one host",
+)
+
+#: the block of a reply that carries no turn (lock grant, abandon, error)
+_NO_TURN = (0, 0, 0, 0)
+
+
+def _same_host(router, pidx: int) -> bool:
+    """Does process ``pidx`` share this one's host, and so its
+    ``CLOCK_MONOTONIC``? What ``nativewire._same_host`` compares: the
+    two modex cards' ``host``."""
+    nw = router._nw
+    return nw is not None and nw._same_host(pidx)
+
+
 #: live window services (one per runtime) for the flight recorder's
 #: lock-table contributor — weak so a torn-down runtime's service
 #: never pins memory or shows up in dumps
@@ -499,8 +544,9 @@ class WinService:
                 if self._stop.is_set():
                     return
                 raise
+            h_recv = time.monotonic_ns()
             try:
-                self._handle(src_nid - 1, raw)
+                self._handle(src_nid - 1, raw, h_recv)
             except Exception as e:
                 # NOTHING may kill the service: a malformed frame, a
                 # corrupt npz, or a user error surfacing as a jax/numpy
@@ -510,7 +556,9 @@ class WinService:
                                 f"process {src_nid - 1}: "
                                 f"{type(e).__name__}: {e}")
 
-    def _handle(self, src_pidx: int, raw: bytes) -> None:
+    def _handle(self, src_pidx: int, raw: bytes, h_recv: int) -> None:
+        """One service frame; ``h_recv``: ``time.monotonic_ns()`` when
+        the service thread took it off its channel."""
         env = DssBuffer(raw)
         if env.unpack_string() != _WIN_MAGIC:
             _log.verbose(1, "win service: non-window frame dropped")
@@ -519,7 +567,8 @@ class WinService:
         token = int(token)
         if kind == KIND_BATCH:
             self._handle_batch(src_pidx, env, len(raw), int(cid),
-                               int(seq), int(arg1), int(arg2), token)
+                               int(seq), int(arg1), int(arg2), token,
+                               h_recv)
         elif kind == KIND_LOCK:
             win = self._window(int(cid), int(seq))
             granted = self.acquire(win, int(arg1), src_pidx, int(arg2),
@@ -543,15 +592,18 @@ class WinService:
 
     def _handle_batch(self, src_pidx: int, env, limit: int, cid: int,
                       seq: int, release_target: int, n_frames: int,
-                      token: int) -> None:
+                      token: int, h_recv: int) -> None:
         """One batch, from its envelope to its reply sent. Its frames
         must be consumed even if applying fails, and the origin must
         get SOME reply or it stalls for the full request timeout —
         failures reply KIND_ERROR (loud at the origin, service stays
-        alive)."""
+        alive). The turn is stamped on the way (``time.monotonic_ns``:
+        one clock for every process of a host) and rides back on the
+        reply; ``(origin, token)`` joins this span to the origin's
+        ``ompi.osc.reply_wait``."""
         rec = _obs.enabled  # capture once: flag may flip mid-apply
-        t0 = time.perf_counter() if rec else 0.0
-        with _obs.span(_spans.OSC_APPLY, origin=src_pidx) as sp:
+        with _obs.span(_spans.OSC_APPLY, origin=src_pidx,
+                       token=token) as sp:
             nbytes = 0
             try:
                 try:
@@ -572,7 +624,9 @@ class WinService:
                 win = self._window(cid, seq)
                 todo = _unpack_batch(meta, arrays)
                 sp.set_metadata(ops=len(todo), bytes=nbytes)
+                h_frames = time.monotonic_ns()  # and the program's start
                 reads = win._apply_home_batch(todo)
+                h_prog1 = time.monotonic_ns()
                 if release_target >= 0:
                     self.release(win, release_target, src_pidx)
             except Exception as e:
@@ -580,21 +634,32 @@ class WinService:
                                 f"{src_pidx} failed: {e}")
                 self._reply(src_pidx, cid, seq, KIND_ERROR, [], token)
                 return
+            h_reply = time.monotonic_ns()
+            self._reply(src_pidx, cid, seq, KIND_BATCH, reads, token,
+                        turn=(h_recv, h_reply, h_frames - h_recv,
+                              h_prog1 - h_frames))
             if rec and _obs.enabled:
                 # consumer side of the origin's (origin pidx, token)
-                # flow: both values rode the request envelope
-                _obs.record("win_apply", "osc", t0,
-                            time.perf_counter() - t0, nbytes=nbytes,
+                # flow: both values rode the request envelope. The
+                # interval is the turn as stamped (``perf_counter``,
+                # the journal's clock, is CLOCK_MONOTONIC too)
+                _obs.record("win_apply", "osc", h_recv / 1e9,
+                            (h_reply - h_recv) / 1e9, nbytes=nbytes,
                             peer=src_pidx, comm_id=cid,
                             flow=_obs.flow_id("win", src_pidx, token),
                             flow_side="t")
-            self._reply(src_pidx, cid, seq, KIND_BATCH, reads, token)
 
     def _reply(self, dst_pidx: int, cid: int, seq: int, kind: int,
-               reads: List[np.ndarray], token: int = 0) -> None:
+               reads: List[np.ndarray], token: int = 0,
+               turn: Tuple[int, int, int, int] = _NO_TURN) -> None:
+        """``turn``, a batch's: ``[h_recv, h_reply, h_frames - h_recv,
+        h_prog1 - h_frames]`` in ns of the home's ``CLOCK_MONOTONIC``,
+        behind the reply's own five int64s (in their item: a second one
+        would cost the origin a second parse); zeros on every other
+        kind of reply, which the origin ignores."""
         env = DssBuffer()
         env.pack_string(_WIN_MAGIC)
-        env.pack_int64([cid, seq, kind, len(reads), token])
+        env.pack_int64([cid, seq, kind, len(reads), token, *turn])
         frames: List[int] = []
         if reads:
             frames = _frames_of([int(v.nbytes) for v in reads],
@@ -622,7 +687,8 @@ class WinService:
 
     def _pump_replies(self, deadline: float) -> None:
         """Pop ONE reply (and its RDATA payload, if any) off the shared
-        reply channel and route it to its token's slot. Caller holds
+        reply channel and route it to its token's slot, with the home's
+        turn block and the instant it was routed. Caller holds
         ``_pump_lock``. Replies whose requester already timed out and
         deregistered are drained and dropped — their RDATA must be
         consumed here or the NEXT read-carrying reply would unpack the
@@ -641,7 +707,7 @@ class WinService:
         if renv.unpack_string() != _WIN_MAGIC:
             raise MPIError(ErrorCode.ERR_INTERN,
                            "corrupt window reply envelope")
-        rcid, rseq, rkind, n_reads, rtoken = renv.unpack_int64(5)
+        rcid, rseq, rkind, n_reads, rtoken, *turn = renv.unpack_int64(9)
         reads: List[np.ndarray] = []
         if int(n_reads) and int(rkind) != KIND_ERROR:
             # the owner's service thread sends a reply's read frames
@@ -666,6 +732,8 @@ class WinService:
             slot["cid"], slot["seq"] = int(rcid), int(rseq)
             slot["kind"] = int(rkind)
             slot["reads"] = reads
+            slot["turn"] = turn
+            slot["routed"] = time.monotonic_ns()
             slot["ev"].set()
 
     def request(self, win: "WireWindow", owner_pidx: int, kind: int,
@@ -691,7 +759,6 @@ class WinService:
         _win_requests.add()
         nbytes = payload.nbytes if payload is not None else 0
         rec = _obs.enabled  # capture once: flag may flip mid-request
-        t0 = time.perf_counter() if rec else 0.0
         wd_tok = None
         if _watchdog.enabled:
             wd_tok = _watchdog.arm(
@@ -701,7 +768,7 @@ class WinService:
                       "arg1": arg1, "arg2": arg2},
             )
         slot = {"ev": threading.Event(), "reads": None, "kind": None,
-                "cid": -1, "seq": -1}
+                "cid": -1, "seq": -1, "turn": _NO_TURN, "routed": 0}
         with self._reply_guard:
             self._reply_slots[token] = slot
         try:
@@ -722,23 +789,30 @@ class WinService:
                             WIRE_WIN_SERVICE, env.tobytes()),
                         f"window request to process {owner_pidx}",
                     )
+                    t_env = time.monotonic_ns()
                     if payload is not None:
                         _send_frames(self.router, owner_pidx,
                                      WIRE_WIN_DATA, payload.arrays,
                                      payload.frames)
                         _wire_bytes.add(nbytes)
+                with _obs.span(_spans.OSC_REPLY_WAIT, kind=kind,
+                               peer=owner_pidx) as sp:
+                    self._await_reply(slot, kind, owner_pidx, timeout_ms)
+                    if slot["turn"][0]:
+                        self._count_turn(sp, token, owner_pidx, t_env,
+                                         slot)
                 if rec and _obs.enabled:
                     # producer side: the home's win_apply span derives
-                    # the same (origin pidx, token) id from the envelope
+                    # the same (origin pidx, token) id from the
+                    # envelope. The interval is the request in flight,
+                    # envelope sent to reply routed: the stamps the
+                    # timers read
                     _obs.record(
-                        "win_request", "osc", t0,
-                        time.perf_counter() - t0, nbytes=nbytes,
+                        "win_request", "osc", t_env / 1e9,
+                        (slot["routed"] - t_env) / 1e9, nbytes=nbytes,
                         peer=owner_pidx, comm_id=win.comm.cid,
                         flow=_obs.flow_id("win", self.my_pidx, token),
                         flow_side="s")
-                with _obs.span(_spans.OSC_REPLY_WAIT, kind=kind,
-                               peer=owner_pidx):
-                    self._await_reply(slot, kind, owner_pidx, timeout_ms)
         finally:
             if wd_tok is not None:
                 _watchdog.disarm(wd_tok)
@@ -761,6 +835,27 @@ class WinService:
                 f"seq={win.win_seq}, kind={kind})",
             )
         return slot["reads"] or []
+
+    def _count_turn(self, sp, token: int, owner_pidx: int, t_env: int,
+                    slot: dict) -> None:
+        """A batch's reply is routed: its home's turn into the
+        ``osc_home_*_seconds`` timers and, as consecutive pieces from
+        the wait's own start — the way out, the turn, the way back —
+        into the stats of the open ``ompi.osc.reply_wait``."""
+        h_recv, h_reply, recv_ns, prog_ns = slot["turn"]
+        turn_ns = h_reply - h_recv
+        _home_turn.add(turn_ns / 1e9)
+        _home_recv.add(recv_ns / 1e9)
+        _home_program.add(prog_ns / 1e9)
+        stats = {"token": token, "turn_us": turn_ns / 1e3,
+                 "recv_us": recv_ns / 1e3, "program_us": prog_ns / 1e3}
+        if _same_host(self.router, owner_pidx):
+            out = max(0, h_recv - t_env)
+            back = max(0, slot["routed"] - h_reply)
+            _home_out.add(out / 1e9)
+            _home_back.add(back / 1e9)
+            stats.update(out_us=out / 1e3, back_us=back / 1e3)
+        sp.set_metadata(**stats)
 
     def _await_reply(self, slot: dict, kind: int, owner_pidx: int,
                      timeout_ms: int) -> None:

@@ -159,6 +159,14 @@ def profiler_trace(logdir: str):
       planned or interpreted (``cid``, ``seq``, ``bytes``)
     - ``ompi.hier.d2h`` / ``ompi.hier.h2d`` — a device buffer fetched
       to the host / a host result placed on the device (``bytes``)
+    - ``ompi.plan.arrivals`` — one round's arrivals of a native fire
+      handed to the schedule: views of the executor's slab, or copies
+      out of it where the schedule folds (``cid``, ``seq``, ``bytes``
+      copied); ``ompi.hier.pad`` — a reduction's partial made flat,
+      writable and divisible before its first round (``bytes`` copied);
+      ``ompi.hier.fold`` — one fold of arrivals into the partial
+      between two exchanges (``bytes`` of the result): what
+      ``ompi.nbc.wait`` holds beside its exchanges
     - ``ompi.hier.assemble`` — the result of a spanning bcast,
       allgather, gather or alltoall built in one pass from the rank's
       own buffer and the arrivals (``bytes`` of the result)
@@ -180,11 +188,15 @@ def profiler_trace(logdir: str):
       fetch of its device payloads is ``ompi.osc.d2h`` inside it),
       ``ompi.osc.request`` (the request to the home, entry to return:
       ``kind``, ``peer``, ``bytes``; from the payload sent to the reply
-      routed it is ``ompi.osc.reply_wait``, and the reply's read values
+      routed it is ``ompi.osc.reply_wait``, whose stats split a batch's
+      wait by the stamps its home sent back — ``token``, ``turn_us``,
+      of it ``recv_us`` and ``program_us``, and, between processes of
+      one host, ``out_us`` and ``back_us`` — and the reply's read values
       come off the wire under ``ompi.osc.unpack``) and ``ompi.osc.h2d``
       (the read values placed on this process's device) inside it
     - ``ompi.osc.apply`` — at a window's home, on the service thread: a
-      peer's batch from its envelope to its reply sent (``origin``,
+      peer's batch from its envelope to its reply sent (``origin`` and
+      ``token``, which its ``reply_wait`` at the origin carries too;
       ``ops``, ``bytes``)
     - ``ompi.osc.program`` — the call of an epoch program, interpreted
       or planned, wherever it runs (``ops``)

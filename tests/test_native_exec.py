@@ -466,6 +466,7 @@ class TestSlabViews:
 
     def _xchg(self, px, views):
         npl = nx.NativePlan()
+        npl.cid = 0  # the stat of ``ompi.plan.arrivals``
         npl.pool_rounds = [[(0, [(0, 0, (2, self.NB // 8),
                                   np.dtype("int32"), self.NB)])]]
         x = nx.NativeXchg(None, None, npl, (), views=views)

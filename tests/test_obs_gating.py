@@ -359,15 +359,21 @@ def test_span_sites_exist_and_only_the_helper_annotates():
               # ISSUE 36: the OpenSHMEM layer
               "SHMEM_QUIET": "oshmem/shmem.py",
               "SHMEM_DRAIN": "oshmem/shmem.py",
-              "SHMEM_GET": "oshmem/shmem.py", "SHMEM_AMO": "oshmem/shmem.py"}
+              "SHMEM_GET": "oshmem/shmem.py", "SHMEM_AMO": "oshmem/shmem.py",
+              # ISSUE 38: what ompi.nbc.wait holds between two exchanges
+              # (HIER_FOLD's second site, the exact-order fold, is in
+              # coll/hier.py)
+              "PLAN_ARRIVALS": "coll/native_exec.py",
+              "HIER_PAD": "coll/hier_schedules.py",
+              "HIER_FOLD": "coll/hier_schedules.py"}
     import importlib.util  # obs/spans.py by path: the package pulls jax
     spec = importlib.util.spec_from_file_location(
         "_spans_only", os.path.join(REPO, SPANS_MODULE))
     spans = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(spans)
     # sixteen names since ISSUE 31, nine more since ISSUE 34, four since
-    # ISSUE 36, each with a site of its own
-    assert len(spans.NAMES) == 29
+    # ISSUE 36, three since ISSUE 38, each with a site of its own
+    assert len(spans.NAMES) == 32
     assert {getattr(spans, const) for const in wanted} == set(spans.NAMES)
     for const, rel in wanted.items():
         path = os.path.join(pkg, rel)

@@ -12,7 +12,10 @@ trace see is what is asserted here.
 - a two-process spanning job: ``d2h``/exchange/``h2d`` inside their
   ``call``, the exchange's ``(cid, seq)`` equal to its
   ``ompi.nbc.wait``'s, on the interpreted, the planned and the native
-  path; ``native_fire`` and ``xchg`` never nested;
+  path; ``native_fire`` and ``xchg`` never nested; what a folding
+  allreduce of 64 KiB does between its exchanges — the arrivals handed
+  out, the partial padded, the folds — each under its own span inside
+  the wait;
 - with no session nothing is written and results are bit-identical;
 - with ``obs.enabled`` the journal holds the ``(op, layer)`` names it
   held before the spans existed, and a journaled span agrees with its
@@ -348,6 +351,29 @@ APP = textwrap.dedent("""
     obs.disable()
     doc["journal"] = [[s.op, s.layer, obs.wall_ns(s.t_start), s.dt]
                       for s in obs.journal.snapshot()]
+    # 4. a partial of 64 KiB: the folding schedule (Rabenseifner at two
+    #    processes), recorded, replayed, then traced; and a bcast from
+    #    the other process, whose arrival is read once and never kept
+    from ompi_release_tpu.mca import pvar
+    big = jax.device_put(np.stack([np.arange(16384, dtype=np.float32)
+                                   %% 7 + off + i for i in range(2)]))
+    copied = pvar.PVARS.lookup("plan_pool_copy_bytes")
+
+    def folding_round():
+        return [np.asarray(o).tobytes() for o in (
+            world.allreduce(big), world.bcast(big, root=2))]
+
+    folding_round()
+    untraced = folding_round()
+    c0 = copied.read()
+    if me == 0:
+        with tools_trace.profiler_trace(os.path.join(out_dir, "fold")):
+            outs = folding_round()
+        doc["folding"] = T.read_xplane(os.path.join(out_dir, "fold"))
+    else:
+        outs = folding_round()
+    doc["folding_copied"] = copied.read() - c0
+    doc["folding_bit_identical"] = outs == untraced
     if me == 0:
         with open(os.path.join(out_dir, "rank0.json"), "w") as f:
             json.dump(doc, f)
@@ -476,3 +502,43 @@ def test_spanning_observed_journal_names_and_one_timeline(spanning):
     for (_, _, wall, dt), ev in zip(fires, marks):
         assert abs((start + ev["t0"]) - wall) < 1e6
         assert abs((ev["t1"] - ev["t0"]) - dt * 1e9) < 1e6
+
+
+def test_a_folding_allreduce_names_what_its_wait_holds(spanning):
+    """ISSUE 38: between the segments of its native fire a Rabenseifner
+    allreduce of 64 KiB takes its arrivals out of the executor's slab
+    (copies: ``plan_pool_copy_bytes``), pads (here: copies the read-only
+    fetch of) its partial and folds once; a bcast's arrival is a view of
+    the slab and nothing is folded."""
+    _, events = spanning["folding"]
+    (reduce, kids), (bcast, moved) = _calls_with_children(events)
+    assert (reduce["stats"]["op"], bcast["stats"]["op"]) == (
+        "allreduce", "bcast")
+    (wait,) = named(kids, spans.NBC_WAIT)
+    arrivals = named(kids, spans.PLAN_ARRIVALS)
+    (pad,), (fold,) = named(kids, spans.HIER_PAD), named(kids,
+                                                         spans.HIER_FOLD)
+    fires = named(kids, spans.PLAN_NATIVE_FIRE)
+    assert len(fires) == 2 and len(arrivals) == 2  # one live round
+    inner = arrivals + [pad, fold]
+    assert all(inside(e, wait) for e in inner)
+    assert sum(e["t1"] - e["t0"] for e in inner + fires) <= (
+        wait["t1"] - wait["t0"])
+    assert not any(inside(a, b) for a in inner + fires
+                   for b in inner + fires if a is not b)
+    for e in arrivals:
+        assert (e["stats"]["cid"], e["stats"]["seq"]) == (
+            wait["stats"]["cid"], wait["stats"]["seq"])
+    # each round brings half of the 64 KiB partial; both are copied out
+    assert [e["stats"]["bytes"] for e in arrivals] == [32768, 32768]
+    assert spanning["folding_copied"] == 65536
+    assert pad["stats"] == {"bytes": 65536}  # a jax-backed fetch: read-only
+    assert fold["stats"] == {"bytes": 32768}
+    assert pad["t1"] <= fires[0]["t0"] and fires[0]["t1"] <= fold["t0"]
+    assert fold["t1"] <= fires[1]["t0"]
+    # the bcast: one arrival, handed out as a view, no fold, no pad
+    (view,) = named(moved, spans.PLAN_ARRIVALS)
+    assert view["stats"]["bytes"] == 0
+    assert inside(view, named(moved, spans.NBC_WAIT)[0])
+    assert not named(moved, spans.HIER_FOLD) + named(moved, spans.HIER_PAD)
+    assert spanning["folding_bit_identical"] is True

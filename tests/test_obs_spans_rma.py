@@ -10,6 +10,14 @@ under ``unpack`` and are placed under ``h2d``); rank 1's, the home's, holds
 ``ompi.osc.apply`` with the epoch program inside it, on the service thread.
 ``osc_wire_bytes`` and ``osc_wire_ops`` move by what the wire audit expects.
 With no session open the same calls write nothing and deliver the same bits.
+
+The home's turn (ISSUE 38): every batch reply carries the home's stamps,
+the origin sums them into the five ``osc_home_*_seconds`` timers, once per
+batch, and writes them as stats of its open ``ompi.osc.reply_wait``, whose
+``token`` the home's ``ompi.osc.apply`` carries too. A lock grant, an
+abandon and an error reply carry zeros and tick nothing; between processes
+that do not share a host ``out`` and ``back`` stand still; the reply of a
+request that timed out is still drained, frames and all.
 """
 
 import json
@@ -53,6 +61,11 @@ APP = textwrap.dedent("""
               for i in range(WINDOW)]
     COUNTERS = ("osc_wire_bytes", "osc_wire_ops", "osc_rma_ops",
                 "osc_wire_requests", "osc_host_payloads")
+    TIMERS = tuple("osc_home_%%s_seconds" %% k
+                   for k in ("turn", "recv", "program", "out", "back"))
+
+    def timers():
+        return [pvar.PVARS.lookup(k).read() for k in TIMERS]
 
     def counters():
         return {k: pvar.PVARS.lookup(k).read() for k in COUNTERS}
@@ -78,12 +91,13 @@ APP = textwrap.dedent("""
         win.lock(1, LOCK_SHARED)
     calls()  # compiles, plans and first contacts, outside every count
     world.barrier()
-    before = counters()
+    before, t_before = counters(), timers()
     with tools_trace.profiler_trace(os.path.join(out_dir, "t%%d" %% me)):
         doc["traced"] = calls()
     doc["events"] = T.read_xplane(os.path.join(out_dir, "t%%d" %% me))[1]
     after = counters()
     doc["delta"] = {k: after[k] - before[k] for k in COUNTERS}
+    doc["timers"] = [a - b for a, b in zip(timers(), t_before)]
     world.barrier()
     doc["untraced"] = calls()
     # no session: the sites above wrote nothing
@@ -91,6 +105,76 @@ APP = textwrap.dedent("""
         pass
     doc["events_after"] = T.read_xplane(os.path.join(out_dir, "e%%d" %% me))[1]
     if me == 0:
+        win.unlock(1)
+
+    # -- replies that carry no turn, a peer on another clock, a stale reply
+    from ompi_release_tpu.osc import wire_win
+    from ompi_release_tpu.osc.window import LOCK_EXCLUSIVE, _PendingOp
+    from ompi_release_tpu.request.request import Request
+    from ompi_release_tpu.utils.errors import MPIError
+    svc, requests = win.service, pvar.PVARS.lookup("osc_wire_requests")
+
+    def ticked(fn):
+        # (what fn returned, each timer's change, requests made)
+        t0, r0 = timers(), requests.read()
+        got = fn()
+        return got, [a - b for a, b in zip(timers(), t0)], requests.read() - r0
+
+    def refused():
+        try:
+            svc.request(win, 1, wire_win.KIND_BATCH, -1, 0,
+                        payload=wire_win.Batch('{"ops": 1}', (), []))
+        except MPIError as e:
+            return e.code.name
+
+    def on_another_host():
+        keep = wire_win._same_host
+        wire_win._same_host = lambda router, pidx: False
+        try:
+            win.put(pieces[0], 1, disp=0)
+            win.flush(1)
+        finally:
+            wire_win._same_host = keep
+
+    def late():
+        # the home cannot take the window's lock for a second: the
+        # request gives up first, and its reply, read frame and all,
+        # arrives with nobody waiting
+        seg = svc.tuning().segment
+        op = _PendingOp("get", 1, request=Request(), disp=0, count=PIECE)
+        try:
+            svc.request(win, 1, wire_win.KIND_BATCH, -1, 0,
+                        payload=wire_win._pack_batch([op], seg),
+                        timeout_ms=300)
+        except MPIError as e:
+            return e.code.name
+
+    def get_again():
+        req = win.get(1, disp=0, count=PIECE)
+        win.flush(1)
+        return np.asarray(req.value).tobytes().hex()
+
+    if me == 0:
+        # an abandon with no interest to forget: the home just answers
+        doc["abandon"] = ticked(lambda: svc.request(
+            win, 1, wire_win.KIND_ABANDON, 1, 0))[1:]
+        doc["grant"] = ticked(lambda: win.lock(1, LOCK_EXCLUSIVE))[1:]
+        doc["refused"] = ticked(refused)
+        doc["other_host"] = ticked(on_another_host)[1:]
+    world.barrier()
+    if me == 1:
+        with win._op_lock:
+            world.barrier()
+            time.sleep(1.0)
+    else:
+        world.barrier()
+        doc["late"] = ticked(late)
+    world.barrier()
+    if me == 0:
+        with tools_trace.profiler_trace(os.path.join(out_dir, "s0")):
+            doc["after_late"] = ticked(get_again)
+        doc["events_late"] = T.read_xplane(os.path.join(out_dir, "s0"))[1]
+        doc["want"] = np.asarray(pieces[0]).tobytes().hex()
         win.unlock(1)
     win.free()
     with open(os.path.join(out_dir, "rank%%d.json" %% me), "w") as f:
@@ -150,7 +234,11 @@ def test_a_request_holds_its_reply_wait_and_a_reply_its_unpack(ranks):
         assert pack["t1"] <= req["t0"]  # composed, then sent
         assert req["stats"] == {"kind": KIND_BATCH, "peer": 1,
                                 "bytes": pack["stats"]["bytes"]}
-        assert wait["stats"] == {"kind": KIND_BATCH, "peer": 1}
+        assert set(wait["stats"]) == {"kind", "peer", "token", "out_us",
+                                      "turn_us", "recv_us", "program_us",
+                                      "back_us"}
+        assert (wait["stats"]["kind"], wait["stats"]["peer"]) == (
+            KIND_BATCH, 1)
     # only the gets' reply carries read values: off the wire inside the
     # wait, onto the device after it, inside the flush
     (unpack,), (h2d,) = (T.named(events, spans.OSC_UNPACK),
@@ -173,6 +261,11 @@ def test_the_home_applies_each_batch_with_its_program_inside(ranks):
         assert prog["stats"] == {"ops": WINDOW}
         assert apply["stats"]["origin"] == 0
         assert apply["stats"]["ops"] == WINDOW
+    # spans of one request share an identifier: (origin, token)
+    waits = T.named(osc(ranks[0]["events"]), spans.OSC_REPLY_WAIT)
+    assert [a["stats"]["token"] for a in applies] == [
+        w["stats"]["token"] for w in waits]
+    assert waits[0]["stats"]["token"] != waits[1]["stats"]["token"]
     assert BYTES < applies[0]["stats"]["bytes"] <= BYTES + 2048
     assert 0 < applies[1]["stats"]["bytes"] <= 2048
     assert {e["name"] for e in events} == {spans.OSC_APPLY,
@@ -199,3 +292,63 @@ def test_no_session_writes_nothing_and_delivers_the_same_bits(ranks):
         assert doc["events_after"] == []
         assert doc["traced"] == doc["untraced"]
         assert len(doc["traced"]) == (WINDOW if doc["rank"] == 0 else 1)
+
+
+TURN, RECV, PROGRAM, OUT, BACK = range(5)  # the app's TIMERS, in order
+
+
+def test_the_homes_turn_ticks_once_a_batch_into_timers_and_stats(ranks):
+    turn, recv, program, out, back = ranks[0]["timers"]
+    waits = T.named(osc(ranks[0]["events"]), spans.OSC_REPLY_WAIT)
+    # one reply routed per request made: the timers are the sums of
+    # what each wait wrote into its own stats
+    assert len(waits) == ranks[0]["delta"]["osc_wire_requests"] == 2
+    for value, stat in ((turn, "turn_us"), (recv, "recv_us"),
+                        (program, "program_us"), (out, "out_us"),
+                        (back, "back_us")):
+        assert value * 1e6 == pytest.approx(
+            sum(w["stats"][stat] for w in waits), abs=0.01)
+    assert 0 < recv and 0 < program and recv + program <= turn
+    assert out >= 0 and back >= 0
+    # a get has no payload frame: the wait opens as the envelope is
+    # sent, so the three pieces lie inside the request and fill most of
+    # the wait (bounds a loaded machine keeps)
+    get_wait = waits[1]["stats"]
+    pieces = get_wait["out_us"] + get_wait["turn_us"] + get_wait["back_us"]
+    req = T.named(osc(ranks[0]["events"]), spans.OSC_REQUEST)[1]
+    assert pieces * 1e3 <= req["t1"] - req["t0"]
+    assert pieces * 1e3 >= (waits[1]["t1"] - waits[1]["t0"]) / 2
+    # the home made no request: nothing was routed to it
+    assert ranks[1]["timers"] == [0, 0, 0, 0, 0]
+
+
+def test_a_reply_without_a_turn_ticks_nothing(ranks):
+    doc = ranks[0]
+    # a lock grant and an abandon: one request each, zeros on the reply
+    assert doc["grant"] == [[0, 0, 0, 0, 0], 1]
+    assert doc["abandon"] == [[0, 0, 0, 0, 0], 1]
+    # an error reply is loud at the origin, as before, and counts nothing
+    assert doc["refused"] == ["ERR_RMA_SYNC", [0, 0, 0, 0, 0], 1]
+
+
+def test_across_hosts_only_the_turn_ticks(ranks):
+    delta, made = ranks[0]["other_host"]
+    assert made == 1
+    assert delta[TURN] > 0 and delta[RECV] > 0 and delta[PROGRAM] > 0
+    assert delta[OUT] == 0 and delta[BACK] == 0
+
+
+def test_a_stale_reply_is_still_drained(ranks):
+    doc = ranks[0]
+    # the request that gave up was routed nothing ...
+    assert doc["late"] == ["ERR_PENDING", [0, 0, 0, 0, 0], 1]
+    # ... and the next one finds its own values behind the late reply's
+    got, delta, made = doc["after_late"]
+    assert got == doc["want"] and made == 1 and delta[TURN] > 0
+    events = osc(doc["events_late"])
+    (wait,) = T.named(events, spans.OSC_REPLY_WAIT)
+    unpacks = T.named(events, spans.OSC_UNPACK)
+    assert [u["stats"]["bytes"] for u in unpacks] == [4 * PIECE] * 2
+    assert all(T.inside(u, wait) for u in unpacks)
+    assert delta[TURN] * 1e6 == pytest.approx(wait["stats"]["turn_us"],
+                                              abs=0.01)

@@ -12,7 +12,9 @@ wire by one batch per quiet and one per blocking call. With no session open
 the same calls write nothing and deliver the same bits; with ``obs`` enabled
 the drain still journals as ``shmem_bulk_flush``. The AMOs' operands are
 host scalars and stay on the host (ISSUE 37): ``osc_host_payloads`` counts
-them and ``ompi.osc.d2h`` reports the puts' device blocks alone.
+them and ``ompi.osc.d2h`` reports the puts' device blocks alone. Each batch's
+reply brings the home's turn back (ISSUE 38): the ``osc_home_*_seconds``
+timers tick once per blocking call and once per allocation a quiet drained.
 """
 
 import json
@@ -58,8 +60,11 @@ APP = textwrap.dedent("""
                 "shmem_bulk_ops", "shmem_bulk_flushes", "osc_wire_requests",
                 "osc_host_payloads")
 
+    TIMERS = tuple("osc_home_%%s_seconds" %% k
+                   for k in ("turn", "recv", "program", "out", "back"))
+
     def counters():
-        return {k: pvar.PVARS.lookup(k).read() for k in COUNTERS}
+        return {k: pvar.PVARS.lookup(k).read() for k in COUNTERS + TIMERS}
 
     def calls():
         # PE 0 alone issues; PE 1's service thread applies
@@ -90,6 +95,7 @@ APP = textwrap.dedent("""
     doc["events"] = T.read_xplane(os.path.join(out_dir, "t%%d" %% me))[1]
     after = counters()
     doc["delta"] = {k: after[k] - before[k] for k in COUNTERS}
+    doc["timers"] = [after[k] - before[k] for k in TIMERS]
     ctx.barrier_all()
     doc["untraced"] = calls()
     # no session: the sites above wrote nothing
@@ -206,3 +212,24 @@ def test_the_drain_still_journals_as_the_bulk_flush(ranks):
     assert ranks[0]["journal"] == [
         ["shmem_bulk_flush", "osc", 4 * PIECE * PUTS]]
     assert ranks[1]["journal"] == []
+
+
+def test_the_homes_turn_ticks_once_a_blocking_call_and_a_drain(ranks):
+    events = ranks[0]["events"]
+    waits = T.named(events, spans.OSC_REPLY_WAIT)
+    quiets = T.named(events, spans.SHMEM_QUIET)
+    blocking = T.named(events, spans.SHMEM_GET) + T.named(events,
+                                                          spans.SHMEM_AMO)
+    # one routed reply, with its turn, per fetching AMO and blocking get;
+    # one for the allocation the first quiet drained (the idle allocation
+    # and the second quiet have nothing to ask a home)
+    for call in blocking + quiets[:1]:
+        (mine,) = [w for w in waits if T.inside(w, call)]
+        assert mine["stats"]["turn_us"] >= mine["stats"]["program_us"] > 0
+    assert not [w for w in waits if T.inside(w, quiets[1])]
+    assert len(waits) == ranks[0]["delta"]["osc_wire_requests"]
+    turn, recv, program, out, back = ranks[0]["timers"]
+    assert turn * 1e6 == pytest.approx(
+        sum(w["stats"]["turn_us"] for w in waits), abs=0.01)
+    assert 0 < recv + program <= turn and out >= 0 and back >= 0
+    assert ranks[1]["timers"] == [0, 0, 0, 0, 0]
