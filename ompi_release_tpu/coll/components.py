@@ -129,11 +129,13 @@ class _XlaModule:
         )
 
     def allgather(self, comm, x):
-        def body(xb):
-            g = lax.all_gather(xb, AXIS, axis=0)  # (n, ...)
-            return g.reshape((-1,) + g.shape[2:])
-
-        return run_sharded(comm, ("xla", "allgather"), body, x)
+        # tiled: the rank's (N, ...) block gathers straight into the
+        # (nN, ...) result, 7 % faster on v5e than gathering (n, N, ...)
+        # and reshaping it (PERF.md §6)
+        return run_sharded(
+            comm, ("xla", "allgather"),
+            lambda xb: lax.all_gather(xb, AXIS, axis=0, tiled=True), x,
+        )
 
     def gather(self, comm, x, root: int):
         return run_sharded(
@@ -171,14 +173,13 @@ class _XlaModule:
         )
 
     def alltoall(self, comm, x):
-        n = comm.size
-
-        def body(xb):
-            blocks = xb.reshape((n, -1) + xb.shape[1:])
-            out = spmd.alltoall_lax(blocks, AXIS, n)
-            return out.reshape(xb.shape)
-
-        return run_sharded(comm, ("xla", "alltoall"), body, x)
+        # tiled on the rank's block: its j-th 1/n goes to rank j, and
+        # rank j's block for us lands in the j-th 1/n
+        return run_sharded(
+            comm, ("xla", "alltoall"),
+            lambda xb: lax.all_to_all(xb, AXIS, split_axis=0,
+                                      concat_axis=0, tiled=True), x,
+        )
 
     def scan(self, comm, x, op: Op, *, exclusive: bool = False):
         n = comm.size

@@ -658,15 +658,28 @@ def allgather_ring(x: jax.Array, axis_name: str, n: int) -> jax.Array:
     return out
 
 
+# a 32-bit TPU array is tiled (8, 128): a 1-D block of whole tiles
+# lies in memory exactly as its (rows, 128) view does
+_LANES = 128
+_TILE = 8 * _LANES
+
+
 def reduce_scatter_lax(x: jax.Array, op: Op, axis_name: str,
                        n: int) -> jax.Array:
-    """reduce_scatter_block: x is (n*chunk,) per rank; rank i gets the
-    reduced i-th chunk. SUM uses the fused psum_scatter."""
+    """reduce_scatter_block: x is (n*chunk, ...) per rank; rank i gets
+    the reduced i-th chunk. SUM runs psum_scatter tiled on the block as
+    it lies: a (n, chunk) view of it is a relayout on the TPU. A 1-D
+    block of whole tiles goes as 128-lane rows, the same bytes, which
+    XLA:TPU reduce-scatters; on the flat vector it runs a whole
+    all-reduce and slices it (PERF.md §5)."""
     chunk = x.shape[0] // n
-    blocks = x.reshape((n, chunk) + x.shape[1:])
     if op.lax_collective == "psum":
-        return lax.psum_scatter(blocks, axis_name, scatter_dimension=0,
-                                tiled=False)
+        rows = (x.reshape(-1, _LANES)
+                if x.ndim == 1 and x.shape[0] % (n * _TILE) == 0 else x)
+        out = lax.psum_scatter(rows, axis_name, scatter_dimension=0,
+                               tiled=True)
+        return out.reshape((chunk,) + x.shape[1:])
+    blocks = x.reshape((n, chunk) + x.shape[1:])
     # generic: allreduce then take own chunk
     red = allreduce_lax(blocks, op, axis_name)
     rank = lax.axis_index(axis_name)
