@@ -7,7 +7,8 @@ nothing that is there:
 
     configs/<config>.json   a deployment: launcher, ranks, operations, dtypes
     traffic/<traffic>.json  a mix: sizes per rank, slice to trace
-    ops/<operation>.py      how to call it, its reference, its least bytes
+    ops/<operation>.py      how to call it, its reference, its least bytes,
+                            and optionally its own inputs (make)
     metrics/<metric>.json   a reader from readers/ and its parameters
     readers/<reader>.py     read(facts, **parameters) -> number or None
 """

@@ -123,7 +123,8 @@ class Bench:
 
         jax = self.jax
         self.full = traffic.make_inputs(
-            seed, self.keys, self.n, None if self.spanning else self.sharding)
+            seed, self.keys, self.n, None if self.spanning else self.sharding,
+            self.cfg)
         if self.spanning:
             rows = slice(self.off, self.off + self.local_n)
             self.local = {k: jax.device_put(v[rows], self.sharding)

@@ -8,6 +8,9 @@ import pytest
 import perfbench_rehearsal as rh
 
 CELLS = rh.cells("tpurun")
+# the cell the control and the planted faults are written against: it
+# makes an allreduce and a bcast on both ranks
+FAULTS_ON = "osu_span2.large"
 
 
 @pytest.mark.parametrize("trace", [0, 1])
@@ -17,11 +20,17 @@ def test_last_line_of_a_rehearsal(capfd, cell, trace):
     rh.check_line(line, cell, trace, err)
 
 
+def test_the_faults_cell_is_one_of_the_launchers_cells():
+    assert FAULTS_ON in CELLS
+    ops = rh.MAN.cell(FAULTS_ON)["operations"]
+    assert "allreduce" in ops and "bcast" in ops
+
+
 def test_the_lower_precision_control_is_not_correct(capfd):
-    rh.check_control(capfd, CELLS[0])
+    rh.check_control(capfd, FAULTS_ON)
 
 
 @pytest.mark.parametrize("fault, number", [("no_exchange", "sum_err_ulp"),
                                            ("altered", "moved_mismatch")])
 def test_a_broken_timed_path_is_not_correct(capfd, monkeypatch, fault, number):
-    rh.check_fault(capfd, monkeypatch, CELLS[0], fault, number)
+    rh.check_fault(capfd, monkeypatch, FAULTS_ON, fault, number)

@@ -29,6 +29,17 @@ FAULTY = os.path.join(rh.HERE, "faulty_rma_worker.py")
 X = np.stack([np.arange(128, dtype=np.float32),
               1000 + np.arange(128, dtype=np.float32)])
 MIB = 1 << 20
+RMA_LARGE = {f"{k}.rma_large" for k in (
+    "osc_sync_ms", "osc_pack_ms", "osc_d2h_ms", "osc_wait_ms",
+    "osc_unpack_ms", "osc_h2d_ms", "rma_ops_per_call", "batches_per_call",
+    "wire_mb", "plan_hit_pct", "home_turn_ms", "home_recv_ms",
+    "home_program_ms", "home_out_ms", "home_back_ms")}
+SPAN_LARGE = {f"{k}.span_large" for k in (
+    "device_busy_ms", "peak_share_pct", "stall_ms", "fallback_copies",
+    "device_idle_pct")}
+SPAN_SMALL = {f"{k}.span_small" for k in (
+    "dispatch_us", "device_busy_us", "peak_share_pct", "native_fire_pct",
+    "device_idle_pct", "d2h_us", "h2d_us", "xchg_us")}
 
 
 @pytest.mark.parametrize("op, rank, want", [
@@ -78,20 +89,22 @@ def test_the_cells_and_their_rounds():
     assert {m["name"] for m in rh.MAN.metrics_of("osu_rma.stream",
                                                  "end_to_end")} == {
         "span_algbw", "setup_s"}
-    layer = [m["name"] for m in rh.MAN.metrics_of("osu_rma.stream",
-                                                  "per_layer")]
-    assert sum(n.endswith(".rma_large") for n in layer) == 10
-    assert sum(n.endswith(".span_large") for n in layer) == 5
+    # by name: an entry appended later, for this cell or another, moves
+    # no pin
+    layer = {m["name"] for m in rh.MAN.metrics_of("osu_rma.stream",
+                                                  "per_layer")}
+    assert RMA_LARGE <= layer and SPAN_LARGE <= layer
     # after it, on the configuration that is there: entries alone
     names = list(rh.MAN.cells)
-    assert names[-2:] == ["osu_rma.stream", "osu_span4.small"]
+    assert names.index("osu_rma.stream") < names.index("osu_span4.small")
     small = rh.MAN.cell("osu_span4.small")
     assert small["chips"] == 4 and small["config"]["ranks"] == 4
     assert len(traffic.round_of(small)) == 15  # five collectives, three sizes
     assert {m["name"] for m in rh.MAN.metrics_of("osu_span4.small",
                                                  "end_to_end")} == {
         "span_call_us", "span_call_p95_us", "setup_s"}
-    assert len(rh.MAN.metrics_of("osu_span4.small", "per_layer")) == 8
+    assert SPAN_SMALL <= {m["name"] for m in rh.MAN.metrics_of(
+        "osu_span4.small", "per_layer")}
     assert {"osu_rma.stream", "osu_span4.small"} <= set(rh.cells("tpurun"))
 
 
@@ -149,6 +162,9 @@ APP = textwrap.dedent("""
             verdict[op].append(bool(
                 isinstance(got, jax.Array) and got.shape[0] == 1
                 and np.array_equal(np.asarray(got).reshape(-1), row(me))))
+            # rank 1 has read its slot before rank 0 writes it again, as
+            # the cell's round between two calls on one window ensures
+            world.barrier()
     with open(os.path.join(sys.argv[1], "rank%%d.json" %% me), "w") as f:
         json.dump(verdict, f)
     world.barrier()

@@ -33,6 +33,14 @@ X = np.stack([np.arange(128, dtype=np.float32),
 # a table of 16 words: word k names index k % 8 and the value k
 WORD = (np.arange(16) % 8 << 8) + 128 + np.arange(16)
 T = np.stack([WORD, WORD + 5]).astype(np.int32)
+SHM_SMALL = {f"{k}.shm_small" for k in (
+    "shm_quiet_us", "shm_drain_us", "shm_get_us", "shm_amo_us",
+    "osc_sync_us", "osc_wait_us", "osc_pack_us", "osc_unpack_us",
+    "osc_d2h_us", "osc_h2d_us", "shm_ops_per_call", "shm_batches_per_call",
+    "shm_wire_kb", "plan_hit_pct", "home_turn_us", "home_recv_us",
+    "home_program_us", "home_out_us", "home_back_us")}
+SPAN_SMALL = {f"{k}.span_small" for k in (
+    "device_busy_us", "peak_share_pct", "device_idle_pct")}
 
 
 def by_hand_posted():
@@ -132,10 +140,11 @@ def test_the_cell_and_its_rounds():
         4 << 20, 1 << 20, 65536, 65536]
     assert {m["name"] for m in rh.MAN.metrics_of(CELL, "end_to_end")} == {
         "span_call_us", "span_call_p95_us", "setup_s"}
-    layer = [m["name"] for m in rh.MAN.metrics_of(CELL, "per_layer")]
-    assert sum(n.endswith(".shm_small") for n in layer) == 14
-    assert sum(n.endswith(".span_small") for n in layer) == 3
-    assert len(layer) == 17 and CELL in rh.cells("tpurun")
+    # by name: an entry appended later, for this cell or another, moves
+    # no pin
+    layer = {m["name"] for m in rh.MAN.metrics_of(CELL, "per_layer")}
+    assert SHM_SMALL <= layer and SPAN_SMALL <= layer
+    assert CELL in rh.cells("tpurun")
     # of the first ten cells four are on four chips
     assert [w["chips"] for w in rh.MAN.doc["workloads"][:10]].count(4) == 4
 
@@ -200,6 +209,9 @@ APP = textwrap.dedent("""
                 isinstance(got, jax.Array) and got.shape[0] == 1
                 and got.dtype == dt
                 and np.array_equal(np.asarray(got).reshape(-1), row(me))))
+            # PE 1 has read its allocation before PE 0 puts to it again, as
+            # the cell's round between two calls on one allocation ensures
+            world.barrier()
     with open(os.path.join(sys.argv[1], "rank%%d.json" %% me), "w") as f:
         json.dump(verdict, f)
     world.barrier()

@@ -1,12 +1,11 @@
-"""The metrics PR 38 adds over what rank 0 waits for: the home's turn of a
-one-sided batch (five timers the origin fills from the stamps on the reply)
-and the folds, the pad and the hand-out of arrivals inside ``ompi.nbc.wait``
-(three spans). Data files and entries alone: each names a reader that is
-there and a counter or span the library has; the five ``.shm_small`` files
-wait without an entry (``test_perfbench_shmem.py`` pins that cell's count).
-Traced CPU rehearsals of ``osu_rma.stream`` and ``osu_span2.large`` report
-them, and the idle time inside a folding allreduce is credited to the three
-spans by name.
+"""The metrics over what rank 0 waits for: the home's turn of a one-sided
+batch (five timers the origin fills from the stamps on the reply), for
+``osu_rma.stream`` and ``osu_shmem.rate``, and the folds, the pad and the
+hand-out of arrivals inside ``ompi.nbc.wait`` (three spans). Data files
+and entries alone: each names a reader that is there and a counter or span
+the library has. Traced CPU rehearsals of ``osu_rma.stream``,
+``osu_shmem.rate`` and ``osu_span2.large`` report them, and the idle time
+inside a folding allreduce is credited to the three spans by name.
 
 Run as a program this file is the rehearsals' worker: the benchmark's own,
 with its trace written under a directory of this test's — the traced
@@ -40,7 +39,8 @@ SPANS = {"fold": "ompi.hier.fold", "pad": "ompi.hier.pad",
 ENTERED = {**{f"home_{k}_ms.rma_large": ["osu_rma.stream"] for k in HOME},
            **{f"{k}_ms.span_large": ["osu_span2.large", "osu_span4.large"]
               for k in SPANS}}
-WAITING = [f"home_{k}_us.shm_small" for k in HOME]
+# the same five for osu_shmem.rate, entered after the eight
+SHMEM_HOME = {f"home_{k}_us.shm_small": ["osu_shmem.rate"] for k in HOME}
 
 
 def spec_of(name):
@@ -48,7 +48,7 @@ def spec_of(name):
                                            name + ".json"))
 
 
-@pytest.mark.parametrize("name", sorted(ENTERED) + WAITING)
+@pytest.mark.parametrize("name", sorted(ENTERED) + list(SHMEM_HOME))
 def test_a_metric_names_a_reader_and_a_counter_or_a_span_that_exist(name):
     from ompi_release_tpu.mca import pvar
     from ompi_release_tpu.obs import spans
@@ -71,20 +71,26 @@ def test_a_metric_names_a_reader_and_a_counter_or_a_span_that_exist(name):
 
 
 def test_eight_are_entered_and_five_wait_for_a_benchmark_pr():
+    """All thirteen are entered, picked out by name in their order (an
+    entry appended after them moves no pin): the eight, then the five that
+    waited for a benchmark PR as files without entries; the test keeps
+    the name it had while they waited."""
     layer = rh.MAN.doc["per_layer"]
-    assert [m["name"] for m in layer[-8:]] == list(ENTERED)
-    for m in layer[-8:]:
-        assert m["workloads"] == ENTERED[m["name"]]
+    ours = [m for m in layer
+            if m["name"] in ENTERED or m["name"] in SHMEM_HOME]
+    assert [m["name"] for m in ours] == list(ENTERED) + list(SHMEM_HOME)
+    for m in ours:
+        entered = m["name"] in ENTERED
+        assert m["workloads"] == (ENTERED if entered
+                                  else SHMEM_HOME)[m["name"]]
         assert (m["unit"], m["better"], m["moves"]) == (
-            "ms", "lower", "span_algbw")
+            ("ms", "lower", "span_algbw") if entered
+            else ("us", "lower", "span_call_us"))
         assert (m["source"], m["layer"]) == (
             ("program_counter", "p2p, RMA, OSHMEM")
             if m["name"].startswith("home_")
             else ("program_span", "spanning round"))
-    entered = {m["name"] for m in layer}
-    for name in WAITING:
-        assert name not in entered
-        assert "test_perfbench_shmem.py" in spec_of(name)["what"]
+        assert "NOT ENTERED" not in spec_of(m["name"]).get("what", "")
 
 
 def rehearse_traced(capfd, monkeypatch, tmp_path, cell):
@@ -102,6 +108,16 @@ def test_osu_rma_stream_reports_the_homes_turn(capfd, monkeypatch, tmp_path):
     # the turn lies inside the origin's wait, the wait inside its flush
     assert turn <= m["osc_sync_ms.rma_large"]
     # both ranks of the rehearsal share a host, so a clock: these tick
+    assert out > 0 and back > 0
+
+
+def test_osu_shmem_rate_reports_the_homes_turn(capfd, monkeypatch, tmp_path):
+    m = rehearse_traced(capfd, monkeypatch, tmp_path, "osu_shmem.rate")
+    turn, recv, program, out, back = (m[f"home_{k}_us.shm_small"]
+                                      for k in HOME)
+    assert 0 < recv and 0 < program and recv + program <= turn
+    assert turn <= m["osc_sync_us.shm_small"]
+    # both PEs of the rehearsal share a host, so a clock: these tick
     assert out > 0 and back > 0
 
 
