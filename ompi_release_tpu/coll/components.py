@@ -251,10 +251,13 @@ class _XlaModule:
         )
 
     # -- v-variants (padded lax kernels, counts at the driver edge) --------
-    def alltoallv(self, comm, sendbufs, sendcounts):
+    def alltoallv(self, comm, sendbufs, sendcounts, **device_form):
+        # device_form: recv_capacity — the buffer and the counts stay on
+        # the chips (vcoll.alltoallv_device)
         from . import vcoll
 
-        return vcoll.alltoallv(comm, sendbufs, sendcounts, kernel="lax")
+        return vcoll.alltoallv(comm, sendbufs, sendcounts, kernel="lax",
+                               **device_form)
 
     def allgatherv(self, comm, sendbufs):
         from . import vcoll
@@ -816,11 +819,11 @@ class _TunedModule:
         jax.block_until_ready(out)
 
     # -- v-variants: tuned's hand schedules on the padded kernels ----------
-    def alltoallv(self, comm, sendbufs, sendcounts):
+    def alltoallv(self, comm, sendbufs, sendcounts, **device_form):
         from . import vcoll
 
         return vcoll.alltoallv(comm, sendbufs, sendcounts,
-                               kernel="pairwise")
+                               kernel="pairwise", **device_form)
 
     def allgatherv(self, comm, sendbufs):
         from . import vcoll
@@ -1040,9 +1043,11 @@ class _SelfModule:
         }
 
     @staticmethod
-    def _alltoallv(comm, bufs, counts):
+    def _alltoallv(comm, bufs, counts, recv_capacity=None):
         from . import vcoll
 
+        if recv_capacity is not None:  # the device form, as on n ranks
+            return vcoll.alltoallv_device(comm, bufs, counts, recv_capacity)
         b = vcoll._as_1d_arrays(bufs, 1, "alltoallv")
         c = vcoll._counts_matrix(counts, 1)
         if b[0].shape[0] != int(c[0, 0]):

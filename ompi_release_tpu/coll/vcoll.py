@@ -19,6 +19,11 @@ shape: changing counts changes only the edge slicing, never triggers a
 retrace (the "no per-call retrace" north-star requirement applies to
 varying ragged workloads too — this is why counts live at the edge).
 
+``alltoallv`` has a second, device form (:func:`alltoallv_device`)
+for counts that only the device knows: one rank-sharded buffer and
+count matrix in, the exchange, its offsets and the receive buffer's
+compaction in one program, nothing on the host.
+
 Driver-mode conventions (matching ``comm/communicator.py``):
 rank-dependent inputs/outputs are Python lists indexed by rank (ragged
 lengths make a leading-axis array impossible); results identical on
@@ -27,11 +32,13 @@ every rank are returned once.
 
 from __future__ import annotations
 
+import functools
 import time as _time
-from typing import List, Sequence
+from typing import List, NamedTuple, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -44,6 +51,7 @@ AXIS = "rank"
 
 from .. import obs as _obs  # noqa: E402
 from ..mca import pvar as _pvar  # noqa: E402
+from ..obs import spans as _spans  # noqa: E402
 
 _padded_elems = _pvar.counter(
     "vcoll_alltoallv_padded_elems",
@@ -53,6 +61,17 @@ _overflow_elems = _pvar.counter(
     "vcoll_alltoallv_overflow_elems",
     "hot-pair tail elements delivered host-side at the driver edge "
     "(skew mitigation; these bypass the kernel)",
+)
+_device_calls = _pvar.counter(
+    "vcoll_alltoallv_device_calls",
+    "alltoallv calls of the device form: a rank-sharded buffer and count "
+    "matrix in, the receive buffer and counts out, nothing on the host",
+)
+_host_bytes = _pvar.counter(
+    "vcoll_alltoallv_host_bytes",
+    "bytes alltoallv moved through host memory: the list form's send "
+    "buffers read, its padded array put and fetched, its receive buffers "
+    "put (0 on the device form)",
 )
 
 
@@ -118,22 +137,31 @@ def _skew_cap(c: np.ndarray) -> int:
 
 
 def alltoallv(comm, sendbufs: Sequence, sendcounts, *,
-              kernel: str = "lax") -> List:
+              kernel: str = "lax", recv_capacity=None):
     """Every rank sends ``sendcounts[i][j]`` elements to rank j.
 
     ``sendbufs[i]`` = rank i's send buffer: the chunks for ranks
     0..n-1 back to back (MPI's sdispls are implicit/contiguous; pass
     pre-sliced data for the general displacement case). Returns
     ``recv[i]`` = concatenation of chunks from ranks 0..n-1 in source
-    order — exactly MPI_Alltoallv's receive layout.
+    order — exactly MPI_Alltoallv's receive layout — on rank i's
+    device.
+
+    With ``recv_capacity`` the call is the device form
+    (:func:`alltoallv_device`): ``sendbufs`` is one rank-sharded
+    ``(n, m)`` array and ``sendcounts`` an ``(n, n)`` one, and
+    ``kernel`` does not apply.
 
     Skewed count matrices are mitigated (see :func:`_skew_cap`): the
     padded kernel's cap is bounded at a count quantile and hot pairs'
     overflow tails are delivered host-side at the driver edge
     (numpy slices concatenated into the receive buffers — they never
     traverse a kernel or transport), accounted in the
-    ``vcoll_alltoallv_overflow_elems`` pvar.
+    ``vcoll_alltoallv_overflow_elems`` pvar. Every byte of this form
+    passes through the host (``vcoll_alltoallv_host_bytes``).
     """
+    if recv_capacity is not None:
+        return alltoallv_device(comm, sendbufs, sendcounts, recv_capacity)
     rec = _obs.enabled  # capture once: flag may flip mid-call
     t_edge = _time.perf_counter() if rec else 0.0
     n = comm.size
@@ -174,7 +202,8 @@ def alltoallv(comm, sendbufs: Sequence, sendcounts, *,
     _padded_elems.add(n * n * cap)
     _overflow_elems.add(overflow_elems)
     out = np.asarray(out)  # (n, n, cap); out[i, j] = chunk j -> i
-    recv = []
+    devices = list(comm.submesh.devices.flat)
+    recv, moved = [], sum(b.nbytes for b in bufs) + 2 * padded.nbytes
     for i in range(n):
         parts = []
         for j in range(n):
@@ -184,8 +213,10 @@ def alltoallv(comm, sendbufs: Sequence, sendcounts, *,
             if tail is not None:
                 part = np.concatenate([part, tail])
             parts.append(part)
-        recv.append(jnp.asarray(np.concatenate(parts) if parts
-                                else np.zeros((0,), dtype)))
+        got = np.concatenate(parts) if parts else np.zeros((0,), dtype)
+        moved += got.nbytes
+        recv.append(jax.device_put(got, devices[i]))
+    _host_bytes.add(moved)
     if rec:
         # whole-edge span (pad + kernel + overflow delivery); the
         # kernel's own coll-layer span nests inside it in the trace
@@ -195,6 +226,196 @@ def alltoallv(comm, sendbufs: Sequence, sendcounts, *,
             comm_id=comm.cid,
         )
     return recv
+
+
+# ---------------------------------------------------------------------------
+# alltoallv, device form: buffer and counts never leave the chips
+# ---------------------------------------------------------------------------
+
+#: elements of one row of the exchange: ``lax.ragged_all_to_all`` on
+#: XLA:TPU lays its leading (ragged) axis out one 128-lane tile per
+#: index, so a flat buffer would take 128 times its size; in rows of
+#: 128 it moves as laid out, and a chunk travels as the rows that hold it
+GRANULE = 128
+
+
+class Operands(NamedTuple):
+    """One rank's side of a device-form exchange. The first four are
+    ``lax.ragged_all_to_all``'s, in rows of :data:`GRANULE`; the rest
+    are what the receiver keeps to compact its rows."""
+
+    input_offsets: jax.Array   # (n,) first row of this rank's chunk for j
+    send_sizes: jax.Array      # (n,) rows of it that j takes
+    output_offsets: jax.Array  # (n,) where those rows land on j
+    recv_sizes: jax.Array      # (n,) rows this rank takes from source k
+    recv_rows: jax.Array       # (n,) where source k's rows land here
+    start: jax.Array           # (n,) element of k's chunk in those rows
+    at: jax.Array              # (n,) element where k's chunk lands in recv
+    held: jax.Array            # (n,) elements of k's chunk recv holds
+    recvcounts: jax.Array      # (n,) elements each source sent, uncut
+    dropped: jax.Array         # () elements recv could not hold
+
+
+def stage_rows(n: int, cap: int) -> int:
+    """Rows of the staging buffer: every source's chunk may straddle
+    two rows it shares with its neighbours."""
+    return -(-cap // GRANULE) + 2 * n
+
+
+def exchange_operands(sendcounts, cap: int) -> Operands:
+    """The operands of one rank's exchange, inside the program: both
+    device bodies build them here. ``sendcounts``: this rank's row of
+    the count matrix. Each source tells its peers, in one
+    ``all_to_all`` (MPI_Alltoall of the counts), how many elements it
+    sends and where in its first row they start; each receiver decides
+    what its ``cap`` elements hold (each source's chunk in source
+    order, cut where the buffer ends) and where the rows holding it
+    land, and tells each source in a second one, so sender and
+    receiver agree on every size by construction.
+    """
+    r = GRANULE
+    sc = sendcounts.astype(jnp.int32)
+    off = jnp.cumsum(sc) - sc
+    hdr = lax.all_to_all(jnp.stack([sc, off % r], axis=1), AXIS, 0, 0,
+                         tiled=True)
+    rc, head = hdr[:, 0], hdr[:, 1]
+    land = jnp.cumsum(rc) - rc
+    held = jnp.maximum(jnp.minimum(rc, cap - land), 0)
+    rows = jnp.where(held > 0, (head + held + r - 1) // r, 0)
+    recv_rows = jnp.cumsum(rows) - rows
+    back = lax.all_to_all(jnp.stack([recv_rows, rows], axis=1), AXIS, 0, 0,
+                          tiled=True)
+    return Operands(off // r, back[:, 1], back[:, 0], rows, recv_rows,
+                    recv_rows * r + head, jnp.minimum(land, cap), held, rc,
+                    jnp.sum(rc - held))
+
+
+def _as_rows(xb):
+    """The rank's buffer as rows of :data:`GRANULE` (zero-filled to a
+    whole row)."""
+    tail = -xb.shape[0] % GRANULE
+    if tail:
+        xb = jnp.concatenate([xb, jnp.zeros((tail,), xb.dtype)])
+    return xb.reshape(-1, GRANULE)
+
+
+def _compact(stage, o: Operands, cap: int):
+    """The receive buffer from the staged rows: source k's ``held``
+    elements from ``start[k]`` of the rows to ``at[k]``, zero past the
+    last one."""
+    flat = stage.reshape(-1)
+    flat = jnp.concatenate([flat, jnp.zeros((cap,), flat.dtype)])
+    t = jnp.arange(cap)
+    out = jnp.zeros((cap,), flat.dtype)
+    for k in range(o.at.shape[0]):
+        piece = lax.dynamic_slice(flat, (o.start[k] - o.at[k],), (cap,))
+        out = jnp.where((t >= o.at[k]) & (t < o.at[k] + o.held[k]), piece,
+                        out)
+    return out
+
+
+def _ragged_body(xb, sc, dropped, *, cap: int):
+    """TPU: ``lax.ragged_all_to_all`` moves the rows that hold the
+    counted elements, and nothing else."""
+    o = exchange_operands(sc, cap)
+    stage = lax.ragged_all_to_all(
+        _as_rows(xb), jnp.zeros((stage_rows(sc.shape[0], cap), GRANULE),
+                                xb.dtype),
+        o.input_offsets, o.send_sizes, o.output_offsets, o.recv_sizes,
+        axis_name=AXIS)
+    return _compact(stage, o, cap), o.recvcounts, dropped + o.dropped
+
+
+def _padded_body(xb, sc, dropped, *, cap: int):
+    """Every other backend (XLA:CPU has no ragged-all-to-all): the same
+    operands, each pair's rows padded to the pair capacity, the tiled
+    ``all_to_all`` of the ``xla`` component, and the rows staged in
+    source order, each chunk over the zero tail of the one before."""
+    o = exchange_operands(sc, cap)
+    n, rows = sc.shape[0], _as_rows(xb)
+    s = stage_rows(n, cap)
+    p = min(rows.shape[0], s)
+    padded = jnp.concatenate([rows, jnp.zeros((p, GRANULE), rows.dtype)])
+    send = jnp.stack([lax.dynamic_slice(padded, (o.input_offsets[j], 0),
+                                        (p, GRANULE)) for j in range(n)])
+    keep = jnp.arange(p)[None, :] < o.send_sizes[:, None]
+    send = jnp.where(keep[:, :, None], send, 0)
+    got = lax.all_to_all(send, AXIS, 0, 0, tiled=True)  # [k]: from k
+    stage = jnp.zeros((s + p, GRANULE), xb.dtype)
+    for k in range(n):
+        stage = lax.dynamic_update_slice(stage, got[k], (o.recv_rows[k], 0))
+    return _compact(stage[:s], o, cap), o.recvcounts, dropped + o.dropped
+
+
+def _ragged_on(comm) -> bool:
+    return next(iter(comm.submesh.devices.flat)).platform == "tpu"
+
+
+def truncated(comm):
+    """Elements each rank's receive buffer could not hold, over every
+    device-form call on ``comm`` so far: a rank-sharded ``(n,)`` int32
+    device array that the exchange itself keeps (MPI's ERR_TRUNCATE
+    without a host sync; read it when the caller syncs anyway)."""
+    t = getattr(comm, "_alltoallv_truncated", None)
+    if t is None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        t = jax.device_put(np.zeros((comm.size,), np.int32),
+                           NamedSharding(comm.submesh, P(AXIS)))
+        comm._alltoallv_truncated = t
+    return t
+
+
+def alltoallv_device(comm, x, sendcounts, recv_capacity: int):
+    """MPI_Alltoallv with the buffer and the counts on the chips.
+
+    ``x``: rank-sharded ``(n, m)``, row i rank i's send buffer with its
+    chunks for ranks 0..n-1 back to back (MPI's contiguous sdispls).
+    ``sendcounts``: rank-sharded ``(n, n)`` int32, row i rank i's; the
+    program sends each column to its rank (MPI_Alltoall of the counts).
+    Returns ``(recv, counts)`` on the communicator's devices: ``recv``
+    is ``(n, recv_capacity)``, row j the chunks from each source in
+    source order, zero past rank j's total; ``counts`` row j what each
+    source sent to j. A total past ``recv_capacity`` is cut where the
+    buffer ends, ``counts`` still says what was sent, and
+    :func:`truncated` counts what was cut.
+
+    No count is read on the host and nothing is fetched: the call
+    returns once its program is enqueued, and one compiled program
+    serves every count matrix of one ``(n, m, recv_capacity, dtype)``:
+    the ragged body on a TPU, the padded one elsewhere.
+    """
+    n, cap = comm.size, int(recv_capacity)
+    shape = tuple(getattr(x, "shape", ()))
+    if len(shape) != 2 or shape[0] != n or cap < 1:
+        raise MPIError(
+            ErrorCode.ERR_COUNT,
+            f"device-form alltoallv needs an ({n}, m) buffer and a "
+            f"recv_capacity of at least 1, got {shape} and {cap}",
+        )
+    if tuple(getattr(sendcounts, "shape", ())) != (n, n):
+        raise MPIError(
+            ErrorCode.ERR_COUNT,
+            f"device-form alltoallv needs ({n}, {n}) sendcounts, got "
+            f"{getattr(sendcounts, 'shape', type(sendcounts).__name__)}",
+        )
+    body = _ragged_body if _ragged_on(comm) else _padded_body
+    return _exchange(comm, x, sendcounts, cap, body)
+
+
+def _exchange(comm, x, sendcounts, cap, body):
+    """One device-form exchange through ``body`` (the caller has
+    checked the arguments)."""
+    with _obs.span(_spans.VCOLL_ALLTOALLV, journal=("alltoallv", "vcoll"),
+                   bytes=_spans.nbytes(x), cid=comm.cid):
+        recv, counts, cut = run_sharded(
+            comm, ("xla", "alltoallv_device", body.__name__, x.shape[1],
+                   cap, str(x.dtype)),
+            functools.partial(body, cap=cap), x,
+            extra_arrays=(sendcounts, truncated(comm)))
+        comm._alltoallv_truncated = cut
+        _device_calls.add()
+    return recv, counts
 
 
 # ---------------------------------------------------------------------------

@@ -797,12 +797,29 @@ class Communicator:
         return self.fusion_buffer().allreduce(x, op)
 
     # -- v-variant collectives (per-rank counts; ragged driver edge) -------
-    def alltoallv(self, sendbufs, sendcounts):
+    def alltoallv(self, sendbufs, sendcounts, *, recv_capacity=None):
         """MPI_Alltoallv: ``sendbufs[i]`` holds rank i's chunks for
         ranks 0..n-1 back to back, ``sendcounts[i][j]`` elements for
         rank j. Returns ``recv[i]`` = chunks from each source, in
-        source order."""
-        return self._coll("alltoallv")(self, sendbufs, sendcounts)
+        source order, on rank i's device.
+
+        Device form, with ``recv_capacity``: ``sendbufs`` is one
+        rank-sharded ``(size, m)`` array, ``sendcounts`` a rank-sharded
+        ``(size, size)`` int32 array; returns ``(recv, counts)``, a
+        rank-sharded ``(size, recv_capacity)`` receive buffer and the
+        counts each rank received, and no count or element is read on
+        the host (``coll/vcoll.alltoallv_device``)."""
+        if recv_capacity is None:
+            return self._coll("alltoallv")(self, sendbufs, sendcounts)
+        if self.spans_processes:
+            raise MPIError(
+                ErrorCode.ERR_NOT_AVAILABLE,
+                f"device-form alltoallv on {self.name}, which spans "
+                "controller processes: only the list form crosses "
+                "processes",
+            )
+        return self._coll("alltoallv")(
+            self, sendbufs, sendcounts, recv_capacity=recv_capacity)
 
     def allgatherv(self, sendbufs):
         """MPI_Allgatherv: ragged per-rank buffers, concatenated in

@@ -24,6 +24,9 @@ keyword stats of the event, never folded into the name:
                            to the call's return (nested in ``call``)
 ``ompi.coll.compile``      the same call when the program-cache lookup
                            missed: trace + compile + first launch (``op``)
+``ompi.vcoll.alltoallv``   the host side of a device-form ``alltoallv``:
+                           checks, program lookup and enqueue, never a
+                           wait (``bytes`` of the send buffer, ``cid``)
 ``ompi.nbc.wait``          the caller parked on (or running inline) a
                            posted spanning schedule (``cid``, ``seq``)
 ``ompi.plan.native_fire``  one frozen wire plan walked by the C executor:
@@ -160,6 +163,7 @@ SHMEM_QUIET = "ompi.shmem.quiet"
 SHMEM_DRAIN = "ompi.shmem.drain"
 SHMEM_GET = "ompi.shmem.get"
 SHMEM_AMO = "ompi.shmem.amo"
+VCOLL_ALLTOALLV = "ompi.vcoll.alltoallv"
 
 NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
          PLAN_NATIVE_FIRE, PLAN_XCHG, HIER_D2H, HIER_H2D, WIRE_STASH,
@@ -167,7 +171,7 @@ NAMES = (COLL_CALL, COLL_LAUNCH, COLL_COMPILE, NBC_WAIT,
          PML_H2D, HIER_ASSEMBLE, OSC_SYNC, OSC_PACK, OSC_D2H, OSC_REQUEST,
          OSC_REPLY_WAIT, OSC_UNPACK, OSC_H2D, OSC_APPLY, OSC_PROGRAM,
          SHMEM_QUIET, SHMEM_DRAIN, SHMEM_GET, SHMEM_AMO, PLAN_ARRIVALS,
-         HIER_PAD, HIER_FOLD)
+         HIER_PAD, HIER_FOLD, VCOLL_ALLTOALLV)
 
 #: ``jax.profiler.TraceAnnotation`` and the ``obs`` package, bound on
 #: the first span: importing ``obs`` must not import jax (``obs

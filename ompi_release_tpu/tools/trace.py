@@ -151,6 +151,9 @@ def profiler_trace(logdir: str):
     - ``ompi.coll.launch`` — the compiled program's call, host side
     - ``ompi.coll.compile`` — the same call when the program was not
       cached: it traces and compiles first (``op``)
+    - ``ompi.vcoll.alltoallv`` — the host side of a device-form
+      ``alltoallv``: checks, program lookup and enqueue (``bytes``,
+      ``cid``)
     - ``ompi.nbc.wait`` — the caller parked on, or running, a posted
       spanning schedule (``cid``, ``seq``)
     - ``ompi.plan.native_fire`` — a frozen wire plan walked by the C

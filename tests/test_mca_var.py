@@ -205,4 +205,7 @@ def test_census_of_options_and_counters():
     got = json.loads(r.stdout.split("CENSUS ", 1)[1].splitlines()[0])
     assert got["cvars"] == 104
     assert len(got["on_off"]) == 17, got["on_off"]
-    assert got["pvars"] == 123  # PR 38: the five osc_home_*_seconds
+    # the five osc_home_*_seconds came last but two; the last two are
+    # the device-form alltoallv's (vcoll_alltoallv_device_calls,
+    # vcoll_alltoallv_host_bytes)
+    assert got["pvars"] == 125

@@ -116,3 +116,36 @@ def test_reduce_scatter_block_is_a_reduce_scatter(mesh, monkeypatch, size):
     for kind, shape, _, _ in found:
         if kind not in ("parameter", "bitcast", "fusion"):
             assert np.prod(shape) <= 2 * elems // n, (kind, shape)
+
+
+def test_device_alltoallv_moves_rows_at_nas_is_class_c(mesh):
+    """The device form's TPU body at NAS IS class C (2^25 int32 keys a
+    rank, a receive buffer of 1.5 times that): one ragged-all-to-all of
+    rows of ``GRANULE`` elements. A flat buffer would have been laid out
+    one 128-lane tile per element (24 GiB for the receive buffer, which
+    the compiler refuses); in rows, nothing the program holds besides
+    its arguments is larger than twice the receive buffer."""
+    import functools
+
+    from ompi_release_tpu.coll import vcoll
+
+    n, m = mesh.size, 1 << 25
+    cap = 3 * m // 2
+
+    def wrapper(xb, *eb):
+        out = vcoll._ragged_body(xb[0], *[e[0] for e in eb], cap=cap)
+        return jax.tree.map(lambda a: a[None], out)
+
+    prog = jax.jit(jax.shard_map(wrapper, mesh=mesh, in_specs=P("rank"),
+                                 out_specs=P("rank")))
+    sh = NamedSharding(mesh, P("rank"))
+    args = [jax.ShapeDtypeStruct((n, m), jnp.int32, sharding=sh),
+            jax.ShapeDtypeStruct((n, n), jnp.int32, sharding=sh),
+            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=sh)]
+    compiled = prog.lower(*args).compile()
+    hlo = compiled.as_text()
+    ragged = [ln for ln in hlo.splitlines()
+              if " ragged-all-to-all(" in ln]
+    assert len(ragged) == 1
+    assert f"s32[{vcoll.stage_rows(n, cap)},1,{vcoll.GRANULE}]" in ragged[0]
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * 4 * cap

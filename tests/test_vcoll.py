@@ -68,6 +68,23 @@ class TestAlltoallv:
         total_to_1 = int(c[:, 1].sum())
         assert np.asarray(recv[1]).shape == (total_to_1,)
 
+    def test_each_receive_buffer_is_on_its_ranks_device(self, comm):
+        """Rank i's receive buffer lands on rank i's device, not all on
+        the default one, and the host staging is counted."""
+        from ompi_release_tpu.mca import pvar as pvar_mod
+
+        n = comm.size
+        c = _ragged_counts(n, seed=3, lo=1)
+        bufs = [np.arange(int(c[i].sum()), dtype=np.float32) + i
+                for i in range(n)]
+        moved = pvar_mod.PVARS.lookup("vcoll_alltoallv_host_bytes")
+        before = moved.read()
+        recv = comm.alltoallv(bufs, c)
+        devices = list(comm.submesh.devices.flat)
+        assert [r.devices() for r in recv] == [{d} for d in devices]
+        # at least every element read once and placed once
+        assert moved.read() - before >= 2 * 4 * int(c.sum())
+
     def test_count_mismatch_raises(self, comm):
         n = comm.size
         c = np.ones((n, n), np.int64)
