@@ -105,6 +105,13 @@ _assembled = pvar.counter(
 _assembled_bytes = pvar.counter(
     "hier_assembled_bytes", "bytes of those results")
 
+_rsb_halved = pvar.counter(
+    "hier_reduce_scatter_halved",
+    "reduce_scatter_block calls on a spanning comm that ran only the "
+    "recursive-halving half of Rabenseifner and placed only the "
+    "rank's own blocks (_HierModule._reduce_scatter_halved)",
+)
+
 #: current spanning-collective round per comm cid, maintained only
 #: while obs is enabled: {"op", "round", "awaiting_procs",
 #: "awaiting_ranks"}. THE answer to "the job is stuck — who is waiting
@@ -307,6 +314,13 @@ class _HierModule:
              else 1 + self.peers.index(self.owner[r]),
              self.members_of[self.owner[r]].index(r))
             for r in range(comm.size))
+        #: every process owns an equal run of consecutive comm ranks,
+        #: the processes in rank order: chunk j of a flat buffer split
+        #: into P is then exactly process j's ranks' blocks, in order
+        k = comm.size // len(self.procs)
+        self._ranks_chunked = all(
+            self.members_of[p] == list(range(j * k, (j + 1) * k))
+            for j, p in enumerate(self.procs))
         self._xchg = _XchgAdapter(self)
         # handle for coll/plan's frozen-schedule record/replay: the
         # plan layer swaps _xchg for the duration of ONE schedule run
@@ -659,10 +673,11 @@ class _HierModule:
             pair_op=op.is_pair_op, topo=dims,
         )
 
-    def _combine_partials(self, partial, op: Op):
+    def _combine_partials(self, partial, op: Op, host: bool = False):
         """Inter-process combine of per-process partials; identical on
         every process (fixed, process-index-derived order per
-        schedule)."""
+        schedule). ``host``: an order-waiving schedule's result, which
+        is computed on the host, is returned there, not placed."""
         if len(self.procs) == 1:
             if op.is_pair_op:
                 return (jnp.asarray(partial[0]), jnp.asarray(partial[1]))
@@ -679,15 +694,17 @@ class _HierModule:
             if alg not in _topo.TOPO_ALGS:
                 return self._combine_leader(partial, op)
             return self._combine_flat(self.procs, partial, op,
-                                      packed=packed, alg=alg)
-        return self._combine_flat(self.procs, partial, op)
+                                      packed=packed, alg=alg, host=host)
+        return self._combine_flat(self.procs, partial, op, host=host)
 
     def _combine_flat(self, procs: List[int], partial, op: Op,
-                      packed=None, alg: Optional[str] = None):
+                      packed=None, alg: Optional[str] = None,
+                      host: bool = False):
         """Run the selected allreduce schedule over ``procs`` (the
         whole process set, or the leader set under the leader tier).
         ``packed``/``alg`` let a caller that already packed and picked
-        (the leader-tier stand-aside) hand both through."""
+        (the leader-tier stand-aside) hand both through; ``host`` as
+        in :meth:`_combine_partials`."""
         P = len(procs)
         if P == 1:
             if op.is_pair_op:
@@ -715,7 +732,8 @@ class _HierModule:
                 fn = (_hs.allreduce_ring if alg == "ring"
                       else _hs.allreduce_rabenseifner)
                 out = fn(self._xchg, procs, me, arr, npop, ident)
-            return _h2d(np.asarray(out).reshape(arr.shape))
+            out = np.asarray(out).reshape(arr.shape)
+            return out if host else _h2d(out)
         if alg == "recursive_doubling":
             flats = _hs.allgather_bruck(
                 self._xchg, procs, me, packed,
@@ -952,11 +970,46 @@ class _HierModule:
             out = np.stack([chunks[r] for r in self.local_ranks])
             return out.reshape((self.local_n, -1) + total.shape[1:])
 
-        total = self._combine_partials(self._local_partial(x, op), op)
+        partial = self._local_partial(x, op)
+        if self._halving_fits(partial, op):
+            return self._reduce_scatter_halved(partial, op)
+        total = self._combine_partials(partial, op, host=True)
         if op.is_pair_op:
             tv, ti = total
             return (_h2d(chunked(_d2h(tv))), _h2d(chunked(_d2h(ti))))
         return _h2d(chunked(_d2h(total)))
+
+    def _halving_fits(self, partial, op: Op) -> bool:
+        """Whether reduce_scatter_block may stop after Rabenseifner's
+        recursive-halving half: the allreduce pick is ``rabenseifner``
+        over the flat process set (a power of two of them, no leader
+        tier), and each process's chunk of the flat partial is its
+        own ranks' blocks in order, whole (no padding). Every input
+        is the same on every process, so all of them take this path
+        or none does."""
+        P = len(self.procs)
+        return (P > 1 and not P & (P - 1) and self._ranks_chunked
+                and not op.is_pair_op and partial.ndim > 0
+                and partial.shape[0] > 0
+                and partial.shape[0] % self.comm.size == 0
+                and not self._leader_tier_active(op)
+                and self._pick_allreduce(
+                    self.procs, int(partial.nbytes), op) == "rabenseifner")
+
+    def _reduce_scatter_halved(self, partial, op: Op):
+        """reduce_scatter_block as the halving rounds alone: after
+        them chunk ``mi`` of the flat partial is this process's
+        ranks' blocks of the sum, folded exactly as the allreduce
+        folds them, and only that chunk is placed."""
+        arr = _d2h(partial)
+        self._note_alg("rabenseifner")
+        flat, _, per, mi = _hs.reduce_scatter_halving(
+            self._xchg, self.procs, self.my_pidx, arr,
+            lambda a, b: _d2h(op(a, b)), op.identity_for(arr.dtype))
+        if not getattr(self._xchg, "dry_run", False):  # not a probe
+            _rsb_halved.add()
+        block = flat[mi * per:(mi + 1) * per]
+        return _h2d(block.reshape((self.local_n, -1) + arr.shape[1:]))
 
     # -- data movement -----------------------------------------------------
     def bcast(self, comm, x, root: int):

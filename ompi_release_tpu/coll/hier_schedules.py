@@ -420,22 +420,25 @@ def allreduce_ring(x, procs: List[int], me: int, mine,
     return np.concatenate(chunks)[:L]
 
 
-def allreduce_rabenseifner(x, procs: List[int], me: int, mine,
-                           op: Callable, identity) -> np.ndarray:
-    """Recursive-halving reduce-scatter + recursive-doubling
-    allgather (Rabenseifner): ~2n bytes in ceil(2 log2 P) rounds.
-    Power-of-two process counts only — callers degrade to
-    :func:`allreduce_ring` otherwise. The halving fold keeps a fixed
-    operand order (lower process positions left), deterministic across
-    ranks and runs; commutative ops only."""
+def reduce_scatter_halving(x, procs: List[int], me: int, mine,
+                           op: Callable, identity) -> tuple:
+    """Recursive-halving reduce-scatter, the first half of
+    Rabenseifner: log2 P rounds, each sending half of what is still
+    accumulated to the partner at the round's distance and folding
+    what comes back. Power-of-two process counts only. Returns
+    ``(flat, L, per, mi)``: ``flat`` is ``mine`` flat, padded with
+    ``identity`` to ``per * P`` elements (``L`` its own length), and
+    its chunk ``mi`` — ``flat[mi * per:(mi + 1) * per]``, ``mi`` this
+    process's position in ``procs`` — holds the reduction of that
+    chunk over every process. The fold keeps a fixed operand order
+    (lower process positions left), deterministic across ranks and
+    runs; commutative ops only."""
     P = len(procs)
-    if P & (P - 1):
-        return allreduce_ring(x, procs, me, mine, op, identity)
     mi = procs.index(me)
     flat, L, per = _pad_chunks(mine, P, identity)
     lo, hi = 0, P  # chunk-position range I still accumulate
     d = P // 2
-    while d >= 1:  # recursive halving reduce-scatter
+    while d >= 1:
         partner = procs[mi ^ d]
         half = (hi - lo) // 2
         if mi & d:
@@ -451,8 +454,23 @@ def allreduce_rabenseifner(x, procs: List[int], me: int, mine,
         fold(op, left, right, into=seg)
         lo, hi = keep
         d //= 2
+    return flat, L, per, mi  # lo == mi: bits selected top-down
+
+
+def allreduce_rabenseifner(x, procs: List[int], me: int, mine,
+                           op: Callable, identity) -> np.ndarray:
+    """Recursive-halving reduce-scatter (:func:`reduce_scatter_halving`)
+    + recursive-doubling allgather (Rabenseifner): ~2n bytes in
+    ceil(2 log2 P) rounds. Power-of-two process counts only — callers
+    degrade to :func:`allreduce_ring` otherwise; commutative ops
+    only."""
+    P = len(procs)
+    if P & (P - 1):
+        return allreduce_ring(x, procs, me, mine, op, identity)
+    flat, L, per, mi = reduce_scatter_halving(x, procs, me, mine, op,
+                                              identity)
     d = 1
-    blk = mi  # owned chunk position (== mi: bits selected top-down)
+    blk = mi  # owned chunk position
     while d < P:  # recursive doubling allgather
         partner = procs[mi ^ d]
         plo = blk ^ d

@@ -30,6 +30,7 @@ tolerance; everything else in the matrix is bitwise.
 """
 
 import json
+import math
 import os
 import sys
 import textwrap
@@ -125,6 +126,108 @@ class TestAllreduceParityMatrix:
             hs.allgather_bruck(x, procs, p, data[p], [9] * P), sub))
         for p in procs:
             np.testing.assert_array_equal(np.asarray(out[p]), want)
+
+
+def _rabenseifner_whole(x, procs, me, mine, op, identity):
+    """``allreduce_rabenseifner`` as one loop after the other, as it
+    was written before its halving half became
+    ``reduce_scatter_halving``: the reference the split is held to."""
+    P = len(procs)
+    mi = procs.index(me)
+    flat, L, per = hs._pad_chunks(mine, P, identity)
+    lo, hi = 0, P
+    d = P // 2
+    while d >= 1:
+        partner = procs[mi ^ d]
+        half = (hi - lo) // 2
+        if mi & d:
+            keep, send = (lo + half, hi), (lo, lo + half)
+        else:
+            keep, send = (lo, lo + half), (lo + half, hi)
+        payload = flat[send[0] * per:send[1] * per]
+        got = hs._flat(hs._round(x, {partner: [payload]},
+                                 {partner: 1})[partner][0])
+        seg = flat[keep[0] * per:keep[1] * per]
+        left, right = (got, seg) if mi & d else (seg, got)
+        hs.fold(op, left, right, into=seg)
+        lo, hi = keep
+        d //= 2
+    d = 1
+    blk = mi
+    while d < P:
+        partner = procs[mi ^ d]
+        plo = blk ^ d
+        payload = flat[blk * per:(blk + d) * per]
+        got = hs._flat(hs._round(x, {partner: [payload]},
+                                 {partner: 1})[partner][0])
+        flat[plo * per:(plo + d) * per] = got
+        blk = min(blk, plo)
+        d *= 2
+    return flat[:L]
+
+
+def _recorded(procs, fn):
+    """``simulate`` with every process's rounds recorded: {pidx:
+    (result, rounds as ``RoundRecorder`` keeps them, bytes sent)}."""
+    from ompi_release_tpu.coll.plan import RoundRecorder
+
+    def run(x, p):
+        rec = RoundRecorder(x)
+        out = fn(rec, p)
+        sent = sum(math.prod(shape) * np.dtype(dt).itemsize
+                   for sends, _ in rec.rounds for _, msgs in sends
+                   for shape, dt in msgs)
+        return out, tuple(rec.rounds), sent
+
+    return simulate(procs, run)
+
+
+class TestRabenseifnerSplit:
+    """``allreduce_rabenseifner`` = ``reduce_scatter_halving`` + the
+    doubling loop: the same rounds, bytes and bits as the one-piece
+    schedule; the halving half alone is its first log2 P rounds, and
+    its chunk ``mi`` is the allreduce's, bit for bit."""
+
+    @pytest.mark.parametrize("P", [2, 4, 8])
+    @pytest.mark.parametrize("length", [4096, 4099])
+    @pytest.mark.parametrize("opname", ["sum", "skew"])
+    def test_split_is_the_whole_schedule(self, P, length, opname):
+        """``skew`` weighs its left operand: any change of operand
+        order or association shows in its bits."""
+        procs = list(range(10, 10 + P))
+        rng = np.random.default_rng(P * length)
+        data = {p: rng.standard_normal(length).astype(np.float32)
+                for p in procs}
+        if opname == "sum":
+            npop = lambda a, b: np.asarray(ops.SUM(a, b))  # noqa: E731
+        else:
+            npop = lambda a, b: (np.float32(0.75) * a  # noqa: E731
+                                 + b).astype(np.float32)
+        ident = np.float32(0)
+        now = _recorded(procs, lambda x, p: hs.allreduce_rabenseifner(
+            x, procs, p, data[p].copy(), npop, ident))
+        was = _recorded(procs, lambda x, p: _rabenseifner_whole(
+            x, procs, p, data[p].copy(), npop, ident))
+        half = _recorded(procs, lambda x, p: hs.reduce_scatter_halving(
+            x, procs, p, data[p].copy(), npop, ident))
+        steps = int(math.log2(P))
+        per = -(-length // P)
+        for p in procs:
+            out, rounds, sent = now[p]
+            assert rounds == was[p][1] and sent == was[p][2]
+            assert out.tobytes() == was[p][0].tobytes()  # BITWISE
+            assert len(rounds) == 2 * steps
+            flat, L, h_per, mi = half[p][0]
+            assert (L, h_per, mi) == (length, per, procs.index(p))
+            assert half[p][1] == rounds[:steps]
+            assert half[p][2] == (P - 1) * per * 4
+            chunk = flat[mi * per:(mi + 1) * per]
+            if per * P == L:  # a padded tail folds the identity
+                assert chunk.tobytes() == \
+                    out[mi * per:(mi + 1) * per].tobytes()
+            else:
+                assert chunk[:L - mi * per].tobytes() == \
+                    out[mi * per:(mi + 1) * per].tobytes()
 
 
 class TestMovementSchedules:

@@ -207,5 +207,6 @@ def test_census_of_options_and_counters():
     assert len(got["on_off"]) == 17, got["on_off"]
     # the five osc_home_*_seconds came last but two; the last two are
     # the device-form alltoallv's (vcoll_alltoallv_device_calls,
-    # vcoll_alltoallv_host_bytes)
-    assert got["pvars"] == 125
+    # vcoll_alltoallv_host_bytes); hier_reduce_scatter_halved counts the
+    # reduce_scatter_block calls that ran Rabenseifner's halving alone
+    assert got["pvars"] == 126

@@ -1,9 +1,10 @@
 """A native fire in segments (ISSUE 33): real ``tpurun`` jobs.
 
 A spanning allreduce or reduce_scatter_block of ``hier_small_message``
-bytes or more runs Rabenseifner (2 and 4 processes) or the ring (3):
-schedules that fold what arrives and send the fold on. Their plans
-have LIVE rounds — the C walk pauses before each, the schedule body
+bytes or more runs Rabenseifner (2 and 4 processes; reduce_scatter_block
+only its recursive-halving half) or the ring (3): schedules that fold
+what arrives and send the fold on. Their plans have LIVE rounds (all
+but reduce_scatter_block's single round at two processes) — the C walk pauses before each, the schedule body
 supplies that round's sends, the same fire walks on — so they leave
 ``PlannedXchg``. What is held here: results bit for bit those of the
 interpreted and the replayed path, one ``plan_native_fires`` per call,
@@ -26,9 +27,14 @@ pytestmark = needs_native
 #: little, so that 2, 3 and 4 processes of two ranks each divide them
 SIZES = {"64k": 16392, "1m": 262152}
 
-#: a process's live rounds per call: Rabenseifner's rounds after the
-#: first (2 log2 P - 1), the ring's two folds-sent-on at three
+#: a process's live rounds per allreduce: Rabenseifner's rounds after
+#: the first (2 log2 P - 1), the ring's two folds-sent-on at three
 LIVE = {2: 1, 3: 2, 4: 3}
+
+#: the same for reduce_scatter_block, which stops after Rabenseifner's
+#: recursive-halving rounds (log2 P - 1 of them live); the ring at
+#: three runs whole
+LIVE_RSB = {2: 0, 3: 2, 4: 1}
 
 SETUP = """
     from ompi_release_tpu.coll import native_exec as nx
@@ -66,7 +72,8 @@ SETUP = """
 
 
 def _setup(n, size, coll, op):
-    return SETUP % {"per": SIZES[size], "live": LIVE[n], "op": op,
+    live = (LIVE if coll == "allreduce" else LIVE_RSB)[n]
+    return SETUP % {"per": SIZES[size], "live": live, "op": op,
                     "coll": coll}
 
 
